@@ -7,7 +7,8 @@ hyperparameters as bench_reference.py's training anchor -- Adam 1e-4, batch
 4, BCE, 256x256, reference: scripts/train_segmenter.py:45-50,143-145):
 
 1. steady-state TPU train-step throughput (chained lax.scan, one fetch --
-   see bench.py for why naive timing lies on this image);
+   see bench.py for the timing method); needs the accelerator and exits
+   non-zero without one;
 2. an end-to-end `train_model` convergence run recording wall-clock and
    final val mIoU/Dice (the metric the reference never computes, SURVEY.md
    section 2.1 "Trainer");
@@ -74,6 +75,14 @@ def bench_tpu_step_throughput() -> dict:
     import jax.numpy as jnp
     import optax
 
+    from robotic_discovery_platform_tpu.utils import flops as flops_lib
+    from robotic_discovery_platform_tpu.utils.platforms import (
+        require_accelerator,
+    )
+
+    peaks = flops_lib.chip_peaks(
+        require_accelerator("bench_train.py tpu").device_kind)
+
     from robotic_discovery_platform_tpu.models import losses
     from robotic_discovery_platform_tpu.models.unet import build_unet, init_unet
     from robotic_discovery_platform_tpu.training import trainer
@@ -107,17 +116,15 @@ def bench_tpu_step_throughput() -> dict:
             float(chained(state, x, y))
             best = min(best, time.perf_counter() - t0)
         step_ms = best * 1e3 / 50
-        from robotic_discovery_platform_tpu.utils import flops as flops_lib
-
         step_flops = flops_lib.unet_train_step_flops(batch, IMG)
         out[f"batch{batch}"] = {
             "step_ms": round(step_ms, 3),
             "steps_per_s": round(1000.0 / step_ms, 2),
             "images_per_s": round(batch * 1000.0 / step_ms, 2),
             "compile_s": round(compile_s, 1),
-            # conv-only analytic FLOPs (3x forward for fwd+dx+dw) over the
-            # v5e bf16 peak -- utils/flops.py states the basis
-            "mfu": round(flops_lib.mfu(step_flops, step_ms / 1e3), 4),
+            # conv-only analytic FLOPs (3x forward for fwd+dx+dw) over this
+            # device's published bf16 peak (utils/flops.CHIP_PEAKS)
+            "mfu": round(flops_lib.mfu(step_flops, step_ms / 1e3, peaks), 4),
         }
     return out
 
@@ -197,6 +204,11 @@ def bench_torch_convergence() -> dict:
 def main() -> None:
     import tempfile
 
+    from robotic_discovery_platform_tpu.utils.platforms import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     only = sys.argv[1] if len(sys.argv) > 1 else "all"
     out_path = REPO / "TRAINBENCH.json"
     result = {}

@@ -394,6 +394,11 @@ def _merge(key: str, value: dict) -> dict:
 
 
 def main() -> None:
+    from robotic_discovery_platform_tpu.utils.platforms import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     cmd = sys.argv[1] if len(sys.argv) > 1 else "summary"
     if cmd == "data":
         if not TRAIN_DIR.exists():

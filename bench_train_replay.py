@@ -206,6 +206,11 @@ def bench_torch(data_dir: Path) -> dict:
 
 
 def main() -> None:
+    from robotic_discovery_platform_tpu.utils.platforms import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     only = sys.argv[1] if len(sys.argv) > 1 else "all"
     result = json.loads(OUT.read_text()) if OUT.exists() else {}
     result.setdefault("config", {

@@ -46,18 +46,19 @@ def clamped_uniform_knots(num_ctrl: int, degree: int = 3) -> np.ndarray:
 
 def _basis_columns(uu, knots, degree: int):
     """Cox-de Boor recursion on a COLUMN of parameters: ``uu`` is [N, 1],
-    ``knots`` an already-cast jnp vector. Shared verbatim by the XLA path
-    (:func:`bspline_basis`) and the fused Pallas kernels
+    ``knots`` an already-cast [1, K] jnp ROW (2-D throughout, static
+    non-negative slices only: what a TPU kernel can lower). Shared verbatim
+    by the XLA path (:func:`bspline_basis`) and the fused Pallas kernels
     (ops/pallas/geometry.py), so the two paths are the same ops and their
     results compare bitwise."""
-    n_knots = knots.shape[0]
+    n_knots = knots.shape[1]
     num_ctrl = n_knots - degree - 1
 
     # Degree-0: indicator of the half-open knot span, closed at the top so
     # u == 1 lands in the last nonempty span (FITPACK convention).
-    t_lo = knots[:-1][None, :]  # [1, n_knots-1]
-    t_hi = knots[1:][None, :]
-    last_span = t_hi >= knots[-1]
+    t_lo = knots[:, :-1]  # [1, n_knots-1]
+    t_hi = knots[:, 1:]
+    last_span = t_hi >= knots[:, n_knots - 1:]
     b = jnp.where(
         (uu >= t_lo) & ((uu < t_hi) | (last_span & (uu <= t_hi))),
         1.0,
@@ -68,10 +69,10 @@ def _basis_columns(uu, knots, degree: int):
 
     for d in range(1, degree + 1):
         n_b = n_knots - 1 - d  # number of degree-d functions
-        t_i = knots[:n_b][None, :]
-        t_id = knots[d : d + n_b][None, :]
-        t_i1 = knots[1 : 1 + n_b][None, :]
-        t_id1 = knots[d + 1 : d + 1 + n_b][None, :]
+        t_i = knots[:, :n_b]
+        t_id = knots[:, d : d + n_b]
+        t_i1 = knots[:, 1 : 1 + n_b]
+        t_id1 = knots[:, d + 1 : d + 1 + n_b]
         denom_l = t_id - t_i
         denom_r = t_id1 - t_i1
         left = jnp.where(denom_l > 0, (uu - t_i) / jnp.where(denom_l > 0, denom_l, 1.0), 0.0)
@@ -94,7 +95,7 @@ def bspline_basis(u, knots, degree: int = 3):
     """
     u = jnp.asarray(u)
     knots = jnp.asarray(knots, dtype=u.dtype)
-    return _basis_columns(u[:, None], knots, degree)
+    return _basis_columns(u[:, None], knots[None, :], degree)
 
 
 def _deriv_matrix_product(knots_np: np.ndarray, degree: int,

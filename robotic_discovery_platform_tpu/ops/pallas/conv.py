@@ -34,17 +34,11 @@ from robotic_discovery_platform_tpu.analysis.contracts import shape_contract
 
 
 def _element_block_spec(shape, index_map) -> pl.BlockSpec:
-    """A BlockSpec whose index_map returns ELEMENT offsets, across the two
-    Pallas APIs: newer jax spells it per-dimension (``pl.Element(d)``),
-    jax <= 0.4.x spells it ``indexing_mode=pl.Unblocked()`` for the whole
-    spec. The halo-slab input of the 3x3 kernel needs element indexing in
-    either spelling (overlapping row tiles cannot be expressed as block
-    indices)."""
-    if hasattr(pl, "Element"):
-        return pl.BlockSpec(
-            tuple(pl.Element(d) for d in shape), index_map
-        )
-    return pl.BlockSpec(shape, index_map, indexing_mode=pl.Unblocked())
+    """A BlockSpec whose index_map returns ELEMENT offsets
+    (``pl.Element`` per dimension). The halo-slab input of the 3x3 kernel
+    needs element indexing: overlapping row tiles cannot be expressed as
+    block indices."""
+    return pl.BlockSpec(tuple(pl.Element(d) for d in shape), index_map)
 
 
 def use_pallas() -> bool:
@@ -206,6 +200,14 @@ def conv3x3_bn_relu(
             raise ValueError(
                 f"tiling {tiling} does not divide (H={h}, Cout={cout})"
             )
+        need = vmem_bytes_3x3(tile_h, tile_co, width, cin, x.dtype.itemsize,
+                              jnp.dtype(out_dtype).itemsize)
+        if need > _VMEM_BUDGET:
+            raise ValueError(
+                f"tiling {tiling} needs an estimated {need} bytes of VMEM "
+                f"at W={width}, Cin={cin}; the kernel's budget is "
+                f"{_VMEM_BUDGET}"
+            )
     else:
         tile_h, tile_co = _tiles_3x3(
             h, width, cin, cout, x.dtype.itemsize,
@@ -244,6 +246,7 @@ def conv3x3_bn_relu(
         ),
         out_shape=jax.ShapeDtypeStruct((b * h, width, cout), out_dtype),
         interpret=interpret,
+        name="conv3x3_bn_relu",
     )(xp, w, sb)
     return out.reshape(b, h, width, cout)
 
@@ -345,6 +348,7 @@ def conv1x1(x, w, scale, bias, *, relu: bool = False, out_dtype=None,
             ),
             out_shape=jax.ShapeDtypeStruct((b, h, width), out_dtype),
             interpret=interpret,
+            name="conv1x1",
         )(x, w, sb)
         return out[..., None]
 
@@ -362,6 +366,7 @@ def conv1x1(x, w, scale, bias, *, relu: bool = False, out_dtype=None,
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, width, cout), out_dtype),
         interpret=interpret,
+        name="conv1x1",
     )(x, w, sb)
 
 
@@ -448,6 +453,7 @@ def conv_transpose2x2(x, w, bias, *, out_dtype=None, interpret: bool = False):
         ),
         out_shape=jax.ShapeDtypeStruct((b, 2 * h, 2 * width, cout), out_dtype),
         interpret=interpret,
+        name="conv_transpose2x2",
     )(x, w, bias2d)
 
 
@@ -522,11 +528,10 @@ def conv3x3_grad_weights(x, g, *, interpret: bool = False):
     Unlike the forward kernel, the overlapping halo slabs are materialized
     at the XLA level (one extra HBM copy of x, ~2/tile_h overhead) and the
     kernel uses standard block indexing. The pl.Element halo scheme the
-    forward kernel uses is NOT available here: this image's TPU compile
-    service crashes (HTTP 500, tpu_compile_helper exit 1) whenever an
-    Element-indexed dw kernel shares one XLA module with the forward
-    kernel -- as every backward pass does -- so the dw kernel avoids
-    Element indexing entirely.
+    forward kernel uses is not used here: under an earlier JAX the TPU
+    compiler crashed whenever an Element-indexed dw kernel shared one XLA
+    module with the forward kernel -- as every backward pass does (not
+    re-tested under jax 0.9.0).
 
     Args:
         x: [B, H, W, Cin] forward input.
@@ -535,10 +540,10 @@ def conv3x3_grad_weights(x, g, *, interpret: bool = False):
     b, h, width, cin = x.shape
     cout = g.shape[-1]
     if cin < 64:
-        # narrow lane dims (the RGB input layer) crash this image's
-        # compile helper at serving scale; zero-padded channels contribute
-        # exactly zero to the gradient, so pad up to a full lane tile and
-        # slice the result back (the layer is a negligible FLOP fraction)
+        # narrow lane dims (the RGB input layer) crashed the TPU compiler
+        # at serving scale under an earlier JAX; zero-padded channels
+        # contribute exactly zero to the gradient, so pad up to a full lane
+        # tile and slice the result back (a negligible FLOP fraction)
         x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, 64 - cin)))
         return conv3x3_grad_weights(x, g, interpret=interpret)[:, :, :cin]
     # VMEM accounting against the 16 MB scoped limit (observed error
@@ -583,6 +588,7 @@ def conv3x3_grad_weights(x, g, *, interpret: bool = False):
         out_specs=pl.BlockSpec((9, cin, tile_co), lambda co, s: (0, 0, co)),
         out_shape=jax.ShapeDtypeStruct((9, cin, cout), jnp.float32),
         interpret=interpret,
+        name="conv3x3_grad_weights",
     )(slabs, gf)
     return out.reshape(3, 3, cin, cout)
 
@@ -607,8 +613,8 @@ def _vjp_pallas(x, cin: int, cout: int, impl: str, interpret: bool) -> bool:
     - interpret always exercises the interpreted Pallas kernels (they are
       what the CPU tests exist to validate);
     - sub-sublane channel counts (the RGB input layer, its cout=3 dx conv,
-      and any dw whose lane dim would be < 8) crash this image's compile
-      helper at large batch; those layers are a negligible FLOP fraction
+      and any dw whose lane dim would be < 8) crashed the TPU compiler at
+      large batch under an earlier JAX; those layers are a negligible FLOP fraction
       and already sit at XLA boundaries, so they run the XLA forms under
       every COMPILED dispatch mode, forced "pallas" included;
     - measured v5e crossover for the TRAIN step (chained scan, 256^2):

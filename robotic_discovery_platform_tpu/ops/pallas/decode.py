@@ -26,8 +26,10 @@ tests/test_pallas_geometry.py idiom).
 
 Dispatch rides the same machinery as the geometry kernels:
 ``GeometryConfig.kernel_impl`` through :func:`geometry.resolve_impl` with
-the op key ``"jpeg_idct"``, so PALLAS_TUNE.json can pin either backend per
-(batch, blocks) shape.
+the op key ``"jpeg_idct"``. On the chip "auto" runs the XLA path: Mosaic
+refuses the kernel's int32 x int32 matmul (the v5e MXU has no int32
+accumulate; ``geometry.MOSAIC_REFUSES``), while XLA's own int32 dot is exact
+there -- the decode stays bitwise equal to libjpeg (chip_smoke.py checks it).
 """
 
 from __future__ import annotations
@@ -185,4 +187,5 @@ def dequant_idct(coefs, q, *, impl: str = "auto"):
         out_specs=pl.BlockSpec((1, tile_n, 64), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n, 64), jnp.int32),
         interpret=which == "interpret",
+        name="dequant_idct",
     )(cc, qq, jnp.asarray(m1), jnp.asarray(m2))

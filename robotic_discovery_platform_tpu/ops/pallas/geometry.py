@@ -9,9 +9,9 @@ emits as several HBM round trips:
   them five times for the masked min/max/count reductions that seed the
   binning. :func:`deproject_edge_stats` computes the maps AND the five
   reductions in ONE pass over the input tiles -- each pixel is read once,
-  the per-tile partials (one [1, 8] row per grid step) are folded outside
-  the kernel with order-independent min/max/integer-sum, so the result is
-  bitwise identical to the XLA reference path.
+  the per-tile partials (one aligned [8, 128] tile per grid step) are
+  folded outside the kernel with order-independent min/max/integer-sum, so
+  the result is bitwise identical to the XLA reference path.
 - **B-spline design matmuls** (ops/bspline.py): the Cox-de Boor basis
   matrix B [N, C] is materialized to HBM only to be immediately contracted
   into the [C, C] Gram matrix and [C, D] right-hand side.
@@ -24,7 +24,9 @@ emits as several HBM round trips:
 Every kernel mirrors the XLA reference path op for op (the basis recursion
 and curvature formula are the SAME shared helpers from ops/bspline.py), so
 tests/test_pallas_geometry.py compares them BITWISE on CPU in interpret
-mode. Dispatch is per-shape via :func:`resolve_impl`:
+mode. Compiled by Mosaic the two spline kernels agree with XLA to f32
+rounding only (the f32 matmul passes are ordered differently;
+chip_smoke.py measures it). Dispatch is per-shape via :func:`resolve_impl`:
 ``GeometryConfig.kernel_impl`` ("auto" = Pallas on TPU, XLA elsewhere) with
 the PALLAS_TUNE.json autotable able to veto or force a backend per
 (op, shape) -- the same measured-overlay convention as the conv tiles.
@@ -38,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from robotic_discovery_platform_tpu.ops.pallas.conv import (
     _pick_tile,
@@ -46,13 +49,28 @@ from robotic_discovery_platform_tpu.ops.pallas.conv import (
 
 KERNEL_IMPLS = ("auto", "pallas", "xla", "interpret")
 
+#: Ops whose Pallas kernel Mosaic refuses to compile on the one supported
+#: installation (jax 0.9.0 / libtpu 0.0.34, TPU v5e), with the compiler's
+#: reason. "auto" runs their XLA twin on every backend -- a stated, static
+#: choice (chip_smoke.py prints it), never a run-time fallback. The
+#: kernels and their interpret-mode tests stay for a later repair;
+#: ``kernel_impl="pallas"`` still pins them and surfaces Mosaic's error.
+MOSAIC_REFUSES = {
+    "jpeg_idct": (
+        "int32 x int32 matmul with int32 accumulation, which the v5e MXU "
+        "does not have: \"Mosaic failed to compile TPU kernel: Bad lhs/rhs "
+        "type: 'vector<480x128xi32>' 'vector<128x128xi32>'\" (tpu.matmul)"
+    ),
+}
+
 
 def resolve_impl(configured: str, op: str, **dims) -> str:
     """The backend one fused-geometry launch runs: "pallas", "interpret",
     or "xla".
 
     ``configured`` is ``GeometryConfig.kernel_impl``: "xla" / "pallas" /
-    "interpret" pin a path; "auto" runs Pallas on TPU and XLA elsewhere,
+    "interpret" pin a path; "auto" runs Pallas on TPU and XLA elsewhere --
+    except the ops in :data:`MOSAIC_REFUSES`, which run XLA everywhere --
     with a per-(op, shape) entry in the PALLAS_TUNE.json table able to
     override the default either way (the escape hatch for shapes where the
     measured kernel loses to XLA, exactly like the conv tile overrides).
@@ -64,6 +82,8 @@ def resolve_impl(configured: str, op: str, **dims) -> str:
         )
     if configured != "auto":
         return configured
+    if op in MOSAIC_REFUSES:
+        return "xla"
     from robotic_discovery_platform_tpu.ops.pallas import tuning
 
     table = tuning.lookup_impl(op, **dims)
@@ -75,27 +95,35 @@ def resolve_impl(configured: str, op: str, **dims) -> str:
 # -- deproject + masked edge-stats ------------------------------------------
 
 
-def _deproject_kernel(m_ref, d_ref, p_ref, x_ref, y_ref, z_ref, v_ref,
+#: per-tile stats ride one aligned f32 vreg tile; lanes 0..4 of every row
+#: hold x_min, x_max, y_min, y_max, n_valid
+_STATS_TILE = (8, 128)
+
+
+def _deproject_kernel(p_ref, m_ref, d_ref, x_ref, y_ref, z_ref, v_ref,
                       s_ref, *, tile_h, width, stride):
     """One row-tile grid step: maps + per-tile masked stats.
 
+    p_ref: [8] f32 parameters in SMEM (fx, fy, cx, cy, depth_scale, 0...).
     m_ref/d_ref: [tile_h, W] f32 mask/depth tiles (pre-cast by the
         wrapper: uint8/uint16 -> f32 is exact).
-    p_ref: [1, 8] f32 parameter row (fx, fy, cx, cy, depth_scale, 0...).
     x/y/z/v_ref: [tile_h, W] f32 output map tiles (v is 0/1).
-    s_ref: [1, 8] per-tile stats row: x_min, x_max, y_min, y_max, n_valid
+    s_ref: one [8, 128] stats tile per grid step (Mosaic stores no scalars
+        to VMEM, and a block's last two dims must tile by 8 x 128): lane k
+        of every row carries stat k -- x_min, x_max, y_min, y_max, n_valid
         (masked with the same +-1e30 sentinels as the XLA path, so folding
-        the rows with min/max/sum outside reproduces its values bitwise).
+        the tiles with min/max/sum outside reproduces its values).
     """
     i = pl.program_id(0)
-    fx, fy = p_ref[0, 0], p_ref[0, 1]
-    cx, cy = p_ref[0, 2], p_ref[0, 3]
-    ds = p_ref[0, 4]
+    fx, fy = p_ref[0], p_ref[1]
+    cx, cy = p_ref[2], p_ref[3]
+    ds = p_ref[4]
     off = (stride - 1) / 2.0
-    vv = (jax.lax.broadcasted_iota(jnp.float32, (tile_h, width), 0)
-          + i * tile_h) * stride + off
-    uu = jax.lax.broadcasted_iota(jnp.float32, (tile_h, width), 1) \
-        * stride + off
+    # integer iota (the TPU has no float iota); small ints convert exactly
+    rows = jax.lax.broadcasted_iota(jnp.int32, (tile_h, width), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (tile_h, width), 1)
+    vv = (rows + i * tile_h).astype(jnp.float32) * stride + off
+    uu = cols.astype(jnp.float32) * stride + off
     z = d_ref[:] * ds
     valid = (m_ref[:] > 0) & (z > 0)
     x = (uu - cx) * z / fx
@@ -105,12 +133,18 @@ def _deproject_kernel(m_ref, d_ref, p_ref, x_ref, y_ref, z_ref, v_ref,
     z_ref[:] = z
     v_ref[:] = valid.astype(jnp.float32)
     big = jnp.float32(1e30)
-    s_ref[:] = jnp.zeros((1, 8), jnp.float32)
-    s_ref[0, 0] = jnp.min(jnp.where(valid, x, big))
-    s_ref[0, 1] = jnp.max(jnp.where(valid, x, -big))
-    s_ref[0, 2] = jnp.min(jnp.where(valid, y, big))
-    s_ref[0, 3] = jnp.max(jnp.where(valid, y, -big))
-    s_ref[0, 4] = jnp.sum(valid.astype(jnp.float32))
+    stats = (
+        jnp.min(jnp.where(valid, x, big)),
+        jnp.max(jnp.where(valid, x, -big)),
+        jnp.min(jnp.where(valid, y, big)),
+        jnp.max(jnp.where(valid, y, -big)),
+        jnp.sum(valid.astype(jnp.float32)),
+    )
+    lane = jax.lax.broadcasted_iota(jnp.int32, _STATS_TILE, 1)
+    tile = jnp.zeros(_STATS_TILE, jnp.float32)
+    for k, stat in enumerate(stats):
+        tile = jnp.where(lane == k, stat, tile)
+    s_ref[:] = tile
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "interpret"))
@@ -126,43 +160,51 @@ def deproject_edge_stats(mask, depth, fx, fy, cx, cy, depth_scale, *,
             offset), same semantics as ops/geometry.deproject.
 
     Returns ``(x, y, z, valid_bool, (x_min, x_max, y_min, y_max,
-    n_valid_i32))`` -- bitwise identical to the XLA reference path
-    (``deproject`` + the inline reductions of ``_edge_points``): the maps
-    are the same elementwise f32 ops, and min/max/integer-count folds are
-    order-independent.
+    n_valid_i32))`` -- the same elementwise f32 ops as the XLA reference
+    path (``deproject`` + the inline reductions of ``_edge_points``) with
+    order-independent min/max/integer-count folds: bitwise identical to it
+    in interpret mode and, compiled by Mosaic on a v5e, on every frame
+    chip_smoke.py has compared.
     """
     h, width = depth.shape
     mf = jnp.asarray(mask).astype(jnp.float32)
     df = jnp.asarray(depth).astype(jnp.float32)
-    params = jnp.concatenate([
-        jnp.stack([
-            jnp.asarray(fx, jnp.float32), jnp.asarray(fy, jnp.float32),
-            jnp.asarray(cx, jnp.float32), jnp.asarray(cy, jnp.float32),
-            jnp.asarray(depth_scale, jnp.float32),
-        ]),
-        jnp.zeros((3,), jnp.float32),
-    ])[None, :]
-    tile_h = _pick_tile(h, 64)
-    tiles = h // tile_h
-    map_shape = jax.ShapeDtypeStruct((h, width), jnp.float32)
+    # row tiles must be whole 8-row f32 sublane tiles: pad H up (padded
+    # rows carry mask 0, so they are invalid and leave the stats alone)
+    hp = -(-h // 8) * 8
+    if hp != h:
+        mf = jnp.pad(mf, ((0, hp - h), (0, 0)))
+        df = jnp.pad(df, ((0, hp - h), (0, 0)))
+    params = jnp.stack([
+        jnp.asarray(fx, jnp.float32), jnp.asarray(fy, jnp.float32),
+        jnp.asarray(cx, jnp.float32), jnp.asarray(cy, jnp.float32),
+        jnp.asarray(depth_scale, jnp.float32),
+        *(jnp.zeros((), jnp.float32),) * 3,
+    ])
+    tile_h = 8 * _pick_tile(hp // 8, 8)
+    tiles = hp // tile_h
+    map_shape = jax.ShapeDtypeStruct((hp, width), jnp.float32)
     map_spec = pl.BlockSpec((tile_h, width), lambda i: (i, 0))
+    sr, sl = _STATS_TILE
     x, y, z, v, part = pl.pallas_call(
         functools.partial(_deproject_kernel, tile_h=tile_h, width=width,
                           stride=stride),
         grid=(tiles,),
         in_specs=[
-            pl.BlockSpec((tile_h, width), lambda i: (i, 0)),
-            pl.BlockSpec((tile_h, width), lambda i: (i, 0)),
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            map_spec,
+            map_spec,
         ],
         out_specs=[
             map_spec, map_spec, map_spec, map_spec,
-            pl.BlockSpec((1, 8), lambda i: (i, 0)),
+            pl.BlockSpec(_STATS_TILE, lambda i: (i, 0)),
         ],
         out_shape=[map_shape, map_shape, map_shape, map_shape,
-                   jax.ShapeDtypeStruct((tiles, 8), jnp.float32)],
+                   jax.ShapeDtypeStruct((tiles * sr, sl), jnp.float32)],
         interpret=interpret,
-    )(mf, df, params)
+        name="deproject_edge_stats",
+    )(params, mf, df)
+    part = part.reshape(tiles, sr, sl)[:, 0]
     stats = (
         jnp.min(part[:, 0]),
         jnp.max(part[:, 1]),
@@ -170,6 +212,8 @@ def deproject_edge_stats(mask, depth, fx, fy, cx, cy, depth_scale, *,
         jnp.max(part[:, 3]),
         jnp.sum(part[:, 4]).astype(jnp.int32),
     )
+    if hp != h:
+        x, y, z, v = (a[:h] for a in (x, y, z, v))
     return x, y, z, v > 0, stats
 
 
@@ -186,7 +230,7 @@ def _design_kernel(u_ref, w_ref, p_ref, k_ref, g_ref, r_ref, *, degree):
     from robotic_discovery_platform_tpu.ops import bspline
 
     uu = u_ref[:]  # [N, 1]
-    b = bspline._basis_columns(uu, k_ref[0, :], degree)  # [N, C]
+    b = bspline._basis_columns(uu, k_ref[:], degree)  # [N, C]
     bw = b * w_ref[:]  # weights ride in as [N, 1]
     g_ref[:] = bspline._mm(bw.T, b)
     r_ref[:] = bspline._mm(bw.T, p_ref[:])
@@ -228,6 +272,13 @@ def bspline_design(points, weights, u, knots, degree: int = 3,
             jax.ShapeDtypeStruct((num_ctrl, d), jnp.float32),
         ],
         interpret=interpret,
+        name="bspline_design",
+        # every [N, k] operand and basis temporary pads its minor dim to
+        # 128 lanes, ~N * 512 bytes each: Mosaic asked for 18.4 MB at
+        # N = 6400 against the 16 MB default scoped-VMEM limit
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=min(96, max(16, n * 4096 // 2**20)) * 2**20
+        ),
     )(
         jnp.asarray(u, jnp.float32)[:, None],
         jnp.asarray(weights, jnp.float32)[:, None],
@@ -248,7 +299,7 @@ def _curvature_kernel(c_ref, u_ref, k_ref, m1_ref, m2_ref, kap_ref, v_ref,
 
     uu = u_ref[:]  # [N, 1]
     ctrl = c_ref[:]
-    knots_j = k_ref[0, :]
+    knots_j = k_ref[:]  # [1, K]
     r = bspline._mm(bspline._basis_columns(uu, knots_j, degree), ctrl)
     b1 = bspline._basis_columns(uu, knots_j, degree - 1)
     r1 = bspline._mm(bspline._mm(b1, m1_ref[:]), ctrl)
@@ -298,6 +349,7 @@ def bspline_curvature(ctrl, u, knots, degree: int = 3,
             jax.ShapeDtypeStruct((n, d), jnp.float32),
         ],
         interpret=interpret,
+        name="bspline_curvature",
     )(
         jnp.asarray(ctrl, jnp.float32),
         jnp.asarray(u, jnp.float32)[:, None],

@@ -132,7 +132,7 @@ class PallasUNet:
 
     # -- forward --------------------------------------------------------
 
-    def _uniform_force(self, x) -> str:
+    def uniform_backend(self, batch: int, h: int, w: int) -> str:
         """ONE backend for the whole forward, per input shape (see the
         PALLAS_MAX_ELEMS comment): "pallas" or "xla"."""
         if self.force is not None:
@@ -143,8 +143,7 @@ class PallasUNet:
             return "pallas"
         if not pconv.use_pallas():
             return "xla"
-        b, h, w, _ = x.shape
-        widest = b * h * w * 2 * self.model.base_features
+        widest = batch * h * w * 2 * self.model.base_features
         return "pallas" if widest <= PALLAS_MAX_ELEMS else "xla"
 
     def _double_conv(self, x, taps, force):
@@ -179,7 +178,7 @@ class PallasUNet:
 
     def _forward(self, x):
         L = self._layers
-        force = self._uniform_force(x)
+        force = self.uniform_backend(*x.shape[:3])
         x = x.astype(self.model.dtype)
         x1 = self._double_conv(x, L["inc"], force)
         xs = [x1]

@@ -27,19 +27,6 @@ from robotic_discovery_platform_tpu.analysis import recompile
 from robotic_discovery_platform_tpu.parallel import mesh as mesh_lib
 from robotic_discovery_platform_tpu.utils import transferguard
 
-# shard_map API compat: jax >= 0.5 exposes jax.shard_map with replication
-# checking named check_vma; 0.4.x has jax.experimental.shard_map.shard_map
-# with the same check named check_rep. The per-device step mutates
-# batch-stat averages, so the check is off in both spellings.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NO_CHECK = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_NO_CHECK = {"check_rep": False}
-
-
 def _state_shardings(mesh: Mesh, state, tp: bool, tp_min_channels: int):
     """Sharding tree for TrainState: params (and matching opt_state moments)
     optionally tensor-parallel, counters replicated."""
@@ -197,12 +184,14 @@ def shard_map_train_step(mesh: Mesh, model, tx, loss_fn: Callable,
 
     def step(state, x, y):
         specs_state = jax.tree.map(lambda _: rep, state)
-        mapped = _shard_map(
+        # the per-device step mutates batch-stat averages, so the
+        # replication check is off
+        mapped = jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(specs_state, P("data"), P("data")),
             out_specs=(specs_state, rep),
-            **_SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         return mapped(state, x, y)
 

@@ -3,7 +3,7 @@
 The reference is strictly single-device (SURVEY.md section 2.3: no DP/TP/PP,
 no collective backend; the only IPC is gRPC). This module is where the
 TPU-native framework grows its distributed spine: a named
-``jax.sharding.Mesh`` whose axes carry the parallelism taxonomy --
+``jax.sharding.Mesh`` whose axes name the kinds of parallelism --
 
 - ``data``    data parallelism: batch sharding, gradient allreduce over ICI;
 - ``spatial`` spatial/context parallelism: H-dimension activation sharding

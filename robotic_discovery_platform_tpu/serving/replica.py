@@ -209,12 +209,26 @@ def spawn_local_replicas(
     which the front-end's federation + trace stitching scrape.
     ``registrars`` (comma-separated front-end endpoints) makes each
     replica self-register a membership lease on boot -- the elastic
-    path: the front-end needs no endpoint list for these members."""
+    path: the front-end needs no endpoint list for these members.
+
+    This harness is CPU-only BY ARGUMENT: ``force_cpu`` (>= 1 virtual CPU
+    devices per replica) is always passed down and ``JAX_PLATFORMS=cpu``
+    is set in every child's environment. A chip belongs to one process at
+    a time, and the parent of a local fleet has usually touched JAX
+    already (``register_tiny_model``), so children that reached for the
+    accelerator would fail or hang. A replica on a real host is started
+    as its own ``python -m ...serving.replica`` process instead."""
+    if force_cpu < 1:
+        raise ValueError(
+            "spawn_local_replicas boots CPU replicas only (force_cpu >= 1): "
+            "one process per chip -- start an accelerator replica as its "
+            "own process"
+        )
     replicas: list[LocalReplica] = []
     try:
         for i in range(n):
             env = dict(os.environ)
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            env["JAX_PLATFORMS"] = "cpu"
             env["PYTHONPATH"] = os.pathsep.join(
                 p for p in (_PKG_ROOT, env.get("PYTHONPATH")) if p
             )
@@ -234,8 +248,7 @@ def spawn_local_replicas(
                 argv += ["--registrars", registrars]
             if lease_ttl_s:
                 argv += ["--lease-ttl", str(lease_ttl_s)]
-            if force_cpu:
-                argv += ["--force-cpu", str(force_cpu)]
+            argv += ["--force-cpu", str(force_cpu)]
             if warmup is not None:
                 argv += ["--warmup", f"{warmup[0]}x{warmup[1]}"]
             proc, port = _spawn_one(argv, env, timeout_s)
@@ -343,18 +356,11 @@ def main(argv: list[str] | None = None) -> None:
                              "boot; the fleet's warm phase absorbs it)")
     cli = parser.parse_args(argv)
 
+    from robotic_discovery_platform_tpu.utils import platforms
+
     if cli.force_cpu:
-        from robotic_discovery_platform_tpu.utils.platforms import (
-            force_cpu_platform,
-        )
-
-        force_cpu_platform(min_devices=cli.force_cpu)
-    else:
-        from robotic_discovery_platform_tpu.utils.platforms import (
-            apply_env_platform,
-        )
-
-        apply_env_platform()
+        platforms.force_cpu_platform(min_devices=cli.force_cpu)
+    platforms.enable_compile_cache()
 
     from robotic_discovery_platform_tpu.serving import server as server_lib
 

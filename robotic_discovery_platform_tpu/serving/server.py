@@ -107,6 +107,7 @@ Overload control (serving/admission.py, serving/controller.py):
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 import os
@@ -165,6 +166,7 @@ from robotic_discovery_platform_tpu.utils.config import (
     GeometryConfig,
     ServerConfig,
 )
+from robotic_discovery_platform_tpu.utils import platforms
 from robotic_discovery_platform_tpu.utils.logging import get_logger
 from robotic_discovery_platform_tpu.utils.profiling import StageTimer
 
@@ -285,6 +287,10 @@ class Engine(NamedTuple):
     variables: Any
     dispatcher: Any
     version: int | None
+    #: the jitted batched analyzer the dispatcher's closures call (None
+    #: without micro-batching) -- kept so diagnostics can lower the very
+    #: program that serves (chip_smoke.py's implementation report)
+    batch_analyze: Any = None
 
 
 class VisionAnalysisService(vision_grpc.VisionAnalysisServiceServicer):
@@ -753,6 +759,17 @@ class VisionAnalysisService(vision_grpc.VisionAnalysisServiceServicer):
         return self._engine.dispatcher
 
     @property
+    def batch_analyze(self):
+        return self._engine.batch_analyze
+
+    def coef_analyzer(self, height: int, width: int,
+                      subsampling: str = "420"):
+        """The default model's coefficient-lane analyzer for one camera
+        geometry, as ``functools.partial(jitted_analyzer, variables)`` --
+        what the dispatcher memoizes per (geometry, subsampling)."""
+        return self._coef_factory_fn("", height, width, subsampling)
+
+    @property
     def current_version(self) -> int | None:
         return self._engine.version
 
@@ -803,6 +820,7 @@ class VisionAnalysisService(vision_grpc.VisionAnalysisServiceServicer):
             model, img_size=cfg.model_img_size, geom_cfg=geom_cfg,
             forward=forward,
         )
+        batch_geom_cfg = self._batch_geom_cfg()
 
         # Coefficient-lane analyzer factory (split JPEG decode): builds
         # the decode+analyze graph for one (geometry, subsampling), closed
@@ -819,20 +837,18 @@ class VisionAnalysisService(vision_grpc.VisionAnalysisServiceServicer):
                     f"model {model_key!r} frames must use pixel formats"
                 )
             coef_analyze = pipeline.make_coef_batch_analyzer(
-                _model, img_size=cfg.model_img_size, geom_cfg=geom_cfg,
+                _model, img_size=cfg.model_img_size, geom_cfg=batch_geom_cfg,
                 forward=_forward, height=height, width=width,
                 subsampling=subsampling, pack=cfg.egress_pack,
             )
-            return (lambda y, cb, cr, qy, qc, depths, intr, scales:
-                    coef_analyze(_variables, y, cb, cr, qy, qc, depths,
-                                 intr, scales))
+            return functools.partial(coef_analyze, _variables)
 
         self._coef_factory_fn = coef_factory
         with self._coef_direct_lock:
             # stale closures must not outlive the generation that built
             # them -- direct coef graphs rebuild lazily on first use
             self._coef_direct.clear()
-        dispatcher = None
+        dispatcher = batch_analyze = None
         if cfg.batch_window_ms > 0:
             from robotic_discovery_platform_tpu.serving.batching import (
                 BatchDispatcher,
@@ -851,7 +867,7 @@ class VisionAnalysisService(vision_grpc.VisionAnalysisServiceServicer):
             # is ONE [B, P] uint8 fetch per dispatch and dispatcher
             # results are serving/egress.PackedResult rows
             batch_analyze = make_batched(
-                model, img_size=cfg.model_img_size, geom_cfg=geom_cfg,
+                model, img_size=cfg.model_img_size, geom_cfg=batch_geom_cfg,
                 forward=forward, pack=cfg.egress_pack,
             )
             router = None
@@ -939,7 +955,20 @@ class VisionAnalysisService(vision_grpc.VisionAnalysisServiceServicer):
                             entry.per_chip_analyzers,
                             entry.sharded_analyzer,
                         )
-        return Engine(analyze, variables, dispatcher, version)
+        return Engine(analyze, variables, dispatcher, version,
+                      batch_analyze)
+
+    def _batch_geom_cfg(self) -> GeometryConfig:
+        """The geometry config of the BATCHED analyzers. Under a serving
+        mesh the forward's rule holds for their other kernels too (fused
+        geometry, mask pack): one jitted analyzer serves every placement of
+        the mesh, the sharded one included, and a sharded jit refuses them
+        ("Mosaic kernels cannot be automatically partitioned"). So "auto"
+        means XLA there; an explicit ``kernel_impl`` pin stands."""
+        if (self._serving_mesh is not None
+                and self.geom_cfg.kernel_impl == "auto"):
+            return dataclasses.replace(self.geom_cfg, kernel_impl="xla")
+        return self.geom_cfg
 
     @staticmethod
     def _build_forward(model, variables, cfg: ServerConfig):
@@ -1043,7 +1072,8 @@ class VisionAnalysisService(vision_grpc.VisionAnalysisServiceServicer):
                             if cfg.batch_impl == "dense"
                             else pipeline.make_scan_batch_analyzer)
             batched = make_batched(
-                model_q, img_size=cfg.model_img_size, geom_cfg=geom_cfg,
+                model_q, img_size=cfg.model_img_size,
+                geom_cfg=self._batch_geom_cfg(),
                 pack=cfg.egress_pack,
             )
             batch_analyze = (
@@ -2190,6 +2220,7 @@ def build_server(
     ``geom_cfg`` defaults to the serving geometry profile
     (``stride=cfg.geometry_stride``); pass an explicit GeometryConfig to
     override (e.g. stride=1 for reference-exact dense semantics)."""
+    platforms.enable_compile_cache()
     if geom_cfg is None:
         geom_cfg = GeometryConfig(stride=cfg.geometry_stride)
     # this process serves frames: spans and journal events it records are

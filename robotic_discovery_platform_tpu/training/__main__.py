@@ -10,8 +10,8 @@ Usage:
 
 With ``--mesh.data/--mesh.spatial/--mesh.model`` sizes >1 the run shards
 over the device mesh (parallel/); ``--resume`` restores the latest orbax
-checkpoint under ``train.checkpoint_dir``. Honors an inherited
-``JAX_PLATFORMS`` pin before any backend discovery (utils/platforms.py).
+checkpoint under ``train.checkpoint_dir``. Returns the process exit code:
+0 on success, 2 on a config/dataset error.
 """
 
 from __future__ import annotations
@@ -21,12 +21,12 @@ import json
 import sys
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
     from robotic_discovery_platform_tpu.utils.platforms import (
-        apply_env_platform,
+        enable_compile_cache,
     )
 
-    apply_env_platform()
+    enable_compile_cache()
 
     from robotic_discovery_platform_tpu.utils import config as config_lib
 

@@ -14,8 +14,8 @@ Two save paths:
   lockstep).
 - ``save_async``: single-process overlap. The caller hands an
   INDEPENDENT on-device snapshot (the trainer's ``_copy_tree``); a single
-  worker thread then pays the host fetch (the dominant cost through this
-  image's ~110 ms relay: ~350 MB of params+optimizer+best-candidate) and
+  worker thread then pays the host fetch (~350 MB of
+  params+optimizer+best-candidate at full width) and
   the disk write while the next epoch's compute runs on the chip. One
   save in flight at a time; ``wait``/``close`` drain.
 """

@@ -10,31 +10,54 @@ convention used by the standard MFU literature, which counts matmul FLOPs
 only). The count is validated against XLA's own ``cost_analysis`` in
 tests/test_pallas.py.
 
-Peak basis: TPU v5e, 197 TFLOP/s dense bf16 (394 TOPS int8), the figure
-published for v5e in Google's accelerator documentation. MFU numbers quote
-this constant explicitly so they can be re-based for other chips.
+Peaks live in ONE table, :data:`CHIP_PEAKS`, keyed by the ``device_kind``
+JAX reports. Every utilization or roofline figure names the device it is
+taken against: callers look the device up with :func:`chip_peaks`, and a
+device that is not in the table is an error, never a default.
 """
 
 from __future__ import annotations
 
-V5E_PEAK_BF16_TFLOPS = 197.0
-# HBM bandwidth of one v5e chip (public spec: 819 GB/s). Used for
-# per-kernel roofline bounds: a launch cannot run faster than
-# max(flops / peak, bytes_moved / bandwidth).
-V5E_HBM_GBPS = 819.0
+from dataclasses import dataclass
 
 
-def roofline_ms(flops: int, bytes_moved: int,
-                peak_tflops: float = V5E_PEAK_BF16_TFLOPS,
-                hbm_gbps: float = V5E_HBM_GBPS) -> dict:
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks a roofline is drawn against."""
+
+    bf16_tflops: float  # dense bf16 matmul peak, TFLOP/s
+    hbm_gbps: float  # HBM bandwidth, GB/s
+
+
+#: ``jax.devices()[0].device_kind`` -> peaks. Source: Google Cloud
+#: documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM per chip).
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(bf16_tflops=197.0, hbm_gbps=819.0),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks for one ``device_kind``; an unknown device is an error."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r} "
+            f"(known: {sorted(CHIP_PEAKS)}); add it to "
+            "utils/flops.CHIP_PEAKS with its source before reporting "
+            "utilization against it"
+        ) from None
+
+
+def roofline_ms(flops: int, bytes_moved: int, peaks: ChipPeaks) -> dict:
     """Roofline lower bound for one kernel launch: compute time at the
     chip's dense peak vs memory time for the given minimal HBM traffic.
     A launch cannot run faster than ``max(compute_ms, memory_ms)``; real
     traffic (halos, re-reads) is strictly larger than the minimum the
     callers count, so the bound is optimistic and 'percent of bound' is a
     conservative utilization figure."""
-    compute_ms = flops / (peak_tflops * 1e12) * 1e3
-    memory_ms = bytes_moved / (hbm_gbps * 1e9) * 1e3
+    compute_ms = flops / (peaks.bf16_tflops * 1e12) * 1e3
+    memory_ms = bytes_moved / (peaks.hbm_gbps * 1e9) * 1e3
     return {
         "flops": flops,
         "bytes": bytes_moved,
@@ -46,7 +69,8 @@ def roofline_ms(flops: int, bytes_moved: int,
 
 
 def conv3x3_roofline_ms(h: int, w: int, cin: int, cout: int,
-                        batch: int = 1, itemsize: int = 2) -> dict:
+                        batch: int = 1, itemsize: int = 2, *,
+                        peaks: ChipPeaks) -> dict:
     """Roofline for one fused 3x3 conv+BN+ReLU launch: minimal traffic is
     read input once, read weights once, write output once."""
     return roofline_ms(
@@ -54,23 +78,27 @@ def conv3x3_roofline_ms(h: int, w: int, cin: int, cout: int,
         itemsize * (
             batch * h * w * cin + 9 * cin * cout + batch * h * w * cout
         ),
+        peaks,
     )
 
 
 def conv1x1_roofline_ms(h: int, w: int, cin: int, cout: int,
-                        batch: int = 1, itemsize: int = 2) -> dict:
+                        batch: int = 1, itemsize: int = 2, *,
+                        peaks: ChipPeaks) -> dict:
     """Roofline for the fused 1x1 head launch."""
     return roofline_ms(
         2 * batch * h * w * cin * cout,
         itemsize * (
             batch * h * w * cin + cin * cout + batch * h * w * cout
         ),
+        peaks,
     )
 
 
 def conv_transpose2x2_roofline_ms(h: int, w: int, cin: int, cout: int,
                                   batch: int = 1,
-                                  itemsize: int = 2) -> dict:
+                                  itemsize: int = 2, *,
+                                  peaks: ChipPeaks) -> dict:
     """Roofline for the 2x2 stride-2 transposed-conv launch (each INPUT
     pixel spawns four taps; output is [2H, 2W])."""
     return roofline_ms(
@@ -79,21 +107,22 @@ def conv_transpose2x2_roofline_ms(h: int, w: int, cin: int, cout: int,
             batch * h * w * cin + 4 * cin * cout
             + batch * 4 * h * w * cout
         ),
+        peaks,
     )
 
 
-def deproject_roofline_ms(h: int, w: int) -> dict:
+def deproject_roofline_ms(h: int, w: int, *, peaks: ChipPeaks) -> dict:
     """Roofline for the fused deproject+edge-stats kernel
     (ops/pallas/geometry.py): ~12 VPU ops per pixel (two iota builds, the
     z/x/y formulas, the validity test, five masked reductions) against
     reading mask+depth once (f32) and writing the four maps once.
     Bandwidth-bound by construction -- the kernel's whole purpose is
     collapsing the XLA chain's multiple HBM passes into one."""
-    return roofline_ms(12 * h * w, 4 * (2 * h * w + 4 * h * w))
+    return roofline_ms(12 * h * w, 4 * (2 * h * w + 4 * h * w), peaks)
 
 
 def bspline_design_roofline_ms(n: int, c: int, d: int = 3,
-                               degree: int = 3) -> dict:
+                               degree: int = 3, *, peaks: ChipPeaks) -> dict:
     """Roofline for the fused B-spline design kernel: the Cox-de Boor
     recursion (~8 VPU ops per (point, basis-function) per level) plus the
     two MXU contractions, against reading u/w/points once and writing the
@@ -105,11 +134,13 @@ def bspline_design_roofline_ms(n: int, c: int, d: int = 3,
     return roofline_ms(
         basis_flops + mm_flops,
         4 * (n * (2 + d) + c * c + c * d),
+        peaks,
     )
 
 
 def bspline_curvature_roofline_ms(n: int, c: int, d: int = 3,
-                                  degree: int = 3) -> dict:
+                                  degree: int = 3, *,
+                                  peaks: ChipPeaks) -> dict:
     """Roofline for the fused curvature kernel: three basis builds, three
     design+evaluate matmul chains, and the cross/norm formula (~40 VPU
     ops per sample), against ctrl+u in / kappa+valid+r out."""
@@ -118,20 +149,23 @@ def bspline_curvature_roofline_ms(n: int, c: int, d: int = 3,
     return roofline_ms(
         basis_flops + mm_flops + 40 * n,
         4 * (c * d + n + n * (2 + d)),
+        peaks,
     )
 
 
-def jpeg_dequant_roofline_ms(n_blocks: int, batch: int = 1) -> dict:
+def jpeg_dequant_roofline_ms(n_blocks: int, batch: int = 1, *,
+                             peaks: ChipPeaks) -> dict:
     """Roofline for the standalone dequantize stage (one int multiply per
     coefficient against the broadcast [64] quant row): read int16
     coefficients, write int32 products. Counted separately only for the
     analytic table -- the shipped kernel fuses it into the IDCT matmuls,
     which is why the fused bound below charges the int16 read once."""
     n = batch * n_blocks * 64
-    return roofline_ms(n, 2 * n + 4 * n)
+    return roofline_ms(n, 2 * n + 4 * n, peaks)
 
 
-def jpeg_idct_roofline_ms(n_blocks: int, batch: int = 1) -> dict:
+def jpeg_idct_roofline_ms(n_blocks: int, batch: int = 1, *,
+                          peaks: ChipPeaks) -> dict:
     """Roofline for the fused dequant+IDCT launch
     (ops/pallas/decode.dequant_idct): two [N, 64] x [64, 64] integer basis
     matmuls per pass over the block axis (islow's two passes), plus the
@@ -148,33 +182,37 @@ def jpeg_idct_roofline_ms(n_blocks: int, batch: int = 1) -> dict:
     return roofline_ms(
         matmul_flops + elementwise_flops,
         2 * n * 64 + 2 * 64 + 4 * n * 64,
+        peaks,
     )
 
 
 def chroma_upsample_roofline_ms(h: int, w: int, batch: int = 1,
-                                subsampling: str = "420") -> dict:
+                                subsampling: str = "420", *,
+                                peaks: ChipPeaks) -> dict:
     """Roofline for the fancy (triangle) chroma upsample of both chroma
     planes to the [H, W] luma grid: ~6 integer VPU ops per output sample
     (two neighbor adds, two scaled sums, bias, shift) per plane, against
     reading the subsampled planes and writing the full-resolution ones."""
     if subsampling == "444":
-        return roofline_ms(0, 0)
+        return roofline_ms(0, 0, peaks)
     div = 4 if subsampling == "420" else 2
     in_px = 2 * batch * h * w // div
     out_px = 2 * batch * h * w
-    return roofline_ms(6 * out_px, 4 * (in_px + out_px))
+    return roofline_ms(6 * out_px, 4 * (in_px + out_px), peaks)
 
 
-def ycbcr_to_rgb_roofline_ms(h: int, w: int, batch: int = 1) -> dict:
+def ycbcr_to_rgb_roofline_ms(h: int, w: int, batch: int = 1, *,
+                             peaks: ChipPeaks) -> dict:
     """Roofline for the fixed-point YCbCr->RGB convert + clamp: ~12
     integer VPU ops per pixel against reading three int32 planes and
     writing the uint8 RGB image."""
     px = batch * h * w
-    return roofline_ms(12 * px, 4 * 3 * px + 3 * px)
+    return roofline_ms(12 * px, 4 * 3 * px + 3 * px, peaks)
 
 
 def jpeg_decode_roofline_ms(h: int, w: int, batch: int = 1,
-                            subsampling: str = "420") -> dict:
+                            subsampling: str = "420", *,
+                            peaks: ChipPeaks) -> dict:
     """Combined roofline for the whole on-chip decode stage
     (ops/pipeline.decode_coef_batch): dequant+IDCT over every block of all
     three components, chroma upsample, color convert. The gate
@@ -186,16 +224,19 @@ def jpeg_decode_roofline_ms(h: int, w: int, batch: int = 1,
     mcuy = -(-h // (8 * sv))
     blocks_y = (mcuy * sv) * (mcux * sh)
     blocks_c = 2 * mcuy * mcux
-    idct = jpeg_idct_roofline_ms(blocks_y + blocks_c, batch)
-    ups = chroma_upsample_roofline_ms(h, w, batch, subsampling)
-    ycc = ycbcr_to_rgb_roofline_ms(h, w, batch)
+    idct = jpeg_idct_roofline_ms(blocks_y + blocks_c, batch, peaks=peaks)
+    ups = chroma_upsample_roofline_ms(h, w, batch, subsampling,
+                                      peaks=peaks)
+    ycc = ycbcr_to_rgb_roofline_ms(h, w, batch, peaks=peaks)
     return roofline_ms(
         idct["flops"] + ups["flops"] + ycc["flops"],
         idct["bytes"] + ups["bytes"] + ycc["bytes"],
+        peaks,
     )
 
 
-def mask_bitpack_roofline_ms(h: int, w: int, batch: int = 1) -> dict:
+def mask_bitpack_roofline_ms(h: int, w: int, batch: int = 1, *,
+                             peaks: ChipPeaks) -> dict:
     """Roofline for the egress mask bitpack (ops/pallas/pack.bitpack_mask):
     ~2 integer VPU ops per input pixel (the nonzero test and one
     shift-accumulate step of the unrolled 8-way reduction), against
@@ -206,7 +247,7 @@ def mask_bitpack_roofline_ms(h: int, w: int, batch: int = 1) -> dict:
     and the D2H payload it buys shrinks 8x (bench_pallas.py asserts the
     bound class)."""
     px = batch * h * w
-    return roofline_ms(2 * px, px + batch * h * ((w + 7) // 8))
+    return roofline_ms(2 * px, px + batch * h * ((w + 7) // 8), peaks)
 
 
 def unet_forward_flops(img_size: int = 256, base: int = 64,
@@ -265,7 +306,6 @@ def unet_train_step_flops(batch: int, img_size: int = 256, base: int = 64,
     )
 
 
-def mfu(flops: int, seconds: float,
-        peak_tflops: float = V5E_PEAK_BF16_TFLOPS) -> float:
-    """Fraction of peak: (flops / seconds) / peak."""
-    return (flops / max(seconds, 1e-12)) / (peak_tflops * 1e12)
+def mfu(flops: int, seconds: float, peaks: ChipPeaks) -> float:
+    """Fraction of the chip's bf16 peak: (flops / seconds) / peak."""
+    return (flops / max(seconds, 1e-12)) / (peaks.bf16_tflops * 1e12)

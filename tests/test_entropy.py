@@ -328,10 +328,12 @@ def test_decode_rooflines_are_bandwidth_bound_at_serving_shapes():
     rides the analyzer's HBM streams rather than competing for MXU."""
     from robotic_discovery_platform_tpu.utils import flops as flops_lib
 
+    peaks = flops_lib.chip_peaks("TPU v5 lite")  # the serving chip
     for b in (1, 8):
         roof = flops_lib.jpeg_decode_roofline_ms(480, 640, batch=b,
-                                                 subsampling="420")
+                                                 subsampling="420",
+                                                 peaks=peaks)
         assert roof["bound_by"] == "memory", roof
         assert roof["flops"] > 0 and roof["bytes"] > 0
-    idct = flops_lib.jpeg_idct_roofline_ms(4800, batch=8)
+    idct = flops_lib.jpeg_idct_roofline_ms(4800, batch=8, peaks=peaks)
     assert idct["bound_by"] == "memory", idct

@@ -141,7 +141,7 @@ def test_dispatch_policy():
     got = _dispatch_3x3(x, k, s, b, relu=True, interpret=False, force=None)
     want = conv3x3_bn_relu_xla(x, k, s, b)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
-    # uniform whole-net rule (PallasUNet._uniform_force): widest-layer
+    # uniform whole-net rule (PallasUNet.uniform_backend): widest-layer
     # volume b*h*w*(2*base) against the measured crossover
     assert 1 * 256 * 256 * 128 <= PALLAS_MAX_ELEMS  # serving B=1: pallas
     assert 4 * 256 * 256 * 128 > PALLAS_MAX_ELEMS  # batched B>=4: XLA

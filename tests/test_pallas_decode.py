@@ -76,20 +76,24 @@ def test_dequant_idct_dc_only_block_is_flat():
     assert int(out[0]) == 136  # 128 + round(16*4 / 8)
 
 
-def test_resolve_impl_tuning_table_dispatch(monkeypatch):
+def test_resolve_impl_routes_refused_op_to_xla(monkeypatch):
+    """Mosaic refuses the int32 matmul (geometry.MOSAIC_REFUSES), so "auto"
+    is XLA on every backend -- statically, whatever the tune table says;
+    an explicit pin still reaches the kernel."""
     from robotic_discovery_platform_tpu.ops.pallas.geometry import (
+        MOSAIC_REFUSES,
         resolve_impl,
     )
 
+    assert "jpeg_idct" in MOSAIC_REFUSES
     key = tuning.op_key("jpeg_idct", b=8, n=4800)
     monkeypatch.setattr(tuning, "_cache", {key: {"impl": "pallas"}})
-    assert resolve_impl("auto", "jpeg_idct", b=8, n=4800) == "pallas"
-    # malformed entries are ignored; auto on CPU falls back to XLA
-    monkeypatch.setattr(tuning, "_cache", {key: {"impl": "gpu"}})
     assert resolve_impl("auto", "jpeg_idct", b=8, n=4800) == "xla"
     monkeypatch.setattr(tuning, "_cache", {})
     assert resolve_impl("auto", "jpeg_idct", b=8, n=4800) == "xla"
     assert resolve_impl("xla", "jpeg_idct", b=1, n=1) == "xla"
+    assert resolve_impl("pallas", "jpeg_idct", b=1, n=1) == "pallas"
+    assert resolve_impl("interpret", "jpeg_idct", b=1, n=1) == "interpret"
 
 
 # -- whole decode stage ------------------------------------------------------
