@@ -21,6 +21,10 @@ which surfaces as a trace-time error at the call that retraced.
 
 ``budget=None`` means the module default (:data:`DEFAULT_BUDGET`, 1):
 a hot path that has not declared a budget is expected to compile once.
+
+Every counted trace also increments ``rdp_jit_traces_total{fn=<name>}``
+and runs under a ``jax.profiler`` host span ``rdp.jit.trace`` (stat
+``fn``), so a profile shows which call re-traced and for how long.
 """
 
 from __future__ import annotations
@@ -31,6 +35,7 @@ import threading
 from contextlib import contextmanager
 from typing import Any, Callable
 
+from robotic_discovery_platform_tpu.observability import instruments as obs
 from robotic_discovery_platform_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
@@ -131,6 +136,7 @@ def trace_guard(
                 stats.traces += 1
                 stats.shapes.append(signature)
                 traces = stats.traces
+            obs.JIT_TRACES.labels(fn=name).inc()
             limit = stats.effective_budget
             if traces > limit:
                 msg = (
@@ -144,7 +150,12 @@ def trace_guard(
                 if _resolve_strict():
                     raise RecompileBudgetExceeded(msg)
                 log.warning(msg)
-            return fn(*args, **kwargs)
+            import jax
+
+            # a host span of the profiler's trace around the traced Python:
+            # the PjitFunction span that holds one is a call that traced
+            with jax.profiler.TraceAnnotation("rdp.jit.trace", fn=name):
+                return fn(*args, **kwargs)
 
         wrapper.__trace_guard__ = stats
         return wrapper

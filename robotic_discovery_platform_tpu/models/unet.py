@@ -291,23 +291,38 @@ class UNet(nn.Module):
         x = x.astype(self.dtype)
         kw = dict(norm=self.norm, dtype=self.dtype,
                   weight_init=self.weight_init, conv_impl=self.conv_impl)
-        x1 = DoubleConv(f, **kw)(x, train)
-        x2 = Down(f * 2, **kw)(x1, train)
-        x3 = Down(f * 4, **kw)(x2, train)
-        x4 = Down(f * 8, **kw)(x3, train)
-        x5 = Down(f * 16 // factor, **kw)(x4, train)
-        y = Up(f * 8 // factor, self.bilinear, **kw)(x5, x4, train)
-        y = Up(f * 4 // factor, self.bilinear, **kw)(y, x3, train)
-        y = Up(f * 2 // factor, self.bilinear, **kw)(y, x2, train)
-        y = Up(f, self.bilinear, **kw)(y, x1, train)
+        # one stable scope per block (the reference's block names,
+        # pkg/segmentation_model.py:98-107): part of each operation's
+        # op_name, whatever the compiler names its fusions (PERF.md
+        # section 3 says where a profile keeps it)
+        scope = jax.named_scope
+        with scope("rdp.unet.inc"):
+            x1 = DoubleConv(f, **kw)(x, train)
+        with scope("rdp.unet.down1"):
+            x2 = Down(f * 2, **kw)(x1, train)
+        with scope("rdp.unet.down2"):
+            x3 = Down(f * 4, **kw)(x2, train)
+        with scope("rdp.unet.down3"):
+            x4 = Down(f * 8, **kw)(x3, train)
+        with scope("rdp.unet.down4"):
+            x5 = Down(f * 16 // factor, **kw)(x4, train)
+        with scope("rdp.unet.up1"):
+            y = Up(f * 8 // factor, self.bilinear, **kw)(x5, x4, train)
+        with scope("rdp.unet.up2"):
+            y = Up(f * 4 // factor, self.bilinear, **kw)(y, x3, train)
+        with scope("rdp.unet.up3"):
+            y = Up(f * 2 // factor, self.bilinear, **kw)(y, x2, train)
+        with scope("rdp.unet.up4"):
+            y = Up(f, self.bilinear, **kw)(y, x1, train)
         # 1x1 head: the only conv with a bias (reference OutConv,
         # pkg/segmentation_model.py:78-84); fan_in = in_features * 1 * 1
-        logits = nn.Conv(
-            self.num_classes, (1, 1), dtype=self.dtype,
-            kernel_init=_kernel_init(self.weight_init),
-            bias_init=_bias_init(self.weight_init, y.shape[-1]),
-        )(y)
-        return logits.astype(jnp.float32)
+        with scope("rdp.unet.head"):
+            logits = nn.Conv(
+                self.num_classes, (1, 1), dtype=self.dtype,
+                kernel_init=_kernel_init(self.weight_init),
+                bias_init=_bias_init(self.weight_init, y.shape[-1]),
+            )(y)
+            return logits.astype(jnp.float32)
 
 
 def with_compute_dtype(model: UNet, dtype: DType) -> UNet:

@@ -23,6 +23,7 @@ from robotic_discovery_platform_tpu.observability import (
 from robotic_discovery_platform_tpu.observability.registry import (
     REGISTRY,
 )
+from robotic_discovery_platform_tpu.utils.profiling import StageTimer
 
 # -- serving -----------------------------------------------------------------
 
@@ -666,11 +667,52 @@ HTTP_REQUESTS = REGISTRY.histogram(
 TRAIN_STEP = REGISTRY.histogram(
     families.TRAIN_STEP,
     "Mean optimizer-step wall time, observed once per epoch (whole-epoch "
-    "scan dispatches have no per-step boundary to time).",
+    "scan dispatches have no per-step boundary to time). The first epoch "
+    "of a train_model call includes the re-trace, re-lowering and "
+    "compile-or-cache-load of the call's freshly built jitted runners.",
 )
 TRAIN_RATE = REGISTRY.gauge(
     families.TRAIN_RATE,
     "Training throughput over the last epoch's train phase.",
+)
+TRAIN_PHASE = REGISTRY.histogram(
+    families.TRAIN_PHASE,
+    "Wall time of one phase of a train_model call, by the phase's span "
+    "name (rdp.train.job is the whole call, its children tile it; "
+    "rdp.train.checkpoint.fetch/.write run on the checkpoint thread, "
+    "rdp.loader.decode on loader threads). The same stages are host "
+    "spans of a jax.profiler trace taken around the job.",
+    ("phase",),
+    buckets=tuple(0.001 * 4**k for k in range(10)),  # 1 ms .. 262 s
+)
+#: The retraining job's one stage timer, shared by training/trainer.py,
+#: checkpoint.py and data.py: ``TRAIN_PHASES.stage("rdp.train.steps")`` is
+#: a profiler host span and a sample of TRAIN_PHASE at once.
+TRAIN_PHASES = StageTimer(
+    observer=lambda phase, seconds:
+    TRAIN_PHASE.labels(phase=phase).observe(seconds)
+)
+
+# -- compilation (analysis/recompile.py, utils/platforms.py) -----------------
+
+JIT_TRACES = REGISTRY.counter(
+    families.JIT_TRACES,
+    "Traces (jit-cache misses) of a trace-guarded hot function, by guard "
+    "name: every one is followed by a lowering and a compile or a "
+    "persistent-cache load.",
+    ("fn",),
+)
+JIT_SECONDS = REGISTRY.counter(
+    families.JIT_SECONDS,
+    "Seconds JAX reports for each stage of making an executable "
+    "(jaxpr_trace, jaxpr_to_mlir_module, backend_compile: the last is the "
+    "compile or the persistent-cache load), summed over the process.",
+    ("stage",),
+)
+COMPILE_CACHE = REGISTRY.counter(
+    families.COMPILE_CACHE,
+    "Persistent compilation cache lookups, by result (hit, miss).",
+    ("result",),
 )
 
 _BREAKER_STATE_VALUES = {"closed": 0, "open": 1, "half_open": 2}

@@ -636,6 +636,7 @@ def _vjp_pallas(x, cin: int, cout: int, impl: str, interpret: bool) -> bool:
     return use_pallas() and small
 
 
+@jax.named_scope("rdp.conv3x3")
 def _conv3x3_raw(x, w, impl: str, interpret: bool):
     cin, cout = w.shape[2], w.shape[3]
     unit = jnp.ones((cout,), jnp.float32)
@@ -656,6 +657,9 @@ def conv3x3(x, w, impl: str = "auto", interpret: bool = False):
 
     ``impl``: "auto" (Pallas on TPU, XLA elsewhere), "pallas", or "xla" --
     the same measured-dispatch convention as the inference path.
+
+    Forward, dx and dw run under the named scope ``rdp.conv3x3``, whichever
+    implementation the dispatch picks.
     """
     return _conv3x3_raw(x, w, impl, interpret)
 
@@ -664,6 +668,7 @@ def _conv3x3_fwd(x, w, impl, interpret):
     return _conv3x3_raw(x, w, impl, interpret), (x, w)
 
 
+@jax.named_scope("rdp.conv3x3")
 def _conv3x3_bwd(impl, interpret, res, g):
     x, w = res
     g = g.astype(x.dtype)

@@ -29,6 +29,8 @@ from typing import Any
 import jax
 import orbax.checkpoint as ocp
 
+from robotic_discovery_platform_tpu.observability import instruments as obs
+
 
 class CheckpointManager:
     def __init__(self, directory: str | Path, keep: int = 3):
@@ -56,9 +58,11 @@ class CheckpointManager:
 
         def work():
             try:
-                host = jax.device_get(state)
-                self._mgr.save(step, args=ocp.args.StandardSave(host))
-                self._mgr.wait_until_finished()
+                with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.fetch"):
+                    host = jax.device_get(state)
+                with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.write"):
+                    self._mgr.save(step, args=ocp.args.StandardSave(host))
+                    self._mgr.wait_until_finished()
             except BaseException as exc:  # surfaced by the next wait()
                 self._pending_error = exc
 

@@ -32,6 +32,8 @@ from pathlib import Path
 
 import numpy as np
 
+from robotic_discovery_platform_tpu.observability import instruments as obs
+
 
 class PairedSegmentationData:
     """File-pair dataset (reference: SegmentationDataset,
@@ -224,11 +226,15 @@ class StreamingBatches:
 
     def _decode_batch(self, pool: ThreadPoolExecutor, idx: np.ndarray):
         s = self.dataset.img_size
-        xs = np.empty((len(idx), s, s, 3), np.float32)
-        ys = np.empty((len(idx), s, s, 1), np.float32)
-        loaded = pool.map(self.dataset.load, (self.names[i] for i in idx))
-        for i, (x, y) in enumerate(loaded):
-            xs[i], ys[i] = x, y
+        # on the prefetch thread: one batch's decode + resize, fanned out
+        # over the pool
+        with obs.TRAIN_PHASES.stage("rdp.loader.decode"):
+            xs = np.empty((len(idx), s, s, 3), np.float32)
+            ys = np.empty((len(idx), s, s, 1), np.float32)
+            loaded = pool.map(
+                self.dataset.load, (self.names[i] for i in idx))
+            for i, (x, y) in enumerate(loaded):
+                xs[i], ys[i] = x, y
         return xs, ys
 
     def __iter__(self):

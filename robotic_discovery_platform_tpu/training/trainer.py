@@ -42,6 +42,11 @@ from robotic_discovery_platform_tpu.utils.logging import get_logger
 
 log = get_logger(__name__)
 
+#: The retraining job's phases (``rdp.train.*``, README "Profiling a
+#: retraining job"): each is a host span of a ``jax.profiler`` trace and a
+#: sample of ``rdp_train_phase_seconds{phase}``.
+phases = obs.TRAIN_PHASES
+
 
 class TrainState(struct.PyTreeNode):
     """Params + optimizer + norm statistics + progress counters, one pytree
@@ -71,20 +76,27 @@ def core_train_step(model, tx, loss_fn: Callable):
     with explicit shardings, the single-device path with plain jit."""
 
     def step(state: TrainState, x, y):
+        # named scopes go into the operations' op_name metadata; the
+        # backward pass keeps each under JAX's transpose(jvp(...)) prefix
         def compute(params):
             variables = {"params": params}
-            if state.batch_stats:
-                variables["batch_stats"] = state.batch_stats
-                logits, updates = model.apply(
-                    variables, x, train=True, mutable=["batch_stats"]
-                )
-            else:
-                logits, updates = model.apply(variables, x, train=True), {}
-            return loss_fn(logits, y), updates
+            with jax.named_scope("rdp.forward"):
+                if state.batch_stats:
+                    variables["batch_stats"] = state.batch_stats
+                    logits, updates = model.apply(
+                        variables, x, train=True, mutable=["batch_stats"]
+                    )
+                else:
+                    logits, updates = (
+                        model.apply(variables, x, train=True), {})
+            with jax.named_scope("rdp.loss"):
+                return loss_fn(logits, y), updates
 
         (loss, updates), grads = jax.value_and_grad(compute, has_aux=True)(state.params)
-        grad_updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, grad_updates)
+        with jax.named_scope("rdp.optimizer"):
+            grad_updates, opt_state = tx.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, grad_updates)
         new_state = state.replace(
             params=params,
             opt_state=opt_state,
@@ -115,6 +127,7 @@ def make_train_step(model, tx, loss_fn: Callable, donate: bool = True):
 def core_eval_step(model, loss_fn: Callable):
     """Unjitted (state, x, y) -> dict(loss, miou, dice, accuracy)."""
 
+    @jax.named_scope("rdp.eval")
     def step(state: TrainState, x, y):
         variables = {"params": state.params}
         if state.batch_stats:
@@ -196,10 +209,20 @@ def prefetch_to_device(batches, put):
     device placement (``jnp.asarray`` single-device,
     ``parallel.put_global_batch`` under a mesh); ``jax.device_put`` /
     ``jnp.asarray`` are themselves asynchronous, so staging costs the host
-    only the enqueue."""
+    only the enqueue.
+
+    Two phases per batch: ``rdp.train.loader_wait`` is the wait on the
+    input pipeline for the next host batch, ``rdp.train.h2d`` what ``put``
+    costs the host."""
     staged = None
-    for bx, by in batches:
-        nxt = (put(bx), put(by))
+    batches = iter(batches)
+    while True:
+        with phases.stage("rdp.train.loader_wait"):
+            batch = next(batches, None)
+        if batch is None:
+            break
+        with phases.stage("rdp.train.h2d"):
+            nxt = (put(batch[0]), put(batch[1]))
         if staged is not None:
             yield staged
         staged = nxt
@@ -212,7 +235,9 @@ def prefetch_to_device(batches, put):
 #: arrays. jit outputs never alias non-donated inputs, so every leaf is a
 #: fresh buffer with its input sharding preserved. Module-level so the
 #: compiled copy program is cached across improving epochs.
-_copy_tree = jax.jit(lambda t: jax.tree.map(jnp.copy, t))
+@jax.jit
+def _copy_tree(tree):
+    return jax.tree.map(jnp.copy, tree)
 
 
 def _fetch_to_host(tree):
@@ -290,402 +315,459 @@ def train_model(
             (see parallel/).
         register: register the best model in the registry under
             ``cfg.registered_model_name``.
+
+    The call is one ``rdp.train.job`` phase whose children tile it
+    (:data:`phases`): init, restore, stage_data, then per epoch steps,
+    validation, log, best_copy and the checkpoint hand-over, then register
+    and flush.
     """
-    t_start = time.time()
-
-    if arrays is not None:
-        xs, ys = arrays
-        # normalize to ndarrays once (dtype preserved, so integer inputs
-        # are normalized identically whether they arrive as arrays or
-        # lists): the index-array batching below needs fancy indexing
-        if not hasattr(xs, "nbytes"):
-            xs = np.asarray(xs)
-        if not hasattr(ys, "nbytes"):
-            ys = np.asarray(ys)
-        # Integer inputs get the same float normalization the file loader
-        # applies (data.PairedSegmentationData.load): images /255, masks
-        # /255 when 0/255-coded but a plain cast when already {0, 1} class
-        # indices -- dividing those by 255 would silently train against
-        # ~0.004 targets. Besides the wrong scale, u8 arrays reaching the
-        # jitted train step trip an XLA CPU space_to_batch crash on conv
-        # backprop (e.g. synthetic.generate_arrays' raw uint8 output).
-        if not np.issubdtype(xs.dtype, np.floating):
-            xs = np.asarray(xs, np.float32) / 255.0
-        if not np.issubdtype(ys.dtype, np.floating):
-            if np.max(ys, initial=0) > 1:
-                # only the file loader's 0/255 coding gets the /255 path;
-                # any other integer coding (class indices {0,2}, 0..K
-                # multi-class labels) would silently become ~K/255 targets,
-                # so reject it loudly instead of training against noise
-                # (one O(N) pass; the sort for the message only on error)
-                if not ((ys == 0) | (ys == 255)).all():
-                    raise ValueError(
-                        "integer masks must be coded {0,1} or {0,255}; got "
-                        f"values {np.unique(ys)[:8].tolist()}"
-                    )
-                ys = np.asarray(ys, np.float32) / 255.0
+    # one function on purpose: with the body in a helper of its own
+    # (train_model -> _train_job) the first epoch of a process's third call
+    # took 2.2 s longer on the chip (PERF.md, PR 25)
+    with phases.stage("rdp.train.job"):
+        t_start = time.perf_counter()
+        with phases.stage("rdp.train.init"):
+            if arrays is not None:
+                xs, ys = arrays
+                # normalize to ndarrays once (dtype preserved, so integer inputs
+                # are normalized identically whether they arrive as arrays or
+                # lists): the index-array batching below needs fancy indexing
+                if not hasattr(xs, "nbytes"):
+                    xs = np.asarray(xs)
+                if not hasattr(ys, "nbytes"):
+                    ys = np.asarray(ys)
+                # Integer inputs get the same float normalization the file loader
+                # applies (data.PairedSegmentationData.load): images /255, masks
+                # /255 when 0/255-coded but a plain cast when already {0, 1} class
+                # indices -- dividing those by 255 would silently train against
+                # ~0.004 targets. Besides the wrong scale, u8 arrays reaching the
+                # jitted train step trip an XLA CPU space_to_batch crash on conv
+                # backprop (e.g. synthetic.generate_arrays' raw uint8 output).
+                if not np.issubdtype(xs.dtype, np.floating):
+                    xs = np.asarray(xs, np.float32) / 255.0
+                if not np.issubdtype(ys.dtype, np.floating):
+                    if np.max(ys, initial=0) > 1:
+                        # only the file loader's 0/255 coding gets the /255 path;
+                        # any other integer coding (class indices {0,2}, 0..K
+                        # multi-class labels) would silently become ~K/255 targets,
+                        # so reject it loudly instead of training against noise
+                        # (one O(N) pass; the sort for the message only on error)
+                        if not ((ys == 0) | (ys == 255)).all():
+                            raise ValueError(
+                                "integer masks must be coded {0,1} or {0,255}; got "
+                                f"values {np.unique(ys)[:8].tolist()}"
+                            )
+                        ys = np.asarray(ys, np.float32) / 255.0
+                    else:
+                        ys = np.asarray(ys, np.float32)
+                n_samples = len(xs)
+                ds = None
             else:
-                ys = np.asarray(ys, np.float32)
-        n_samples = len(xs)
-        ds = None
-    else:
-        # file-backed: decoded batch-by-batch by StreamingBatches below, so
-        # dataset size is bounded by disk, not host RAM
-        ds = data_lib.PairedSegmentationData(cfg.dataset_dir, cfg.img_size)
-        n_samples = len(ds)
-    train_idx, val_idx = data_lib.train_val_split(
-        n_samples, cfg.validation_split, cfg.seed
-    )
-    if len(val_idx) == 0:
-        raise ValueError("dataset too small for a validation split")
-
-    if mesh is not None and model_cfg.conv_impl != "flax":
-        # the custom-VJP Pallas convs carry no pjit partitioning rules;
-        # under a mesh the nn.Conv/XLA path is the sharding-correct one
-        from robotic_discovery_platform_tpu.utils.config import replace as _rep
-
-        model_cfg = _rep(model_cfg, conv_impl="flax")
-    model = build_unet(model_cfg)
-    tx = optax.adam(cfg.learning_rate)
-    loss_fn = losses_lib.make_loss_fn(cfg.loss, cfg.dice_weight)
-    state = create_state(model, tx, jax.random.key(cfg.seed), cfg.img_size)
-
-    # Best-so-far candidate params/stats, held as independent DEVICE buffers
-    # (_copy_tree) so they survive donation of the live state and checkpoint
-    # as sharded global arrays under tensor parallelism.
-    best_params = None
-    best_stats = None
-
-    # Whole-epoch lax.scan mode: single device with the dataset resident in
-    # HBM (in-memory arrays, no mesh). One dispatch + one fetch per epoch
-    # instead of per step -- see make_epoch_runners.
-    if cfg.epoch_mode not in ("auto", "scan", "stream"):
-        raise ValueError(
-            f"epoch_mode must be auto|scan|stream, got {cfg.epoch_mode!r}"
-        )
-    if cfg.checkpoint_every < 1:
-        # 0 would be a ZeroDivisionError deep in the epoch loop; negatives
-        # would silently save every epoch
-        raise ValueError(
-            f"checkpoint_every must be >= 1, got {cfg.checkpoint_every}"
-        )
-    def _nbytes(a) -> int:
-        # no np.asarray here: that would copy (or device-fetch) the whole
-        # dataset just to read a byte count
-        if hasattr(a, "nbytes"):
-            return int(a.nbytes)
-        return int(np.prod(np.shape(a)) * np.dtype(np.float32).itemsize)
-
-    data_bytes = 0 if arrays is None else _nbytes(xs) + _nbytes(ys)
-    fits = data_bytes <= cfg.scan_max_bytes
-    use_scan = (
-        ds is None and mesh is None
-        and (cfg.epoch_mode == "scan"
-             or (cfg.epoch_mode == "auto" and fits))
-    )
-    if cfg.epoch_mode == "scan" and (ds is not None or mesh is not None):
-        raise ValueError(
-            "epoch_mode='scan' needs an in-memory dataset and no mesh"
-        )
-    if cfg.epoch_mode == "auto" and ds is None and mesh is None and not fits:
-        log.info(
-            "dataset is %.1f GiB > scan_max_bytes; using the streamed "
-            "per-batch path", data_bytes / 2**30,
-        )
-
-    # Multi-host: every process runs the identical program; process 0 alone
-    # writes tracking and the registry. Checkpoint save/restore are
-    # COLLECTIVE -- every process calls them and orbax coordinates its own
-    # cross-host barriers, writing/reading per-host shards (tensor-parallel
-    # state included). ``checkpoint_dir`` must be shared storage (GCS or a
-    # shared filesystem) in a multi-host job, as is standard on TPU pods.
-    is_main = jax.process_index() == 0
-
-    if mesh is not None:
-        from robotic_discovery_platform_tpu import parallel
-
-        train_step, eval_step, state = parallel.parallelize_training(
-            mesh, model, tx, loss_fn, state, donate=cfg.donate_state,
-            tp_min_channels=cfg.tp_min_channels,
-        )
-        spatial_on = dict(mesh.shape).get("spatial", 1) > 1
-
-        def to_device(b):
-            return parallel.put_global_batch(mesh, b, spatial=spatial_on)
-    elif use_scan:
-        train_epoch, eval_epoch = make_epoch_runners(
-            model, tx, loss_fn, donate=cfg.donate_state
-        )
-    else:
-        train_step = make_train_step(model, tx, loss_fn, donate=cfg.donate_state)
-        eval_step = make_eval_step(model, loss_fn)
-    if mesh is None:
-        to_device = jnp.asarray
-        def scalarize(v, dtype):
-            return jnp.asarray(v, dtype)
-    else:
-        from robotic_discovery_platform_tpu.parallel import mesh as mesh_lib
-
-        _rep = mesh_lib.replicated(mesh)
-        def scalarize(v, dtype):
-            # progress counters live replicated on the mesh so the saved
-            # state is a consistent global array on every host
-            return jax.device_put(jnp.asarray(v, dtype), _rep)
-
-    # Checkpoints carry the best-so-far candidate alongside the live state so
-    # a resumed run registers the params that actually achieved
-    # ``best_val_loss``, not whatever the last epoch happened to hold.
-    # Restore happens AFTER parallelize_training so the abstract template
-    # carries the final (possibly TP-sharded) shardings and orbax lands each
-    # host's shards directly on its devices.
-    ckpt = CheckpointManager(cfg.checkpoint_dir, keep=cfg.keep_checkpoints)
-    if resume and ckpt.latest_step() is not None:
-        template = {
-            "state": state,
-            "best_params": state.params,
-            "best_stats": state.batch_stats,
-        }
-        if mesh is not None:
-            template = jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(
-                    a.shape, a.dtype, sharding=a.sharding
-                ),
-                template,
+                # file-backed: decoded batch-by-batch by StreamingBatches below, so
+                # dataset size is bounded by disk, not host RAM
+                ds = data_lib.PairedSegmentationData(cfg.dataset_dir, cfg.img_size)
+                n_samples = len(ds)
+            train_idx, val_idx = data_lib.train_val_split(
+                n_samples, cfg.validation_split, cfg.seed
             )
-        else:
-            template = jax.device_get(template)
-        restored = ckpt.restore(template)
-        state = restored["state"]
-        log.info("resumed from checkpoint at epoch %d", int(state.epoch))
-        if np.isfinite(float(state.best_val_loss)):
-            best_params = restored["best_params"]
-            best_stats = restored["best_stats"]
+            if len(val_idx) == 0:
+                raise ValueError("dataset too small for a validation split")
 
-    divisor = mesh.shape.get("data", 1) if mesh is not None else 1
-    # round the global batch up to a multiple of the data-parallel world size
-    # so every jit-sharded batch divides evenly over the mesh
-    batch_size = ((max(cfg.batch_size, divisor) + divisor - 1) // divisor) * divisor
-    train_batches = val_batches = None
-    if use_scan:
-        xs_tr = jnp.asarray(xs[train_idx])
-        ys_tr = jnp.asarray(ys[train_idx])
-        xs_va = jnp.asarray(xs[val_idx])
-        ys_va = jnp.asarray(ys[val_idx])
-        order_rng = np.random.default_rng(cfg.seed)
-        val_order = jnp.asarray(data_lib.epoch_order(
-            len(val_idx), batch_size, False, order_rng
-        ))
+            if mesh is not None and model_cfg.conv_impl != "flax":
+                # the custom-VJP Pallas convs carry no pjit partitioning rules;
+                # under a mesh the nn.Conv/XLA path is the sharding-correct one
+                from robotic_discovery_platform_tpu.utils.config import replace as _rep
 
-        def run_val():
-            metrics = eval_epoch(state, xs_va, ys_va, val_order)
-            return {k: float(v) for k, v in metrics.items()}
-    elif ds is not None:
-        train_batches = data_lib.StreamingBatches(
-            ds, train_idx, batch_size, shuffle=True, seed=cfg.seed,
-            divisor=divisor, workers=cfg.loader_workers,
-        )
-        val_batches = data_lib.StreamingBatches(
-            ds, val_idx, batch_size, shuffle=False, divisor=divisor,
-            workers=cfg.loader_workers,
-        )
-    else:
-        train_batches = data_lib.Batches(
-            xs[train_idx], ys[train_idx], batch_size, shuffle=True,
-            seed=cfg.seed, divisor=divisor,
-        )
-        val_batches = data_lib.Batches(
-            xs[val_idx], ys[val_idx], batch_size, shuffle=False,
-            divisor=divisor,
-        )
-    if not use_scan:
-        def run_val():
-            agg: dict[str, list] = {}
-            for bx, by in val_batches:
-                m = eval_step(state, to_device(bx), to_device(by))
-                for k, v in m.items():
-                    agg.setdefault(k, []).append(float(v))
-            return {k: float(np.mean(v)) for k, v in agg.items()}
+                model_cfg = _rep(model_cfg, conv_impl="flax")
+            model = build_unet(model_cfg)
+            tx = optax.adam(cfg.learning_rate)
+            loss_fn = losses_lib.make_loss_fn(cfg.loss, cfg.dice_weight)
+            state = create_state(model, tx, jax.random.key(cfg.seed), cfg.img_size)
 
-    if is_main:
-        tracking.set_tracking_uri(cfg.tracking_uri)
-        tracking.set_experiment(cfg.experiment_name)
-        run_ctx = tracking.start_run()
-    else:
-        import contextlib
+            # Best-so-far candidate params/stats, held as independent DEVICE buffers
+            # (_copy_tree) so they survive donation of the live state and checkpoint
+            # as sharded global arrays under tensor parallelism.
+            best_params = None
+            best_stats = None
 
-        run_ctx = contextlib.nullcontext(
-            tracking.ActiveRun(f"process-{jax.process_index()}")
-        )
-
-    registry_version = None
-    final_metrics: dict = {}
-
-    # close() on BOTH exits: an exception mid-training must still drain
-    # any in-flight async save (abandoning the daemon worker would
-    # silently lose the checkpoint it was writing) without masking the
-    # original error; the clean path surfaces save failures by raising
-    try:
-        with run_ctx as run:
-            if is_main:
-                tracking.log_params(
-                    {
-                        # exact reference param-name surface
-                        # (train_segmenter.py:119-128)
-                        "learning_rate": cfg.learning_rate,
-                        "batch_size": batch_size,
-                        "epochs": cfg.epochs,
-                        "validation_split": cfg.validation_split,
-                        "image_size": cfg.img_size,
-                        "optimizer": "adam",
-                        "loss": cfg.loss,
-                        "model": "UNet",
-                        "bilinear": model_cfg.bilinear,
-                        "base_features": model_cfg.base_features,
-                        "backend": jax.default_backend(),
-                        "num_devices": divisor,
-                    }
+            # Whole-epoch lax.scan mode: single device with the dataset resident in
+            # HBM (in-memory arrays, no mesh). One dispatch + one fetch per epoch
+            # instead of per step -- see make_epoch_runners.
+            if cfg.epoch_mode not in ("auto", "scan", "stream"):
+                raise ValueError(
+                    f"epoch_mode must be auto|scan|stream, got {cfg.epoch_mode!r}"
                 )
-
-            epoch_seconds: list = []
-            start_epoch = min(int(state.epoch), cfg.epochs)
-            if int(state.epoch) >= cfg.epochs:
-                log.warning(
-                    "checkpoint epoch %d >= cfg.epochs %d; nothing to train, "
-                    "evaluating only", int(state.epoch), cfg.epochs,
+            if cfg.checkpoint_every < 1:
+                # 0 would be a ZeroDivisionError deep in the epoch loop; negatives
+                # would silently save every epoch
+                raise ValueError(
+                    f"checkpoint_every must be >= 1, got {cfg.checkpoint_every}"
                 )
-                final_metrics = run_val()
-            for epoch in range(start_epoch, cfg.epochs):
-                t_epoch = time.time()
-                if use_scan:
-                    order = jnp.asarray(data_lib.epoch_order(
-                        len(train_idx), batch_size, True, order_rng
-                    ))
-                    state, loss = train_epoch(state, xs_tr, ys_tr, order)
-                    train_loss = float(loss)
-                else:
-                    train_losses = []
-                    # device-prefetch: batch k+1 decodes + stages while the
-                    # donated step for batch k runs on device (losses are
-                    # fetched at epoch end, so nothing here blocks per step)
-                    for dx, dy in prefetch_to_device(
-                        train_batches, to_device
-                    ):
-                        state, loss = train_step(state, dx, dy)
-                        train_losses.append(loss)
-                    train_loss = float(np.mean([float(l) for l in train_losses]))
+            def _nbytes(a) -> int:
+                # no np.asarray here: that would copy (or device-fetch) the whole
+                # dataset just to read a byte count
+                if hasattr(a, "nbytes"):
+                    return int(a.nbytes)
+                return int(np.prod(np.shape(a)) * np.dtype(np.float32).itemsize)
 
-                # Train-phase throughput (the float() above synced the
-                # device, so the measured window covers real step time).
-                # One histogram sample per epoch at the mean step time: the
-                # scan path is one whole-epoch dispatch with no per-step
-                # boundary to time, and the streamed path's per-step wall
-                # time is dispatch-only (losses are fetched at epoch end),
-                # so the epoch mean is the honest per-step number for both.
-                n_steps = (int(order.shape[0]) if use_scan
-                           else len(train_losses))
-                train_time = time.time() - t_epoch
-                if n_steps and train_time > 0:
-                    obs.TRAIN_STEP.observe(train_time / n_steps)
-                    obs.TRAIN_RATE.set(n_steps * batch_size / train_time)
-
-                val = run_val()
-                final_metrics = val
-
-                if is_main:
-                    tracking.log_metric("train_loss", train_loss, step=epoch)
-                    tracking.log_metric("val_loss", val["loss"], step=epoch)
-                    tracking.log_metric("val_miou", val["miou"], step=epoch)
-                    tracking.log_metric("val_dice", val["dice"], step=epoch)
-                epoch_seconds.append(time.time() - t_epoch)
+            data_bytes = 0 if arrays is None else _nbytes(xs) + _nbytes(ys)
+            fits = data_bytes <= cfg.scan_max_bytes
+            use_scan = (
+                ds is None and mesh is None
+                and (cfg.epoch_mode == "scan"
+                     or (cfg.epoch_mode == "auto" and fits))
+            )
+            if cfg.epoch_mode == "scan" and (ds is not None or mesh is not None):
+                raise ValueError(
+                    "epoch_mode='scan' needs an in-memory dataset and no mesh"
+                )
+            if cfg.epoch_mode == "auto" and ds is None and mesh is None and not fits:
                 log.info(
-                    "epoch %d/%d train_loss=%.4f val_loss=%.4f miou=%.4f (%.1fs)",
-                    epoch + 1, cfg.epochs, train_loss, val["loss"], val["miou"],
-                    epoch_seconds[-1],
+                    "dataset is %.1f GiB > scan_max_bytes; using the streamed "
+                    "per-batch path", data_bytes / 2**30,
                 )
 
-                if val["loss"] < float(state.best_val_loss):
-                    state = state.replace(
-                        best_val_loss=scalarize(val["loss"], jnp.float32)
-                    )
-                    best_params, best_stats = _copy_tree(
-                        (state.params, state.batch_stats)
-                    )
+            # Multi-host: every process runs the identical program; process 0 alone
+            # writes tracking and the registry. Checkpoint save/restore are
+            # COLLECTIVE -- every process calls them and orbax coordinates its own
+            # cross-host barriers, writing/reading per-host shards (tensor-parallel
+            # state included). ``checkpoint_dir`` must be shared storage (GCS or a
+            # shared filesystem) in a multi-host job, as is standard on TPU pods.
+            is_main = jax.process_index() == 0
 
-                state = state.replace(epoch=scalarize(epoch + 1, jnp.int32))
-                if (epoch + 1) % cfg.checkpoint_every and epoch + 1 < cfg.epochs:
-                    continue
-                # Collective: every process calls save; orbax coordinates its
-                # own cross-host barriers and each host writes its shards.
-                payload = {
+            if mesh is not None:
+                from robotic_discovery_platform_tpu import parallel
+
+                train_step, eval_step, state = parallel.parallelize_training(
+                    mesh, model, tx, loss_fn, state, donate=cfg.donate_state,
+                    tp_min_channels=cfg.tp_min_channels,
+                )
+                spatial_on = dict(mesh.shape).get("spatial", 1) > 1
+
+                def to_device(b):
+                    return parallel.put_global_batch(mesh, b, spatial=spatial_on)
+            elif use_scan:
+                train_epoch, eval_epoch = make_epoch_runners(
+                    model, tx, loss_fn, donate=cfg.donate_state
+                )
+            else:
+                train_step = make_train_step(
+                    model, tx, loss_fn, donate=cfg.donate_state)
+                eval_step = make_eval_step(model, loss_fn)
+            if mesh is None:
+                to_device = jnp.asarray
+                def scalarize(v, dtype):
+                    return jnp.asarray(v, dtype)
+            else:
+                from robotic_discovery_platform_tpu.parallel import mesh as mesh_lib
+
+                _rep = mesh_lib.replicated(mesh)
+                def scalarize(v, dtype):
+                    # progress counters live replicated on the mesh so the saved
+                    # state is a consistent global array on every host
+                    return jax.device_put(jnp.asarray(v, dtype), _rep)
+
+            # Checkpoints carry the best-so-far candidate alongside the live state so
+            # a resumed run registers the params that actually achieved
+            # ``best_val_loss``, not whatever the last epoch happened to hold.
+            # Restore happens AFTER parallelize_training so the abstract template
+            # carries the final (possibly TP-sharded) shardings and orbax lands each
+            # host's shards directly on its devices.
+            ckpt = CheckpointManager(cfg.checkpoint_dir, keep=cfg.keep_checkpoints)
+            resuming = resume and ckpt.latest_step() is not None
+
+        if resuming:
+            with phases.stage("rdp.train.restore"):
+                template = {
                     "state": state,
-                    "best_params": (
-                        best_params if best_params is not None
-                        else state.params
-                    ),
-                    "best_stats": (
-                        best_stats if best_stats is not None
-                        else state.batch_stats
-                    ),
+                    "best_params": state.params,
+                    "best_stats": state.batch_stats,
                 }
-                if jax.process_count() == 1 and cfg.async_checkpointing:
-                    # single-controller: snapshot to independent device buffers
-                    # (cheap HBM copy, and required -- the live state is donated
-                    # into the next epoch's step), then a background worker pays
-                    # the ONE bulk host fetch + disk write while the next
-                    # epoch's compute runs. Letting orbax pull device arrays
-                    # leaf by leaf would cost a device round-trip per leaf
-                    # (~270 leaves), and a synchronous fetch would serialize
-                    # ~350 MB of D2H traffic into every epoch.
-                    # wait for the PREVIOUS epoch's save before building the
-                    # new snapshot: otherwise three copies of the state (live
-                    # + old snapshot + new snapshot) coexist in HBM whenever
-                    # saves run longer than epochs
-                    ckpt.wait()
-                    ckpt.save_async(epoch + 1, _copy_tree(payload))
-                elif jax.process_count() == 1:
-                    # synchronous opt-out keeps the one-bulk-fetch shape
-                    ckpt.save(epoch + 1, jax.device_get(payload))
+                if mesh is not None:
+                    template = jax.tree.map(
+                        lambda a: jax.ShapeDtypeStruct(
+                            a.shape, a.dtype, sharding=a.sharding
+                        ),
+                        template,
+                    )
                 else:
-                    # multi-host saves are collective; orbax's cross-host
-                    # barriers must run in lockstep on every process
-                    ckpt.save(epoch + 1, payload)
+                    template = jax.device_get(template)
+                restored = ckpt.restore(template)
+                state = restored["state"]
+                log.info("resumed from checkpoint at epoch %d", int(state.epoch))
+                if np.isfinite(float(state.best_val_loss)):
+                    best_params = restored["best_params"]
+                    best_stats = restored["best_stats"]
 
+        with phases.stage("rdp.train.stage_data"):
+            divisor = mesh.shape.get("data", 1) if mesh is not None else 1
+            # round the global batch up to a multiple of the data-parallel
+            # world size so every jit-sharded batch divides evenly over the mesh
+            batch_size = (
+                (max(cfg.batch_size, divisor) + divisor - 1) // divisor
+            ) * divisor
+            train_batches = val_batches = None
+            if use_scan:
+                xs_tr = jnp.asarray(xs[train_idx])
+                ys_tr = jnp.asarray(ys[train_idx])
+                xs_va = jnp.asarray(xs[val_idx])
+                ys_va = jnp.asarray(ys[val_idx])
+                order_rng = np.random.default_rng(cfg.seed)
+                val_order = jnp.asarray(data_lib.epoch_order(
+                    len(val_idx), batch_size, False, order_rng
+                ))
+
+                def run_val():
+                    metrics = eval_epoch(state, xs_va, ys_va, val_order)
+                    return {k: float(v) for k, v in metrics.items()}
+            elif ds is not None:
+                train_batches = data_lib.StreamingBatches(
+                    ds, train_idx, batch_size, shuffle=True, seed=cfg.seed,
+                    divisor=divisor, workers=cfg.loader_workers,
+                )
+                val_batches = data_lib.StreamingBatches(
+                    ds, val_idx, batch_size, shuffle=False, divisor=divisor,
+                    workers=cfg.loader_workers,
+                )
+            else:
+                train_batches = data_lib.Batches(
+                    xs[train_idx], ys[train_idx], batch_size, shuffle=True,
+                    seed=cfg.seed, divisor=divisor,
+                )
+                val_batches = data_lib.Batches(
+                    xs[val_idx], ys[val_idx], batch_size, shuffle=False,
+                    divisor=divisor,
+                )
+            if not use_scan:
+                def run_val():
+                    agg: dict[str, list] = {}
+                    for bx, by in val_batches:
+                        m = eval_step(state, to_device(bx), to_device(by))
+                        for k, v in m.items():
+                            agg.setdefault(k, []).append(float(v))
+                    return {k: float(np.mean(v)) for k, v in agg.items()}
+
+        with phases.stage("rdp.train.log"):
             if is_main:
-                tracking.log_metric("best_val_loss", float(state.best_val_loss))
+                tracking.set_tracking_uri(cfg.tracking_uri)
+                tracking.set_experiment(cfg.experiment_name)
+                run_ctx = tracking.start_run()
+            else:
+                import contextlib
 
-            if register and best_params is not None:
-                # collective all-gather of any TP-sharded leaves, then host fetch
-                # on every process; only process 0 writes the registry
-                host_params = _fetch_to_host(best_params)
-                host_stats = _fetch_to_host(best_stats)
+                run_ctx = contextlib.nullcontext(
+                    tracking.ActiveRun(f"process-{jax.process_index()}")
+                )
+
+        registry_version = None
+        final_metrics: dict = {}
+
+        # close() on BOTH exits: an exception mid-training must still drain
+        # any in-flight async save (abandoning the daemon worker would
+        # silently lose the checkpoint it was writing) without masking the
+        # original error; the clean path surfaces save failures by raising
+        try:
+            with run_ctx as run:
                 if is_main:
-                    variables = {"params": host_params}
-                    if host_stats:
-                        variables["batch_stats"] = host_stats
-                    registry_version = tracking.log_model(
-                        variables, model_cfg,
-                        registered_model_name=cfg.registered_model_name,
-                    )
-                    log.info(
-                        "registered %s version %s", cfg.registered_model_name,
-                        registry_version,
-                    )
+                    with phases.stage("rdp.train.log"):
+                        tracking.log_params(
+                            {
+                                # exact reference param-name surface
+                                # (train_segmenter.py:119-128)
+                                "learning_rate": cfg.learning_rate,
+                                "batch_size": batch_size,
+                                "epochs": cfg.epochs,
+                                "validation_split": cfg.validation_split,
+                                "image_size": cfg.img_size,
+                                "optimizer": "adam",
+                                "loss": cfg.loss,
+                                "model": "UNet",
+                                "bilinear": model_cfg.bilinear,
+                                "base_features": model_cfg.base_features,
+                                "backend": jax.default_backend(),
+                                "num_devices": divisor,
+                            }
+                        )
 
-            run_id = run.info.run_id
+                epoch_seconds: list = []
+                start_epoch = min(int(state.epoch), cfg.epochs)
+                if int(state.epoch) >= cfg.epochs:
+                    log.warning(
+                        "checkpoint epoch %d >= cfg.epochs %d; nothing to train, "
+                        "evaluating only", int(state.epoch), cfg.epochs,
+                    )
+                    with phases.stage("rdp.train.validation"):
+                        final_metrics = run_val()
+                for epoch in range(start_epoch, cfg.epochs):
+                    with phases.stage("rdp.train.epoch", epoch=epoch):
+                        t_epoch = time.perf_counter()
+                        with phases.stage("rdp.train.steps"):
+                            if use_scan:
+                                order = jnp.asarray(data_lib.epoch_order(
+                                    len(train_idx), batch_size, True, order_rng
+                                ))
+                                state, loss = train_epoch(
+                                    state, xs_tr, ys_tr, order)
+                                train_loss = float(loss)
+                            else:
+                                train_losses = []
+                                # device-prefetch: batch k+1 decodes + stages
+                                # while the donated step for batch k runs on
+                                # device (losses are fetched at epoch end, so
+                                # nothing here blocks per step)
+                                step_num = epoch * len(train_batches)
+                                for dx, dy in prefetch_to_device(
+                                    train_batches, to_device
+                                ):
+                                    with jax.profiler.StepTraceAnnotation(
+                                        "rdp.train.step", step_num=step_num
+                                    ):
+                                        state, loss = train_step(state, dx, dy)
+                                    train_losses.append(loss)
+                                    step_num += 1
+                                train_loss = float(
+                                    np.mean([float(l) for l in train_losses]))
 
-    except BaseException:
-        # close without raising: a pending save failure must not mask the
-        # already-propagating training exception (it is logged instead)
-        ckpt.close(raise_errors=False)
-        raise
-    else:
-        ckpt.close()
-    return TrainResult(
-        run_id=run_id,
-        registry_version=registry_version,
-        best_val_loss=float(state.best_val_loss),
-        final_metrics=final_metrics,
-        epochs_run=cfg.epochs - start_epoch,
-        wall_clock_s=time.time() - t_start,
-        epoch_seconds=epoch_seconds,
-    )
+                            # Train-phase throughput (the float() above synced
+                            # the device, so the measured window covers real
+                            # step time). One histogram sample per epoch at the
+                            # mean step time: the scan path is one whole-epoch
+                            # dispatch with no per-step boundary to time, and
+                            # the streamed path's per-step wall time is
+                            # dispatch-only (losses are fetched at epoch end),
+                            # so the epoch mean is the honest per-step number
+                            # for both.
+                            n_steps = (int(order.shape[0]) if use_scan
+                                       else len(train_losses))
+                            train_time = time.perf_counter() - t_epoch
+                        if n_steps and train_time > 0:
+                            obs.TRAIN_STEP.observe(train_time / n_steps)
+                            obs.TRAIN_RATE.set(
+                                n_steps * batch_size / train_time)
+
+                        with phases.stage("rdp.train.validation"):
+                            val = run_val()
+                        final_metrics = val
+
+                        with phases.stage("rdp.train.log"):
+                            if is_main:
+                                tracking.log_metric(
+                                    "train_loss", train_loss, step=epoch)
+                                tracking.log_metric(
+                                    "val_loss", val["loss"], step=epoch)
+                                tracking.log_metric(
+                                    "val_miou", val["miou"], step=epoch)
+                                tracking.log_metric(
+                                    "val_dice", val["dice"], step=epoch)
+                            epoch_seconds.append(time.perf_counter() - t_epoch)
+                            log.info(
+                                "epoch %d/%d train_loss=%.4f val_loss=%.4f "
+                                "miou=%.4f (%.1fs)",
+                                epoch + 1, cfg.epochs, train_loss, val["loss"],
+                                val["miou"], epoch_seconds[-1],
+                            )
+
+                        if val["loss"] < float(state.best_val_loss):
+                            with phases.stage("rdp.train.best_copy"):
+                                state = state.replace(
+                                    best_val_loss=scalarize(
+                                        val["loss"], jnp.float32)
+                                )
+                                best_params, best_stats = _copy_tree(
+                                    (state.params, state.batch_stats)
+                                )
+
+                        state = state.replace(
+                            epoch=scalarize(epoch + 1, jnp.int32))
+                        if ((epoch + 1) % cfg.checkpoint_every
+                                and epoch + 1 < cfg.epochs):
+                            continue
+                        # Collective: every process calls save; orbax
+                        # coordinates its own cross-host barriers and each host
+                        # writes its shards.
+                        payload = {
+                            "state": state,
+                            "best_params": (
+                                best_params if best_params is not None
+                                else state.params
+                            ),
+                            "best_stats": (
+                                best_stats if best_stats is not None
+                                else state.batch_stats
+                            ),
+                        }
+                        if jax.process_count() == 1 and cfg.async_checkpointing:
+                            # single-controller: snapshot to independent device
+                            # buffers (cheap HBM copy, and required -- the live
+                            # state is donated into the next epoch's step), then
+                            # a background worker pays the ONE bulk host fetch +
+                            # disk write while the next epoch's compute runs.
+                            # Letting orbax pull device arrays leaf by leaf
+                            # would cost a device round-trip per leaf (~270
+                            # leaves), and a synchronous fetch would serialize
+                            # ~350 MB of D2H traffic into every epoch.
+                            # wait for the PREVIOUS epoch's save before building
+                            # the new snapshot: otherwise three copies of the
+                            # state (live + old snapshot + new snapshot) coexist
+                            # in HBM whenever saves run longer than epochs
+                            with phases.stage("rdp.train.checkpoint.wait"):
+                                ckpt.wait()
+                            with phases.stage("rdp.train.checkpoint.snapshot"):
+                                ckpt.save_async(epoch + 1, _copy_tree(payload))
+                        else:
+                            with phases.stage("rdp.train.checkpoint.snapshot"):
+                                if jax.process_count() == 1:
+                                    # synchronous opt-out keeps the
+                                    # one-bulk-fetch shape
+                                    ckpt.save(epoch + 1, jax.device_get(payload))
+                                else:
+                                    # multi-host saves are collective; orbax's
+                                    # cross-host barriers must run in lockstep
+                                    # on every process
+                                    ckpt.save(epoch + 1, payload)
+
+                if is_main:
+                    with phases.stage("rdp.train.log"):
+                        tracking.log_metric(
+                            "best_val_loss", float(state.best_val_loss))
+
+                if register and best_params is not None:
+                    with phases.stage("rdp.train.register"):
+                        # collective all-gather of any TP-sharded leaves, then
+                        # host fetch on every process; only process 0 writes the
+                        # registry
+                        host_params = _fetch_to_host(best_params)
+                        host_stats = _fetch_to_host(best_stats)
+                        if is_main:
+                            variables = {"params": host_params}
+                            if host_stats:
+                                variables["batch_stats"] = host_stats
+                            registry_version = tracking.log_model(
+                                variables, model_cfg,
+                                registered_model_name=cfg.registered_model_name,
+                            )
+                            log.info(
+                                "registered %s version %s",
+                                cfg.registered_model_name, registry_version,
+                            )
+
+                run_id = run.info.run_id
+
+        except BaseException:
+            # close without raising: a pending save failure must not mask the
+            # already-propagating training exception (it is logged instead)
+            with phases.stage("rdp.train.flush"):
+                ckpt.close(raise_errors=False)
+            raise
+        else:
+            with phases.stage("rdp.train.flush"):
+                ckpt.close()
+        return TrainResult(
+            run_id=run_id,
+            registry_version=registry_version,
+            best_val_loss=float(state.best_val_loss),
+            final_metrics=final_metrics,
+            epochs_run=cfg.epochs - start_epoch,
+            wall_clock_s=time.perf_counter() - t_start,
+            epoch_seconds=epoch_seconds,
+        )

@@ -90,6 +90,46 @@ def _cpu_pinned() -> bool:
     return jax.config.jax_platforms == "cpu"
 
 
+_JIT_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jaxpr_to_mlir_module",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_compile_listeners_on = False
+
+
+def _listen_to_compiles() -> None:
+    """Feed JAX's own compile telemetry (``jax.monitoring``) into
+    ``rdp_jit_seconds_total{stage}`` and ``rdp_compile_cache_total{result}``.
+    Registered once a process; JAX keeps listeners for its lifetime."""
+    global _compile_listeners_on
+    if _compile_listeners_on:
+        return
+    _compile_listeners_on = True
+    from jax import monitoring
+
+    from robotic_discovery_platform_tpu.observability import (
+        instruments as obs,
+    )
+
+    def on_duration(event: str, seconds: float, **_) -> None:
+        stage = _JIT_STAGE_EVENTS.get(event)
+        if stage is not None:
+            obs.JIT_SECONDS.labels(stage=stage).inc(seconds)
+
+    def on_event(event: str, **_) -> None:
+        result = _CACHE_EVENTS.get(event)
+        if result is not None:
+            obs.COMPILE_CACHE.labels(result=result).inc()
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    monitoring.register_event_listener(on_event)
+
+
 def enable_compile_cache() -> str | None:
     """Turn on JAX's persistent compilation cache; returns its directory.
 
@@ -107,6 +147,7 @@ def enable_compile_cache() -> str | None:
     backend -- and is idempotent."""
     import jax
 
+    _listen_to_compiles()
     if _cpu_pinned():
         return None
     if not os.environ.get(_CACHE_ENV_VAR):
@@ -117,8 +158,11 @@ def enable_compile_cache() -> str | None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # A Mosaic kernel rides in its custom call as serialized MLIR with its
     # locations intact, and JAX strips debug info from the OUTER module
-    # only before hashing. Full Python tracebacks in those locations would
-    # key every Pallas program on the call stack that traced it; one
-    # file:line per op keeps the key the same from every entry point.
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    # only before hashing. Python tracebacks ten frames deep in those
+    # locations would key every Pallas program on the call stack that
+    # traced it; one frame per op keeps the key the same from every entry
+    # point. (Not jax_include_full_tracebacks_in_locations=False: JAX 0.9.0
+    # then writes every op_name as the bare primitive, and no
+    # jax.named_scope reaches a compiled program or a profile.)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     return compile_cache_dir()

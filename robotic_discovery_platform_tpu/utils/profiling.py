@@ -17,6 +17,8 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import Callable
 
+import jax
+
 
 @dataclass
 class StageTimer:
@@ -30,7 +32,13 @@ class StageTimer:
     (``(stage_name, seconds)`` -- serving wires it to the
     ``rdp_stage_latency_seconds`` histogram), so per-stage timing feeds ONE
     system: the in-process summary and the exported histogram observe the
-    same measurements. Called outside the lock; must not raise."""
+    same measurements. Called outside the lock; must not raise.
+
+    A stage is also a host span of the ``jax.profiler`` trace
+    (``TraceAnnotation``, on the clock of the device planes), so a
+    profile taken around the timed code (:func:`jax_trace`,
+    :func:`capture_profile`) shows the same stages by the same names.
+    With no profiler session running the annotation is a flag check."""
 
     totals: dict = field(default_factory=lambda: defaultdict(float))
     counts: dict = field(default_factory=lambda: defaultdict(int))
@@ -40,10 +48,13 @@ class StageTimer:
                                   repr=False, compare=False)
 
     @contextlib.contextmanager
-    def stage(self, name: str):
+    def stage(self, name: str, **stats):
+        """Time the body under ``name``; ``stats`` (``epoch=3``) ride on
+        the profiler span only."""
         t0 = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name, **stats):
+                yield
         finally:
             self.observe(name, time.perf_counter() - t0)
 
@@ -87,8 +98,6 @@ def jax_trace(log_dir: str | None):
     if not log_dir:
         yield
         return
-    import jax
-
     jax.profiler.start_trace(log_dir)
     try:
         yield
@@ -114,7 +123,6 @@ def capture_profile(log_dir: str, seconds: float = 1.0) -> str:
     if not _capture_lock.acquire(blocking=False):
         raise RuntimeError("a profile capture is already in progress")
     try:
-        import jax
         import jax.numpy as jnp
 
         target = os.path.join(

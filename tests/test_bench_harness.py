@@ -70,7 +70,7 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
         "jax_compilation_cache_dir",
         "jax_persistent_cache_min_compile_time_secs",
         "jax_persistent_cache_min_entry_size_bytes",
-        "jax_include_full_tracebacks_in_locations")}
+        "jax_traceback_in_locations_limit")}
     monkeypatch.setattr(platforms, "_cpu_pinned", lambda: False)
     try:
         # placed from outside: no directory is set in code
@@ -85,6 +85,10 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
         assert platforms.enable_compile_cache() == want
         assert jax.config.jax_compilation_cache_dir == want
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        # one frame per location: Mosaic payloads hash the same from every
+        # entry point, and named scopes still reach op_name
+        assert jax.config.jax_traceback_in_locations_limit == 1
+        assert jax.config.jax_include_full_tracebacks_in_locations
     finally:
         for name, value in saved.items():
             jax.config.update(name, value)
