@@ -11,8 +11,10 @@ exactly once per trace (i.e. per jit-cache miss)::
 
 Each ``trace_guard`` call creates one :class:`GuardStats` instance and
 registers it under ``name`` (several instances may share a name: a
-hot-reloaded serving engine legitimately builds a fresh jit cache).
-Budgets are enforced PER INSTANCE -- one engine's cache, one budget.
+hot-reloaded serving engine legitimately builds a fresh jit cache; the
+trainer keeps one pair of runners per configuration and shape set).
+Budgets are enforced PER INSTANCE -- one engine's cache, one budget --
+for as long as the instance lives, which may be its process's.
 
 When an instance exceeds its budget the guard logs a warning with the
 offending abstract shapes; under strict mode (``RDP_RECOMPILE_STRICT=1``

@@ -667,9 +667,9 @@ HTTP_REQUESTS = REGISTRY.histogram(
 TRAIN_STEP = REGISTRY.histogram(
     families.TRAIN_STEP,
     "Mean optimizer-step wall time, observed once per epoch (whole-epoch "
-    "scan dispatches have no per-step boundary to time). The first epoch "
-    "of a train_model call includes the re-trace, re-lowering and "
-    "compile-or-cache-load of the call's freshly built jitted runners.",
+    "scan dispatches have no per-step boundary to time). A call's first "
+    "epoch includes the trace, lowering and compile-or-cache-load of any "
+    "shape the process's jitted runners have not run before.",
 )
 TRAIN_RATE = REGISTRY.gauge(
     families.TRAIN_RATE,
@@ -713,6 +713,17 @@ COMPILE_CACHE = REGISTRY.counter(
     families.COMPILE_CACHE,
     "Persistent compilation cache lookups, by result (hit, miss).",
     ("result",),
+)
+TRAIN_RUNNERS = REGISTRY.counter(
+    families.TRAIN_RUNNERS,
+    "Look-ups of a train_model call's jitted runners in the process-wide "
+    "memo (training/trainer.memoized_runners), by runner family (epoch: the "
+    "whole-epoch scan pair, step: the per-step pair) and result: built = "
+    "first call with these program-shaping settings and shapes, whose "
+    "first epoch traces, lowers and compiles or loads; reused = the jit "
+    "objects of an earlier call, with nothing left to trace "
+    "(rdp_jit_traces_total says whether one did all the same).",
+    ("family", "result"),
 )
 
 _BREAKER_STATE_VALUES = {"closed": 0, "open": 1, "half_open": 2}

@@ -19,6 +19,8 @@ plus the things the reference lacks (SURVEY.md sections 2.3, 5.3-5.4):
 
 from __future__ import annotations
 
+import functools
+import threading
 import time
 from dataclasses import dataclass
 from typing import Any, Callable
@@ -108,16 +110,19 @@ def core_train_step(model, tx, loss_fn: Callable):
 
 
 def make_train_step(model, tx, loss_fn: Callable, donate: bool = True):
-    """Single-device jitted train step.
+    """Single-device jitted train step: a NEW ``jax.jit`` object on every
+    call (``train_model`` keeps one per configuration and batch shape
+    through :func:`memoized_runners`).
 
-    Trace-budgeted (analysis/recompile): the steady state is ONE compile;
-    budget 3 tolerates the legitimate extra shapes (a trailing partial
-    batch, a resume with a different batch size) before the guard flags a
-    retrace leak."""
+    Trace-budgeted (analysis/recompile) at ONE trace for the object's life,
+    which may be its process's: every batch is full (``data.epoch_order``
+    pads the tail) and another batch or image size is another object, so a
+    second trace is a leak -- a shape that changes inside a job, or a
+    cache the object lost."""
     # transferguard.apply: under RDP_TRANSFER_GUARD, warm steps may move
     # no implicit bytes (prefetch_to_device is the sanctioned H2D path)
     return transferguard.apply(jax.jit(
-        recompile.trace_guard("trainer.train_step", budget=3)(
+        recompile.trace_guard("trainer.train_step", budget=1)(
             core_train_step(model, tx, loss_fn)
         ),
         donate_argnums=(0,) if donate else (),
@@ -144,8 +149,10 @@ def core_eval_step(model, loss_fn: Callable):
 
 
 def make_eval_step(model, loss_fn: Callable):
+    """Single-device jitted evaluation step, built and budgeted as
+    :func:`make_train_step`'s."""
     return transferguard.apply(jax.jit(
-        recompile.trace_guard("trainer.eval_step", budget=3)(
+        recompile.trace_guard("trainer.eval_step", budget=1)(
             core_eval_step(model, loss_fn)
         )
     ))
@@ -166,6 +173,11 @@ def make_epoch_runners(model, tx, loss_fn: Callable, donate: bool = True):
     Returns ``(train_epoch, eval_epoch)``:
       train_epoch(state, xs, ys, order) -> (state, mean_loss)
       eval_epoch(state, xs, ys, order) -> dict of mean metrics
+
+    Two NEW ``jax.jit`` objects on every call, each budgeted at one trace
+    as :func:`make_train_step`'s: ``train_model`` keeps a pair per
+    configuration AND data-set size (:func:`memoized_runners`), so an
+    ``order`` of another length reaching the same object is a leak.
     """
     step = core_train_step(model, tx, loss_fn)
     estep = core_eval_step(model, loss_fn)
@@ -187,17 +199,76 @@ def make_epoch_runners(model, tx, loss_fn: Callable, donate: bool = True):
 
     return (
         transferguard.apply(jax.jit(
-            recompile.trace_guard("trainer.train_epoch", budget=2)(
+            recompile.trace_guard("trainer.train_epoch", budget=1)(
                 train_epoch
             ),
             donate_argnums=(0,) if donate else (),
         )),
         transferguard.apply(jax.jit(
-            recompile.trace_guard("trainer.eval_epoch", budget=2)(
+            recompile.trace_guard("trainer.eval_epoch", budget=1)(
                 eval_epoch
             )
         )),
     )
+
+
+#: Pairs of jitted runners the process keeps, one per configuration and
+#: shape set, least recently used out first; a dropped pair releases its
+#: executables, so this also bounds the programs a long-lived process holds
+#: loaded. A serving process retrains one configuration, the benchmark one
+#: at two data-set sizes, a test process many small ones in turn.
+RUNNER_MEMO_BOUND = 4
+
+_runner_memo_lock = threading.Lock()
+
+
+@functools.lru_cache(maxsize=RUNNER_MEMO_BOUND)
+def _kept_runners(family, builders, model_cfg, learning_rate, loss,
+                  dice_weight, donate, guard_mode, shapes):
+    """The memo behind :func:`memoized_runners`: every argument is part of
+    the key, and model, optimiser and loss are built from it here, so a
+    kept runner can close over nothing its key does not say."""
+    model = build_unet(model_cfg)
+    tx = optax.adam(learning_rate)
+    loss_fn = losses_lib.make_loss_fn(loss, dice_weight)
+    if family == "epoch":
+        return builders[0](model, tx, loss_fn, donate=donate)
+    return (builders[0](model, tx, loss_fn, donate=donate),
+            builders[1](model, loss_fn))
+
+
+def memoized_runners(family: str, cfg: TrainConfig, model_cfg: ModelConfig,
+                     shapes: tuple) -> tuple:
+    """``train_model``'s single-device runners, the same ``jax.jit``
+    objects for every call whose program-shaping settings and shapes are
+    equal, so that JAX's own per-object cache spares a repeated job the
+    trace, the lowering and the load of programs its process already holds.
+
+    ``family`` is ``"epoch"`` (:func:`make_epoch_runners`) or ``"step"``
+    (:func:`make_train_step` with :func:`make_eval_step`); either way a
+    ``(train, evaluate)`` pair. The key is everything the runners close
+    over, by value: ``model_cfg`` as the job uses it, the optimiser's and
+    the loss's hyper-parameters, donation, the transfer guard's mode; the
+    builder functions themselves, looked up through this module at call
+    time, so that a replaced builder (a test's planted fault) is never
+    served a sound entry nor leaves its own behind for a sound call; and
+    ``shapes``, whatever decides the shapes the job will feed them (batch
+    and sample shapes, for the epoch family the data set's size). One pair
+    per shape set keeps each runner at the one trace its guard allows, and
+    lets the bound count programs, not only configurations.
+    """
+    builders = ((make_epoch_runners,) if family == "epoch"
+                else (make_train_step, make_eval_step))
+    with _runner_memo_lock:     # exact counts, and no pair built twice
+        built = _kept_runners.cache_info().misses
+        runners = _kept_runners(
+            family, builders + (core_train_step, core_eval_step), model_cfg,
+            cfg.learning_rate, cfg.loss, cfg.dice_weight, cfg.donate_state,
+            transferguard.resolve_transfer_guard(), shapes)
+        built = _kept_runners.cache_info().misses > built
+    obs.TRAIN_RUNNERS.labels(
+        family=family, result="built" if built else "reused").inc()
+    return runners
 
 
 def prefetch_to_device(batches, put):
@@ -312,7 +383,8 @@ def train_model(
             ``checkpoint_dir`` must be shared storage across hosts.
         mesh: optional ``jax.sharding.Mesh``; when given, batches are sharded
             over the mesh's "data" axis and gradients allreduce over ICI
-            (see parallel/).
+            (see parallel/). A mesh of the default device alone trains
+            the same model (XLA convolutions) without one.
         register: register the best model in the registry under
             ``cfg.registered_model_name``.
 
@@ -322,8 +394,10 @@ def train_model(
     and flush.
     """
     # one function on purpose: with the body in a helper of its own
-    # (train_model -> _train_job) the first epoch of a process's third call
-    # took 2.2 s longer on the chip (PERF.md, PR 25)
+    # (train_model -> _train_job) a call that traced its runners took 2.2 s
+    # longer on the chip (PERF.md, PR 25). Since PR 26 only the first call
+    # with a shape traces, and the split costs a later call nothing
+    # (PERF.md, PR 26); the first calls would still pay it
     with phases.stage("rdp.train.job"):
         t_start = time.perf_counter()
         with phases.stage("rdp.train.init"):
@@ -379,6 +453,13 @@ def train_model(
                 from robotic_discovery_platform_tpu.utils.config import replace as _rep
 
                 model_cfg = _rep(model_cfg, conv_impl="flax")
+            if (mesh is not None and mesh.size == 1
+                    and mesh.devices.flat[0] == jax.devices()[0]):
+                # a mesh of the default device alone shards nothing (what
+                # serving/rollout.py's training_mesh() hands a one-chip
+                # replica's cycle): the same model as under any mesh, run by
+                # the single-device runners, which the process keeps
+                mesh = None
             model = build_unet(model_cfg)
             tx = optax.adam(cfg.learning_rate)
             loss_fn = losses_lib.make_loss_fn(cfg.loss, cfg.dice_weight)
@@ -446,14 +527,20 @@ def train_model(
 
                 def to_device(b):
                     return parallel.put_global_batch(mesh, b, spatial=spatial_on)
-            elif use_scan:
-                train_epoch, eval_epoch = make_epoch_runners(
-                    model, tx, loss_fn, donate=cfg.donate_state
-                )
             else:
-                train_step = make_train_step(
-                    model, tx, loss_fn, donate=cfg.donate_state)
-                eval_step = make_eval_step(model, loss_fn)
+                # what decides every shape the runners will be fed: full
+                # batches (data.epoch_order) of these samples and, for a
+                # whole-epoch scan, the two splits' sizes
+                shapes = (max(cfg.batch_size, 1),) + (
+                    (cfg.img_size,) if ds is not None else
+                    (xs.shape[1:], str(xs.dtype), ys.shape[1:], str(ys.dtype)))
+                if use_scan:
+                    train_epoch, eval_epoch = memoized_runners(
+                        "epoch", cfg, model_cfg,
+                        shapes + (len(train_idx), len(val_idx)))
+                else:
+                    train_step, eval_step = memoized_runners(
+                        "step", cfg, model_cfg, shapes)
             if mesh is None:
                 to_device = jnp.asarray
                 def scalarize(v, dtype):
@@ -494,6 +581,11 @@ def train_model(
                     template = jax.device_get(template)
                 restored = ckpt.restore(template)
                 state = restored["state"]
+                if mesh is None:
+                    # host arrays from the host template: staged explicitly,
+                    # because a reused runner is warm from its first step and
+                    # the transfer guard exempts only a cold call's transfers
+                    state = jax.device_put(state)
                 log.info("resumed from checkpoint at epoch %d", int(state.epoch))
                 if np.isfinite(float(state.best_val_loss)):
                     best_params = restored["best_params"]

@@ -1,7 +1,10 @@
 """What the retraining job says about itself: the ``rdp.train.*`` phase
 spans of a ``train_model`` call in a ``jax.profiler`` trace (names, nesting,
 threads, tiling), the ``rdp_train_phase_seconds`` histogram the same stages
-feed, the compile counters, and the named scopes of the compiled step."""
+feed, the compile counters, the jitted runners kept across calls (what
+shares an entry of the memo, what builds anew, its bound, a replaced
+builder, a one-device mesh, the trace guards' budget), and the named
+scopes of the compiled step."""
 
 import dataclasses
 import re
@@ -14,6 +17,7 @@ import pytest
 
 from perfbench.lib import spans as spans_lib, trace as trace_lib
 from robotic_discovery_platform_tpu import tracking
+from robotic_discovery_platform_tpu.analysis import recompile
 from robotic_discovery_platform_tpu.models import losses as losses_lib
 from robotic_discovery_platform_tpu.models.unet import build_unet
 from robotic_discovery_platform_tpu.observability import instruments as obs
@@ -44,6 +48,41 @@ def jit_seconds():
                          "backend_compile"))
 
 
+GUARDS = {"epoch": ("trainer.train_epoch", "trainer.eval_epoch"),
+          "step": ("trainer.train_step", "trainer.eval_step")}
+#: what a ``train_model`` call adds to ``runner_counts``: new runners (a
+#: train and an evaluation one, a guard instance each, each traced once),
+#: or an earlier call's, which trace nothing they have run before
+BUILT = dict(built=1, reused=0, traces=2, guards=2)
+REUSED = dict(built=0, reused=1, traces=0, guards=0)
+
+
+def runner_counts(family):
+    """What the process has counted for one runner family: look-ups of the
+    memo by result, traces of the family's two guards, guard instances."""
+    out = {result: obs.TRAIN_RUNNERS.labels(
+        family=family, result=result).value for result in ("built", "reused")}
+    out["traces"] = sum(obs.JIT_TRACES.labels(fn=g).value
+                        for g in GUARDS[family])
+    out["guards"] = sum(len(recompile.stats_for(g)) for g in GUARDS[family])
+    return out
+
+
+def counted(family, call):
+    """``call()``'s result and what it added to ``runner_counts``."""
+    before = runner_counts(family)
+    out = call()
+    after = runner_counts(family)
+    return out, {k: after[k] - before[k] for k in before}
+
+
+def forget_runners():
+    """An empty memo: what an earlier test of this process kept (the tiny
+    model and the default hyper-parameters are everybody's) must not decide
+    whether a call here builds."""
+    trainer._kept_runners.cache_clear()
+
+
 def traced(tmp_path, call):
     """``call()`` inside the harness's window span under a profiler session
     with the harness's options; the window's spans."""
@@ -58,12 +97,20 @@ def traced(tmp_path, call):
         trace_lib.find_xplane(tmp_path / "trace")))
 
 
+#: epochs of the resumed call: long enough (3 s here) that the job's own
+#: time, a few hundredths of a second of scheduling between its phases, is
+#: under the 2% that the spans must tile it within, now that the call
+#: re-traces nothing; the benchmark's window runs as many
+MORE = 12
+
+
 @pytest.fixture(scope="module", params=["scan", "stream"])
 def job(request, tmp_path_factory):
     """A first ``train_model`` call of two epochs, then the same job resumed
-    for two more under a profiler session: what ``perfbench`` measures."""
+    for ``MORE`` under a profiler session: what ``perfbench`` measures."""
     tmp = tmp_path_factory.mktemp(request.param)
     platforms.enable_compile_cache()    # CPU: only the compile counters
+    forget_runners()
     cfg = TrainConfig(
         epochs=2, batch_size=4, img_size=32, validation_split=0.25,
         tracking_uri=f"file:{tmp}/mlruns", checkpoint_dir=f"{tmp}/ckpt",
@@ -75,21 +122,21 @@ def job(request, tmp_path_factory):
     else:
         synthetic.generate_dataset(tmp / "data", 16, 48, 64, seed=3)
         cfg = dataclasses.replace(cfg, dataset_dir=str(tmp / "data"))
-    guard = "trainer.train_epoch" if request.param == "scan" \
-        else "trainer.train_step"
-    counts = [obs.JIT_TRACES.labels(fn=guard).value]
+    family = "epoch" if request.param == "scan" else "step"
     seconds = [jit_seconds()]
-    trainer.train_model(cfg, TINY_MODEL, **feed)
-    counts.append(obs.JIT_TRACES.labels(fn=guard).value)
+    _, first = counted(family,
+                       lambda: trainer.train_model(cfg, TINY_MODEL, **feed))
     seconds.append(jit_seconds())
     before = {name: phase_sum(name) for name in TILING + ("rdp.train.job",)}
-    result, spans = traced(tmp, lambda: trainer.train_model(
-        dataclasses.replace(cfg, epochs=4), TINY_MODEL, resume=True, **feed))
-    counts.append(obs.JIT_TRACES.labels(fn=guard).value)
+    (result, spans), second = counted(family, lambda: traced(
+        tmp, lambda: trainer.train_model(
+            dataclasses.replace(cfg, epochs=2 + MORE), TINY_MODEL, resume=True,
+            **feed)))
     seconds.append(jit_seconds())
     observed = {name: phase_sum(name) - before[name] for name in before}
     return dict(mode=request.param, spans=spans, result=result,
-                observed=observed, traces=counts, jit_seconds=seconds)
+                observed=observed, counted=(first, second),
+                jit_seconds=seconds)
 
 
 def one(spans, name):
@@ -103,7 +150,7 @@ def test_span_names_and_nesting(job):
     whole = one(spans, "rdp.train.job")
     assert whole.thread == main
     epochs = spans.named("rdp.train.epoch")
-    assert [e.stats["epoch"] for e in epochs] == [2, 3]
+    assert [e.stats["epoch"] for e in epochs] == list(range(2, 2 + MORE))
     starts = []
     for name in JOB_PHASES:
         phase = one(spans, name)
@@ -118,8 +165,7 @@ def test_span_names_and_nesting(job):
         for name in EPOCH_PHASES:
             inside = [s for s in spans.named(name, main) if epoch.holds(s)]
             assert len(inside) == 1, (name, epoch.stats)
-    # both epochs improved on a loss of infinity or did not: the copy, when
-    # it ran, ran inside an epoch
+    # the copy, when it ran, ran inside an epoch
     for copy in spans.named("rdp.train.best_copy"):
         assert any(e.holds(copy) for e in epochs)
     steps = spans.named("rdp.train.steps")
@@ -129,9 +175,10 @@ def test_span_names_and_nesting(job):
     else:
         # 12 training rows at batch 4: three steps an epoch, each a wait, a
         # placement and a dispatch; one more wait finds the epoch's end
-        assert [len(per_step[n]) for n in STEP_PHASES] == [8, 6, 6]
+        assert [len(per_step[n]) for n in STEP_PHASES] \
+            == [4 * MORE, 3 * MORE, 3 * MORE]
         assert [s.stats["step_num"] for s in per_step["rdp.train.step"]] \
-            == [6, 7, 8, 9, 10, 11]
+            == list(range(6, 6 + 3 * MORE))
         for name in STEP_PHASES:
             for span in per_step[name]:
                 assert any(s.holds(span) for s in steps), name
@@ -149,7 +196,8 @@ def test_worker_spans_sit_on_their_own_threads(job):
     spans, main = job["spans"], job["spans"].main
     workers = {s.thread for s in spans.named(WORKER_PHASES)}
     # one save at a time, each on a thread of its own, never the job's
-    assert len(spans.named(WORKER_PHASES)) == 4 and main not in workers
+    assert len(spans.named(WORKER_PHASES)) == 2 * MORE \
+        and main not in workers
     for fetch in spans.named("rdp.train.checkpoint.fetch"):
         write = [w for w in spans.named("rdp.train.checkpoint.write",
                                         fetch.thread)
@@ -159,8 +207,8 @@ def test_worker_spans_sit_on_their_own_threads(job):
     if job["mode"] == "scan":
         assert not decodes
     else:
-        # two epochs of three training batches and one validation batch
-        assert len(decodes) == 8
+        # each epoch three training batches and one validation batch
+        assert len(decodes) == 4 * MORE
         assert main not in {d.thread for d in decodes}
 
 
@@ -175,21 +223,241 @@ def test_phase_histogram_sums_to_the_jobs_wall_clock(job):
                                   rel=0.05)
 
 
-def test_every_call_retraces_its_runner_and_the_counters_say_so(job):
-    """``make_epoch_runners`` / ``make_train_step`` build new ``jax.jit``
-    objects on every ``train_model`` call, so the second call of a process
-    traces again what the first compiled: pinned here as a count, so that
-    the PR that keeps the runners across calls has to change it."""
-    first, second = np.diff(job["traces"])
-    assert (first, second) == (1, 1)
-    assert np.all(np.diff(job["jit_seconds"]) > 0)
-    held = job["spans"].holding("PjitFunction*", "rdp.jit.trace")
-    fn = "train_epoch" if job["mode"] == "scan" else "step"
-    assert f"PjitFunction({fn})" in {s.name for s in held}
-    guards = {s.stats["fn"] for s in job["spans"].named("rdp.jit.trace")}
-    assert guards == ({"trainer.train_epoch", "trainer.eval_epoch"}
-                      if job["mode"] == "scan"
-                      else {"trainer.train_step", "trainer.eval_step"})
+def test_a_repeated_call_reuses_its_runners_and_the_counters_say_so(job):
+    """``train_model`` keeps its jitted runners by configuration
+    (``trainer.memoized_runners``): the first call builds them and traces
+    each guard once; the resumed call, same configuration and shapes and
+    only ``epochs`` changed, gets the same ``jax.jit`` objects back and
+    traces, lowers and compiles nothing."""
+    first, second = job["counted"]
+    assert (first, second) == (BUILT, REUSED)
+    spent = np.diff(job["jit_seconds"])
+    # (the resumed call's own first-time eager operations: a fraction of a
+    # millisecond, where one runner's trace alone takes a hundred)
+    assert spent[0] > 0 and spent[1] < 0.01 * spent[0]
+    # the traced call is the resumed one: no call in it traced
+    assert not job["spans"].named("rdp.jit.trace")
+    assert not job["spans"].holding("PjitFunction*", "rdp.jit.trace")
+
+
+def tiny_job(tmp_path, **changes):
+    """One epoch of the tiny model over 16 resident pairs; ``changes`` to
+    the ``TrainConfig``, ``model`` for another ``ModelConfig``, ``pairs``
+    for another data-set size, ``mesh`` for a device mesh."""
+    imgs, masks = synthetic.generate_arrays(
+        changes.pop("pairs", 16), 32, 32, seed=3)
+    model = changes.pop("model", TINY_MODEL)
+    mesh = changes.pop("mesh", None)
+    cfg = dataclasses.replace(TrainConfig(
+        epochs=1, batch_size=4, img_size=32, validation_split=0.25,
+        tracking_uri=f"file:{tmp_path}/mlruns",
+        checkpoint_dir=f"{tmp_path}/ckpt"), **changes)
+    return trainer.train_model(cfg, model, arrays=(imgs, masks), mesh=mesh,
+                               register=False)
+
+
+@pytest.mark.parametrize("changes,expected", [
+    # what the runners close over builds anew, and the new jit objects trace
+    (dict(learning_rate=3e-4), BUILT),
+    (dict(loss="bce_dice"), BUILT),
+    (dict(loss="bce_dice", dice_weight=0.25), BUILT),
+    (dict(donate_state=False), BUILT),
+    (dict(model=ModelConfig(base_features=8, compute_dtype="float32",
+                            norm="group")), BUILT),
+    # so does what decides their shapes: one pair, one shape set, one trace
+    (dict(pairs=24), BUILT),
+    (dict(batch_size=2), BUILT),
+    # what they do not close over shares the entry, and nothing traces
+    (dict(epochs=2), REUSED),
+    (dict(seed=7, checkpoint_every=2, keep_checkpoints=1), REUSED),
+    # equal by value is equal: another ModelConfig object, the same entry
+    (dict(model=ModelConfig(base_features=8, compute_dtype="float32")),
+     REUSED),
+], ids=["learning_rate", "loss", "dice_weight", "donate_state", "model",
+        "data_set_size", "batch_size", "epochs", "seed_and_checkpoints",
+        "equal_model_config"])
+def test_what_shares_a_memo_entry_and_what_builds_anew(tmp_path, changes,
+                                                      expected):
+    forget_runners()
+    with recompile.strict():    # a second trace by any runner would raise
+        _, base = counted("epoch", lambda: tiny_job(tmp_path / "base"))
+        assert base == BUILT
+        _, changed = counted(
+            "epoch", lambda: tiny_job(tmp_path / "changed", **changes))
+    assert changed == expected
+
+
+def test_the_memo_is_bounded_and_drops_the_least_recently_used():
+    forget_runners()
+    bound = trainer.RUNNER_MEMO_BOUND
+
+    def look_up(rate, family="epoch"):
+        return counted(family, lambda: trainer.memoized_runners(
+            family, TrainConfig(learning_rate=rate), TINY_MODEL, (4, 32)))
+
+    def size():
+        return trainer._kept_runners.cache_info().currsize
+
+    rates = [1e-4 * (k + 1) for k in range(bound)]
+    kept = [look_up(rate)[0] for rate in rates]
+    assert size() == bound
+    # the two families never share an entry; one more pushes the oldest out
+    (train_step, eval_step), added = look_up(rates[1], "step")
+    assert added["built"] == 1 and train_step is not kept[1][0]
+    assert size() == bound
+    again, added = look_up(rates[1])
+    assert added["reused"] == 1 and again[0] is kept[1][0] \
+        and again[1] is kept[1][1]
+    again, added = look_up(rates[0])
+    assert added["built"] == 1 and again[0] is not kept[0][0]
+    assert size() == bound
+
+
+def test_a_replaced_builder_is_honoured_and_leaves_nothing_behind(
+        tmp_path, monkeypatch):
+    """The memo looks ``make_epoch_runners`` up through the module at call
+    time and keys on the function: a planted fault (as
+    ``tests/perfbench``'s broken whole-epoch program) is never served the
+    sound entry, and the sound call after it never the fault's."""
+    forget_runners()
+    sound_runners = trainer.make_epoch_runners
+    sound, _ = counted("epoch", lambda: tiny_job(tmp_path / "sound"))
+    ran = []
+
+    def unchanged(model, tx, loss_fn, donate=True):
+        train_epoch, eval_epoch = sound_runners(model, tx, loss_fn, False)
+
+        def broken(state, xs, ys, order):
+            ran.append(order.shape)
+            return state, train_epoch(state, xs, ys, order)[1]
+
+        return broken, eval_epoch
+
+    with monkeypatch.context() as patch:
+        patch.setattr(trainer, "make_epoch_runners", unchanged)
+        planted, added = counted(
+            "epoch", lambda: tiny_job(tmp_path / "planted"))
+        assert ran and added["built"] == 1 and added["reused"] == 0
+        # the state went nowhere: validation saw the initial weights
+        assert planted.final_metrics["loss"] != sound.final_metrics["loss"]
+    served, added = counted("epoch", lambda: tiny_job(tmp_path / "after"))
+    assert added == REUSED
+    assert len(ran) == 1
+    assert served.final_metrics == sound.final_metrics
+
+
+def test_every_legitimate_shape_has_a_pair_of_its_own_and_a_repeat_is_flagged(
+        tmp_path):
+    """One pair of runners per shape set, one trace a runner: under the
+    strict setting a third and a fourth data-set size pass (a later cycle's
+    data set has another size), a size seen before brings its pair back
+    with nothing to trace and no new guard instance, and what is flagged is
+    a runner that traces a second time -- here the signature it has already
+    traced, its cache lost."""
+    forget_runners()
+    with recompile.strict():
+        for pairs in (16, 24, 32, 40):
+            _, added = counted(
+                "epoch", lambda: tiny_job(tmp_path / str(pairs), pairs=pairs))
+            assert added == BUILT
+        assert not {"trainer.train_epoch", "trainer.eval_epoch"} \
+            & set(recompile.over_budget())
+        for pairs in (24, 40, 24):
+            _, added = counted("epoch", lambda: tiny_job(
+                tmp_path / f"again{pairs}", pairs=pairs))
+            assert added == REUSED
+        _, eval_epoch = trainer.memoized_runners(
+            "epoch", TrainConfig(), TINY_MODEL,
+            (4, (32, 32, 3), "float32", (32, 32, 1), "float32", 18, 6))
+        eval_epoch.clear_cache()    # what jax.clear_caches() does to it
+        with pytest.raises(recompile.RecompileBudgetExceeded,
+                           match="trainer.eval_epoch"):
+            tiny_job(tmp_path / "lost", pairs=24)
+    assert recompile.over_budget()["trainer.eval_epoch"] == 1
+
+
+@pytest.mark.parametrize("strict", [True, False])
+def test_a_shape_that_changes_inside_a_job_is_flagged(
+        tmp_path, monkeypatch, caplog, strict):
+    """A leak: each epoch's ``order`` one batch shorter than the last, so
+    the whole-epoch program is traced and compiled anew every epoch. The
+    guard raises under the strict setting, and warns otherwise, at the
+    second trace."""
+    forget_runners()
+    sound = trainer.data_lib.epoch_order
+    shuffled = []
+
+    def shrinking(n, batch_size, shuffle, rng):
+        order = sound(n, batch_size, shuffle, rng)
+        if not shuffle:
+            return order        # the validation split's, made once
+        shuffled.append(len(order))
+        return order[:len(order) - len(shuffled) + 1]
+
+    monkeypatch.setattr(trainer.data_lib, "epoch_order", shrinking)
+    with recompile.strict(strict), caplog.at_level("WARNING"):
+        if strict:
+            with pytest.raises(recompile.RecompileBudgetExceeded,
+                               match="trainer.train_epoch"):
+                tiny_job(tmp_path, epochs=3)
+            assert len(shuffled) == 2
+        else:
+            tiny_job(tmp_path, epochs=3)
+            assert caplog.text.count("'trainer.train_epoch' retraced") == 2
+    assert recompile.over_budget()["trainer.train_epoch"] == (
+        1 if strict else 2)
+
+
+def one_device_mesh():
+    from robotic_discovery_platform_tpu.parallel import mesh as mesh_lib
+
+    return mesh_lib.make_serving_mesh(1)
+
+
+def test_a_mesh_of_the_default_device_alone_trains_without_one(tmp_path):
+    """``serving/rollout.py``'s ``training_mesh()`` hands a one-chip
+    replica's retraining cycle ``make_serving_mesh(1)``: nothing to shard,
+    so the job is the single-device one, kept runners included, of the
+    model any mesh trains (XLA convolutions), to the last digit."""
+    forget_runners()
+    xla_convs = dataclasses.replace(TINY_MODEL, conv_impl="flax")
+    plain, added = counted(
+        "epoch", lambda: tiny_job(tmp_path / "plain", model=xla_convs))
+    assert added == BUILT
+    before = len(recompile.stats_for("parallel.train_step"))
+    meshed, added = counted("epoch", lambda: tiny_job(
+        tmp_path / "meshed", mesh=one_device_mesh()))
+    assert added == REUSED
+    assert len(recompile.stats_for("parallel.train_step")) == before
+    assert meshed.final_metrics == plain.final_metrics
+
+
+def test_the_rollout_cycle_reuses_its_step_runners(tmp_path):
+    """What the rollout manager's drift cycles do in the serving process
+    (``serving/rollout.py`` ``_retrain``): the same ``TrainConfig`` and
+    ``ModelConfig`` over the data directory, which has grown since the last
+    cycle, under the one-chip replica's mesh. Every batch is full, so the
+    second cycle feeds the step runners the first one's shapes: they come
+    back from the memo and trace nothing."""
+    from robotic_discovery_platform_tpu.workflows.retraining import (
+        run_retraining_pipeline,
+    )
+
+    forget_runners()
+    cfg = TrainConfig(
+        epochs=1, batch_size=4, img_size=32, validation_split=0.25,
+        tracking_uri=f"file:{tmp_path}/mlruns", loader_workers=2,
+        checkpoint_dir=f"{tmp_path}/ckpt", dataset_dir=str(tmp_path / "data"))
+    cycles = []
+    with recompile.strict():
+        for pairs in (16, 24):
+            synthetic.generate_dataset(tmp_path / "data", pairs, 48, 64,
+                                       seed=3)
+            result, added = counted("step", lambda: run_retraining_pipeline(
+                cfg, model_cfg=TINY_MODEL, mesh=one_device_mesh()))
+            assert result.succeeded, result.message
+            cycles.append(added)
+    assert cycles == [BUILT, REUSED]
 
 
 def test_an_exception_in_an_epoch_closes_every_span(tmp_path, monkeypatch):
