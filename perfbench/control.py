@@ -3,22 +3,20 @@ verdict, on the chip at a cell's own size, many seeds to a process:
 
     python3 perfbench/control.py --workload <name> --seeds 11,12,13 --controls 3
 
-For each seed the cell's driver sets up (the job's first steps and first
-whole epoch through the program), the plain reference follows them, and
-every number of ``correct`` is read: the program against the reference,
-and, for the first ``--controls`` seeds,
-
-- the control against the reference: the reference itself, put in the
-  program's place and computed in the nearest precision below the one the
-  configuration states (int8 for bf16);
-- the evaluation path's planted fault: the reference validating with the
-  running statistics the job started from.
+For each seed the cell's driver sets up (whatever of the timed path
+``correct`` compares), the plain reference follows it, and every number of
+``correct`` is read: the program against the reference, and, for the first
+``--controls`` seeds, each thing that the driver's ``controls(job, want)``
+puts in the program's place: the control (the reference itself, computed in
+the nearest precision below the one the configuration states) and the
+faults the driver plants. A row holds the readings of each under the name
+the driver gives it.
 
 Each is judged with the cell's own limits file (``limits/<cell>.json``):
-the program has to come out correct, the control and the fault not. The
-exit code is 1 where one of them does not. A limit belongs above the
-program's largest and below the control's smallest reading. The benchmark's
-own runs never run this.
+the program has to come out correct, every control and fault not. The exit
+code is 1 where one of them does not. A limit belongs above the program's
+largest and below the controls' smallest reading. The benchmark's own runs
+never run this.
 """
 
 from __future__ import annotations
@@ -36,8 +34,7 @@ if str(ROOT) not in sys.path:
 from perfbench.lib import compare, spec  # noqa: E402
 
 
-CONTROL = "int8"
-WINDOW_ONLY = ("window_epochs_missing",)    # needs a window: not read here
+WINDOW_ONLY = "window_"     # a number so named needs a window: not read here
 
 
 def read_seed(bench, workload: str, seed: int, control: bool,
@@ -48,34 +45,54 @@ def read_seed(bench, workload: str, seed: int, control: bool,
     driver = bench.driver(cell.traffic["driver"])
     try:
         job = driver.setup(cell)
-        want = driver.follow(job, stale_eval=control)
+        want = driver.follow(job, controls=control)
         row = {"seed": seed,
                "program": driver.readings(job, job.produced, want)}
         if control:
-            row["control"] = driver.readings(
-                job, driver.follow(job, CONTROL), want)
-            # the reference itself, but for its validation
-            stale = {part: {**body, "val_loss": body["val_loss_stale"]}
-                     for part, body in want.items()}
-            row["stale_eval"] = driver.readings(job, stale, want)
+            for name, got in driver.controls(job, want).items():
+                row[name] = driver.readings(job, got, want)
         return row
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
 
 
+def _readings(rows) -> list:
+    """(seed, who, numbers) of every reading: the program's and, under the
+    names the driver gave them, the controls'."""
+    return [(r["seed"], who, numbers) for r in rows
+            for who, numbers in r.items() if who != "seed"]
+
+
 def verdicts(rows, limits) -> dict:
     """who -> [(seed, correct, numbers over their limit)], by the cell's own
     limits."""
-    limits = {k: v for k, v in limits.items() if k not in WINDOW_ONLY}
+    limits = {k: v for k, v in limits.items()
+              if not k.startswith(WINDOW_ONLY)}
     out = {}
-    for who in ("program", "control", "stale_eval"):
-        for r in rows:
-            if who in r:
-                ok, table = compare.judge(r[who], limits)
-                over = sorted(k for k, t in table.items()
-                              if not t["value"] <= t["limit"])
-                out.setdefault(who, []).append((r["seed"], ok, over))
+    for seed, who, numbers in _readings(rows):
+        ok, table = compare.judge(numbers, limits)
+        over = sorted(k for k, t in table.items()
+                      if not t["value"] <= t["limit"])
+        out.setdefault(who, []).append((seed, ok, over))
     return out
+
+
+def summarise(rows) -> dict:
+    """number -> the program's largest reading and each control's smallest."""
+    read = {}
+    for _, who, numbers in _readings(rows):
+        for name, value in numbers.items():
+            read.setdefault(name, {}).setdefault(who, []).append(value)
+    return {name: {f"{who}_max" if who == "program" else f"{who}_min":
+                   (max if who == "program" else min)(values)
+                   for who, values in by.items()}
+            for name, by in read.items()}
+
+
+def passed(judged: dict) -> bool:
+    """The program sound on every seed, every control and fault caught."""
+    return all(ok == (who == "program")
+               for who, rows in judged.items() for _, ok, _ in rows)
 
 
 def main(argv=None) -> int:
@@ -98,13 +115,7 @@ def main(argv=None) -> int:
             bench, args.workload, seed, i < args.controls,
             ROOT / ".perfbench_runs" / f"control-{args.workload}"))
         print(json.dumps(rows[-1]), flush=True)
-    names = list(rows[0]["program"])
-    summary = {n: {"program_max": max(r["program"][n] for r in rows),
-                   "control_min": min((r["control"][n] for r in rows
-                                       if "control" in r), default=None),
-                   "stale_eval_min": min((r["stale_eval"][n] for r in rows
-                                          if "stale_eval" in r), default=None)}
-               for n in names}
+    summary = summarise(rows)
     judged = verdicts(rows, bench.limits(args.workload))
     print(json.dumps({"workload": args.workload, "summary": summary,
                       "verdicts": judged}))
@@ -112,10 +123,7 @@ def main(argv=None) -> int:
         Path(args.out).parent.mkdir(parents=True, exist_ok=True)
         Path(args.out).write_text(json.dumps(
             {"rows": rows, "summary": summary, "verdicts": judged}, indent=1))
-    sound = all(ok for _, ok, _ in judged.get("program", []))
-    caught = not any(ok for who in ("control", "stale_eval")
-                     for _, ok, _ in judged.get(who, []))
-    return 0 if sound and caught else 1
+    return 0 if passed(judged) else 1
 
 
 if __name__ == "__main__":

@@ -27,6 +27,15 @@ by its own checkpoints:
 ``check`` then lets the plain reference follow the three probe steps and
 every step of the first epoch, in the program's documented data order, and
 compares loss, first gradient, update, batch statistics and Adam's moment.
+
+Beside the five calls of a run (``setup``, ``window``, ``end_to_end``,
+``counters``, ``check``) the driver exports what the benchmark's tools and
+tests ask every driver for: ``abstract_step`` (one optimiser step as a
+function and the shapes it is fed), ``controls`` (the control and the
+planted faults that ``control.py`` judges) and, for ``memory_probe.py``
+alone, ``abstract_epoch``. What is specific to the U-Net and its images
+sits here and in ``reference/_unet.py``, ``lib/flops.py``, ``lib/scenes.py``
+and ``lib/order.py``, not in the harness.
 """
 
 from __future__ import annotations
@@ -41,7 +50,7 @@ from pathlib import Path
 
 import numpy as np
 
-from perfbench.lib import compare, order, scenes
+from perfbench.lib import compare, flops, order, scenes
 
 PROBE_STEPS = 3
 ADAM_B1 = 0.9       # optax.adam's default: after one step mu = (1 - b1) * g
@@ -259,12 +268,28 @@ def counters(job: Job, out: dict, window_s: float) -> dict:
     data, cfg = job.cell.traffic["dataset"], job.base_cfg
     n_val = len(order.train_val_split(data["pairs"], cfg.validation_split,
                                       cfg.seed)[1])
+    eval_batches = job.window_epochs * math.ceil(n_val / cfg.batch_size)
     return {"optimizer_steps": out["optimizer_steps"],
             "train_phase_s": out["train_phase_s"], "window_s": window_s,
             "window_epochs": job.window_epochs, "batch": cfg.batch_size,
-            "eval_batches": job.window_epochs * math.ceil(
-                n_val / cfg.batch_size),
+            "eval_batches": eval_batches,
+            "model_flops": model_flops(job.cell.config, cfg.batch_size,
+                                       out["optimizer_steps"], eval_batches),
             "attempted": out["optimizer_steps"]}
+
+
+def model_flops(config: dict, batch: int, steps: int,
+                eval_batches: int) -> float:
+    """The operations of the model that a window of ``steps`` optimiser
+    steps and ``eval_batches`` validation batches asks for, whatever
+    implements them (``lib/flops.py``: the convolutions' multiply-adds as 2,
+    three passes a convolution in training and two for the first, one in
+    evaluation; norms, activations, loss and optimiser not counted; a
+    validation tail filled by repeated rows counts as the batch it runs)."""
+    model, size = config["model"], config["train"]["img_size"]
+    return (steps * flops.step_flops(model, size, batch)
+            + eval_batches * flops.step_flops(model, size, batch,
+                                              train=False))
 
 
 def _normalise(job: Job, imgs, masks):
@@ -285,12 +310,12 @@ def _normalise(job: Job, imgs, masks):
             masks.astype(np.float32) / 255.0)
 
 
-def follow(job: Job, precision: str = "f32", stale_eval: bool = False) -> dict:
+def follow(job: Job, precision: str = "f32", controls: bool = False) -> dict:
     """What the plain reference gets for the probe's steps and the first
-    epoch's, in the shape of ``job.produced``. ``stale_eval`` adds, for
-    ``control.py``, the evaluation path's planted fault under
-    ``val_loss_stale``: validation with the running statistics the job
-    started from."""
+    epoch's, in the shape of ``job.produced``. ``controls`` adds what
+    :func:`controls` needs beside that: under ``val_loss_stale`` the
+    evaluation path's planted fault, validation with the running
+    statistics the job started from."""
     import jax
     import jax.numpy as jnp
 
@@ -319,7 +344,7 @@ def follow(job: Job, precision: str = "f32", stale_eval: bool = False) -> dict:
 
     def validate(out, params, stats, val_rows):
         out["val_loss"].append(validation(params, stats, val_rows))
-        if stale_eval:
+        if controls:
             out.setdefault("val_loss_stale", []).append(
                 validation(params, stats0, val_rows))
 
@@ -404,6 +429,21 @@ def readings(job: Job, got: dict, want: dict) -> dict:
     }
 
 
+def controls(job: Job, want: dict) -> dict:
+    """name -> what stands in the program's place, in the shape of
+    ``job.produced``, for ``control.py`` to read against ``want`` (from
+    ``follow(job, controls=True)``); each has to come out as not correct.
+    ``int8``: the reference itself with every convolution's operands
+    rounded to symmetric per-tensor int8, the nearest precision below the
+    configuration's bf16. ``stale_eval``: the evaluation path's planted
+    fault, the reference itself but for its validation."""
+    return {
+        "int8": follow(job, "int8"),
+        "stale_eval": {part: {**body, "val_loss": body["val_loss_stale"]}
+                       for part, body in want.items()},
+    }
+
+
 def check(job: Job, out: dict) -> dict:
     """name -> value of every number read; the harness holds each that the
     cell's limits file names to its limit."""
@@ -416,3 +456,65 @@ def check(job: Job, out: dict) -> dict:
     numbers["window_epochs_missing"] = float(job.window_epochs - min(
         epochs_run, sum(math.isfinite(v) for v in window_losses)))
     return numbers
+
+
+def _abstract(cell, **model_overrides):
+    """(model, tx, loss, abstract state, batch, size) of a cell, nothing
+    placed and nothing run."""
+    import jax
+    import optax
+
+    from robotic_discovery_platform_tpu.models import losses
+    from robotic_discovery_platform_tpu.models.unet import build_unet
+    from robotic_discovery_platform_tpu.training import trainer
+    from robotic_discovery_platform_tpu.utils.config import ModelConfig
+
+    train = cell.config["train"]
+    size, batch = train["img_size"], cell.traffic["train"]["batch_size"]
+    model = build_unet(ModelConfig(**cell.config["model"], **model_overrides))
+    tx = optax.adam(train["learning_rate"])
+    state = jax.eval_shape(
+        lambda: trainer.create_state(model, tx, jax.random.key(0), size))
+    return model, tx, losses.make_loss_fn(train["loss"]), state, batch, size
+
+
+def abstract_step(cell):
+    """(fn, args): the function the timed program runs for one optimiser
+    step and its arguments as ``jax.ShapeDtypeStruct``s, for a test or a
+    tool to place, compile and, with arrays in their place, run. The XLA
+    convolution path: what "auto" resolves to at this volume on a TPU (a
+    process that sees a CPU would resolve it otherwise)."""
+    import jax
+    import jax.numpy as jnp
+
+    from robotic_discovery_platform_tpu.training import trainer
+
+    model, tx, loss_fn, state, batch, size = _abstract(cell, conv_impl="flax")
+    return trainer.core_train_step(model, tx, loss_fn), (
+        state,
+        jax.ShapeDtypeStruct((batch, size, size, 3), jnp.float32),
+        jax.ShapeDtypeStruct((batch, size, size, 1), jnp.float32))
+
+
+def abstract_epoch(cell):
+    """(fn, args) of the whole-epoch scan that the window dispatches for a
+    resident data set, ``None`` where the job streams and the single step
+    is its program. For ``memory_probe.py`` only."""
+    import jax
+    import jax.numpy as jnp
+
+    from robotic_discovery_platform_tpu.training import trainer
+
+    data, train = cell.traffic["dataset"], cell.traffic["train"]
+    if data["kind"] != "arrays":
+        return None
+    model, tx, loss_fn, state, batch, size = _abstract(cell)
+    n = len(order.train_val_split(data["pairs"], train["validation_split"],
+                                  0)[0])
+    grid = order.epoch_order(n, batch, False, None)
+    train_epoch, _ = trainer.make_epoch_runners(model, tx, loss_fn)
+    return train_epoch, (
+        state,
+        jax.ShapeDtypeStruct((n, size, size, 3), jnp.float32),
+        jax.ShapeDtypeStruct((n, size, size, 1), jnp.float32),
+        jax.ShapeDtypeStruct(grid.shape, jnp.int32))

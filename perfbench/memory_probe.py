@@ -1,15 +1,18 @@
 """What the device's memory counters count, shown on the chip:
 
-    python3 perfbench/memory_probe.py --workload <name> [--batches 32,64]
+    python3 perfbench/memory_probe.py --workload <name>
 
-Builds the cell's own train program the way ``train_model`` does (the
-whole-epoch scan for a resident data set, the single step otherwise, and
-the single step at each of ``--batches``), compiles it, prints the
-compiler's ``memory_analysis()`` for that executable, runs it, and prints
-the allocator's counters before and after, so that each counter can be set
-beside the bytes the compiler planned: ``bytes_in_use`` beside the
-arguments and results, ``bytes_reserved`` beside the temporaries. Nothing
-here is timed; the benchmark's own runs never run this.
+Asks the cell's driver for its programs as functions and shapes
+(``abstract_step``: one optimiser step at the cell's batch; where the
+driver has it, ``abstract_epoch``: what the window dispatches, such as the
+whole-epoch scan for a resident data set), compiles each, prints the
+compiler's ``memory_analysis()`` for that executable, runs it three times
+on zeros of those shapes, and prints the allocator's counters before and
+after, so that each counter can be set beside the bytes the compiler
+planned: ``bytes_in_use`` beside the arguments and results,
+``bytes_reserved`` beside the temporaries. Nothing is donated, so a result
+is a second copy of the state beside its argument. Nothing here is timed;
+the benchmark's own runs never run this.
 """
 
 from __future__ import annotations
@@ -24,7 +27,7 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from perfbench.lib import order, spec  # noqa: E402
+from perfbench.lib import spec  # noqa: E402
 
 GIB = 2 ** 30
 
@@ -39,31 +42,21 @@ def _counters(device) -> dict:
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--workload", required=True)
-    parser.add_argument("--batches", default="32,64")
     args = parser.parse_args(argv)
 
     import jax
     import jax.numpy as jnp
-    import optax
 
     from perfbench.run import _devices
-    from robotic_discovery_platform_tpu.models import losses
-    from robotic_discovery_platform_tpu.models.unet import build_unet
-    from robotic_discovery_platform_tpu.training import trainer
-    from robotic_discovery_platform_tpu.utils.config import ModelConfig
 
     bench = spec.Bench(ROOT)
-    entry = bench.workload(args.workload)
-    device = _devices(entry["chips"], True)[0]
-    config, traffic = bench.config(entry["config"]), bench.traffic(
-        entry["traffic"])
-    size, data = config["train"]["img_size"], traffic["dataset"]
-    model = build_unet(ModelConfig(**config["model"]))
-    tx = optax.adam(config["train"]["learning_rate"])
-    loss_fn = losses.make_loss_fn(config["train"]["loss"])
-
-    def state():
-        return trainer.create_state(model, tx, jax.random.key(0), size)
+    device = _devices(bench.workload(args.workload)["chips"], True)[0]
+    cell = bench.cell(args.workload, 0, 0.0, ROOT / ".perfbench_runs")
+    driver = bench.driver(cell.traffic["driver"])
+    programs = {"one optimiser step": driver.abstract_step(cell)}
+    epoch = getattr(driver, "abstract_epoch", lambda cell: None)(cell)
+    if epoch is not None:
+        programs["the window's program"] = epoch
 
     def watch_run(fn):
         """Run ``fn`` with a thread sampling in-use and reserved together."""
@@ -103,33 +96,17 @@ def main(argv=None) -> int:
             "after_GiB": _counters(device)}), flush=True)
 
     print(json.dumps({"start_GiB": _counters(device)}), flush=True)
-    for batch in sorted(int(b) for b in args.batches.split(",")):
-        x = jnp.zeros((batch, size, size, 3), jnp.float32)
-        y = jnp.zeros((batch, size, size, 1), jnp.float32)
-        compiled = trainer.make_train_step(model, tx, loss_fn).lower(
-            state(), x, y).compile()
+    for name, (fn, shapes) in programs.items():
+        compiled = jax.jit(fn).lower(*shapes).compile()
+        arrays = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), shapes)
 
-        def steps():
-            s = state()
-            for _ in range(3):      # the state is donated, as in the job
-                s, loss = compiled(s, x, y)
-            return loss
+        def thrice():
+            for _ in range(3):
+                out = compiled(*arrays)
+            return out
 
-        report(f"train step, batch {batch}", compiled, steps)
-        del x, y, compiled
-        if batch == traffic["train"]["batch_size"] \
-                and data["kind"] == "arrays":
-            split = traffic["train"]["validation_split"]
-            n = len(order.train_val_split(data["pairs"], split, 0)[0])
-            xs = jnp.zeros((n, size, size, 3), jnp.float32)
-            ys = jnp.zeros((n, size, size, 1), jnp.float32)
-            grid = jnp.asarray(order.epoch_order(
-                n, batch, False, None).astype("int32"))
-            train_epoch, _ = trainer.make_epoch_runners(model, tx, loss_fn)
-            compiled = train_epoch.lower(state(), xs, ys, grid).compile()
-            report(f"whole-epoch scan, batch {batch}, {len(grid)} steps",
-                   compiled, lambda: compiled(state(), xs, ys, grid))
-            del xs, ys, grid, compiled
+        report(name, compiled, thrice)
+        del compiled, arrays
     return 0
 
 

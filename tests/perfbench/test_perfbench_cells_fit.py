@@ -1,5 +1,6 @@
-"""Each cell's train step, compiled here for a described (not attached)
-``v5e:2x2`` chip at the cell's batch: the compiler's own byte count stays
+"""Each cell's train step, as its driver's ``abstract_step`` hands it
+over, compiled here for a described (not attached) ``v5e:2x2`` chip at the
+cell's own shapes: the compiler's own byte count stays
 over the driver's floor of 4 GiB (25% of a chip), so a later change that
 shrinks a cell under it is seen on the CPU. Nothing runs; these are not
 chip measurements. All such compiles live in this one file (one process
@@ -42,44 +43,37 @@ def no_compile_cache():
     jax.config.update("jax_enable_compilation_cache", before)
 
 
-def _configs():
-    bench = spec.Bench(ROOT)
+def cases(root: Path = ROOT) -> list:
+    """One case for each (configuration, traffic) pair of ``root``'s
+    ``BENCHMARK.json``, named by the cells that share it; the case holds
+    the first of them, whose driver is asked for the step."""
     seen = {}
-    for w in bench.doc["workloads"]:
-        batch = bench.traffic(w["traffic"])["train"]["batch_size"]
-        seen.setdefault((w["config"], batch), []).append(w["name"])
-    return [pytest.param(c, b, id=f"{c}-b{b}:" + "+".join(cells))
-            for (c, b), cells in seen.items()]
+    for w in spec.Bench(root).doc["workloads"]:
+        seen.setdefault((w["config"], w["traffic"]), []).append(w["name"])
+    return [pytest.param(cells[0], id=f"{c}-{t}:" + "+".join(cells))
+            for (c, t), cells in seen.items()]
 
 
-@pytest.mark.parametrize("config,batch", _configs())
-def test_train_step_holds_over_the_floor(one_chip, no_compile_cache, config,
-                                         batch):
+def abstract_step(root: Path, workload: str):
+    """(fn, args) from the driver that the cell's traffic names: the
+    function the timed program runs for one optimiser step, and its
+    arguments as shapes."""
+    bench = spec.Bench(root)
+    cell = bench.cell(workload, 0, 0.0, root / ".perfbench_runs")
+    return bench.driver(cell.traffic["driver"]).abstract_step(cell)
+
+
+@pytest.mark.parametrize("workload", cases())
+def test_train_step_holds_over_the_floor(one_chip, no_compile_cache,
+                                         workload):
     import jax
-    import jax.numpy as jnp
-    import optax
 
-    from robotic_discovery_platform_tpu.models import losses
-    from robotic_discovery_platform_tpu.models.unet import build_unet
-    from robotic_discovery_platform_tpu.training import trainer
-    from robotic_discovery_platform_tpu.utils.config import ModelConfig
-
-    body = spec.Bench(ROOT).config(config)
-    # the XLA convolution path: what "auto" resolves to at this volume on a
-    # TPU (this process sees a CPU and would resolve it otherwise)
-    model = build_unet(ModelConfig(**body["model"], conv_impl="flax"))
-    size = body["train"]["img_size"]
-    tx = optax.adam(body["train"]["learning_rate"])
-    state = jax.eval_shape(
-        lambda: trainer.create_state(model, tx, jax.random.key(0), size))
-    place = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
-        a.shape, a.dtype, sharding=one_chip)
-    step = trainer.core_train_step(model, tx, losses.make_loss_fn("bce"))
-    compiled = jax.jit(step).lower(
-        jax.tree.map(place, state),
-        place(jax.ShapeDtypeStruct((batch, size, size, 3), jnp.float32)),
-        place(jax.ShapeDtypeStruct((batch, size, size, 1), jnp.float32)),
-    ).compile()
-    mem = compiled.memory_analysis()
+    fn, args = abstract_step(ROOT, workload)
+    placed = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), args)
+    mem = jax.jit(fn).lower(*placed).compile().memory_analysis()
     held = mem.temp_size_in_bytes + mem.argument_size_in_bytes
-    assert held > FLOOR, f"{config} at batch {batch}: {held / 2**30:.2f} GiB"
+    print(f"{workload}: {mem.temp_size_in_bytes / 2**30:.2f} GiB of "
+          f"temporaries + {mem.argument_size_in_bytes / 2**30:.2f} of "
+          "arguments")
+    assert held > FLOOR, f"{workload}: {held / 2**30:.2f} GiB"

@@ -157,13 +157,15 @@ DECODERS = [(True, "seg.retrain-resident"),
 
 @pytest.fixture(scope="module", params=DECODERS, ids=["seg", "unet-tconv"])
 def probed(request, tmp_path_factory):
-    """(driver, job after set-up, what the reference gets) for a decoder."""
+    """(driver, job after set-up, what the reference gets, what the driver
+    puts in the program's place as controls) for a decoder."""
     bilinear, workload = request.param
     bench = tiny_bench(bilinear, "arrays")
     driver = bench.driver("retrain")
     job = driver.setup(cell_of(bench, workload,
                                tmp_path_factory.mktemp("probe")))
-    return driver, job, driver.follow(job, stale_eval=True)
+    want = driver.follow(job, controls=True)
+    return driver, job, want, driver.controls(job, want)
 
 
 READ_IN_SETUP = {k: v for k, v in LIMITS.items()
@@ -171,11 +173,11 @@ READ_IN_SETUP = {k: v for k, v in LIMITS.items()
 
 
 def test_the_int8_control_is_not_correct(probed):
-    driver, job, want = probed
+    driver, job, want, controls = probed
     sound, table = compare.judge(
         driver.readings(job, job.produced, want), READ_IN_SETUP)
     assert sound, table
-    control = driver.readings(job, driver.follow(job, "int8"), want)
+    control = driver.readings(job, controls["int8"], want)
     ok, table = compare.judge(control, READ_IN_SETUP)
     assert not ok and control["grad_gap"] > LIMITS["grad_gap"], table
 
@@ -184,13 +186,64 @@ def test_validation_on_stale_statistics_is_not_correct(probed):
     """The evaluation path's fault, planted in the reference put in the
     program's place: validation with the running statistics the job
     started from moves ``val_loss_gap`` and nothing else."""
-    driver, job, want = probed
-    stale = {part: {**body, "val_loss": body["val_loss_stale"]}
-             for part, body in want.items()}
-    ok, table = compare.judge(driver.readings(job, stale, want),
-                              READ_IN_SETUP)
+    driver, job, want, controls = probed
+    ok, table = compare.judge(
+        driver.readings(job, controls["stale_eval"], want), READ_IN_SETUP)
     over = {k for k, row in table.items() if not row["value"] <= row["limit"]}
     assert not ok and over == {"val_loss_gap"}, table
+
+
+def test_the_drivers_controls_are_judged_as_before(probed):
+    """``control.py`` asks the driver which controls and planted faults it
+    has: exactly the two it had of its own, with the verdicts it gave."""
+    from perfbench import control
+
+    driver, job, want, controls = probed
+    assert list(controls) == ["int8", "stale_eval"]
+    row = {"seed": 5, "program": driver.readings(job, job.produced, want),
+           **{name: driver.readings(job, got, want)
+              for name, got in controls.items()}}
+    judged = control.verdicts([row], LIMITS)    # the window's number left out
+    assert judged["program"] == [(5, True, [])]
+    (_, ok, over), = judged["int8"]
+    assert not ok and "grad_gap" in over
+    assert judged["stale_eval"] == [(5, False, ["val_loss_gap"])]
+    assert control.passed(judged)
+    assert not control.passed({**judged, "int8": [(5, True, [])]})
+    assert not control.passed({**judged, "program": [(5, False, ["a"])]})
+    assert set(control.summarise([row])["loss_gap"]) == {
+        "program_max", "int8_min", "stale_eval_min"}
+
+
+@pytest.mark.parametrize("traffic", ["retrain-resident", "retrain-files"])
+def test_abstract_step_has_the_shapes_the_windows_call_feeds(traffic):
+    """At the real sizes, nothing placed and nothing run: batch 32 of
+    256x256 float32 rows whichever way the data set reaches the step (the
+    files' 640x480 frames are resized by the loader), the state of the
+    configuration's own parameters."""
+    import types
+
+    import jax
+
+    bench = spec.Bench(ROOT)
+    driver, body = bench.driver("retrain"), bench.config("unet-tconv")
+    cell = types.SimpleNamespace(config=body, traffic=bench.traffic(traffic))
+    fn, (state, x, y) = driver.abstract_step(cell)
+    assert (x.shape, x.dtype.name) == ((32, 256, 256, 3), "float32")
+    assert (y.shape, y.dtype.name) == ((32, 256, 256, 1), "float32")
+    leaves = jax.tree.leaves(state.params)
+    assert sum(leaf.size for leaf in leaves) == body["parameters"]
+    assert {leaf.dtype.name for leaf in leaves} == {"float32"}
+    assert callable(fn)
+    # the window's own program, for memory_probe.py: the whole-epoch scan
+    # over the resident rows, none where the job streams
+    epoch = driver.abstract_epoch(cell)
+    if traffic == "retrain-files":
+        assert epoch is None
+    else:
+        _, (_, xs, ys, grid) = epoch
+        assert xs.shape == (819, 256, 256, 3) and ys.shape[-1] == 1
+        assert (grid.shape, grid.dtype.name) == ((26, 32), "int32")
 
 
 def test_control_verdicts_go_by_the_cells_limits():
@@ -226,7 +279,7 @@ def test_the_window_is_a_fixed_number_of_epochs(seconds, epochs):
 def test_the_reference_starts_where_the_program_starts(probed):
     """Names and the first gradient agree leaf by leaf, for both decoders:
     the weights the benchmark makes reach the program whole."""
-    _, job, want = probed
+    _, job, want, _ = probed
     got, want = job.produced["probe"]["grad"], want["probe"]["grad"]
     assert set(got) == set(want)
     for k, ref in want.items():

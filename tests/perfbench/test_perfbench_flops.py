@@ -1,10 +1,17 @@
 """The benchmark's operation and byte counts against numbers derived by
-hand for both decoders, so that ``conv_roofline`` cannot pass 100% because a
-count was stale or belonged to the other configuration."""
+hand for both decoders, so that ``conv_roofline`` and ``step_mfu`` cannot
+pass 100% because a count was stale or belonged to the other configuration."""
+
+import json
+import types
+from pathlib import Path
 
 import pytest
 
-from perfbench.lib import flops
+from perfbench.layer_metrics import step_mfu
+from perfbench.lib import flops, spec, trace
+
+ROOT = Path(__file__).resolve().parents[2]
 
 SEG = dict(in_channels=3, num_classes=1, base_features=64, bilinear=True)
 TCONV = dict(SEG, bilinear=False)
@@ -64,3 +71,50 @@ def test_bytes_and_floor_by_hand():
     # memory-bound first and last layers lift the floor over the FLOP time
     assert compute_only < floor < 1.1 * compute_only
     assert floor == pytest.approx(0.040536, rel=1e-3)
+
+
+@pytest.mark.parametrize("config,forward", [("seg", 79_960_211_456),
+                                            ("unet-tconv", 96_334_774_272)])
+def test_model_flops_of_a_window_by_hand(config, forward):
+    """The window of the resident traffic at 50 s: 12 epochs of 26 steps and
+    of 7 validation batches (205 rows, the tail filled), batch 32."""
+    bench = spec.Bench(ROOT)
+    retrain = bench.driver("retrain")
+    body, traffic = bench.config(config), bench.traffic("retrain-resident")
+    want = 312 * 32 * (3 * forward - FIRST) + 84 * 32 * forward
+    assert retrain.model_flops(body, 32, 312, 84) == pytest.approx(
+        want, rel=1e-12)
+    # ... and the driver's counters state that window
+    job = types.SimpleNamespace(
+        cell=types.SimpleNamespace(config=body, traffic=traffic),
+        base_cfg=types.SimpleNamespace(batch_size=32, validation_split=0.2,
+                                       seed=7),
+        window_epochs=retrain.window_epochs(traffic, 50))
+    got = retrain.counters(job, {"optimizer_steps": 312,
+                                 "train_phase_s": 40.0}, 48.0)
+    assert got["eval_batches"] == 84 and got["attempted"] == 312
+    assert got["model_flops"] == retrain.model_flops(body, 32, 312, 84)
+
+
+def test_step_mfu_divides_the_drivers_operations_by_busy_time_and_peak():
+    doc = json.loads((Path(__file__).parent / "data"
+                      / "recorded_trace.json").read_text())
+    summary = trace.reduce(doc)         # one device plane, 160,022 ns busy
+    peaks = spec.Bench(ROOT).peaks("TPU v5 lite")
+
+    def read(**kw):
+        ctx = dict(trace=summary, peaks=peaks,
+                   counters={"model_flops": 1.5e10})
+        return step_mfu.read(types.SimpleNamespace(**{**ctx, **kw}))
+
+    assert read() == pytest.approx(100 * 1.5e10 / (160_022e-9 * 197e12))
+    assert 0 < read() < 100
+    # nothing to read: no trace, no peaks (a CPU run), a driver that states
+    # no operations, a trace in which the device never ran
+    assert read(trace=None) is None and read(peaks=None) is None
+    assert read(counters={}) is None
+    idle = types.SimpleNamespace(busy_s=0.0, devices=0)
+    assert read(trace=idle) is None
+    # four chips: the window's operations over four chips' busy seconds
+    four = types.SimpleNamespace(busy_s=summary.busy_s, devices=4)
+    assert read(trace=four) == pytest.approx(read() / 4)

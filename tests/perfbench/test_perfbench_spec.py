@@ -1,5 +1,7 @@
 """``BENCHMARK.json`` is well-formed by the contract's own rules, and every
-name in it leads to its file."""
+name in it leads to its file: in the repository, and in a copy of it into
+which a family that is no U-Net was laid by new files and entries alone
+(``conftest.toy_root``), so every test here counts once for each."""
 
 import json
 import math
@@ -14,13 +16,14 @@ ROOT = Path(__file__).resolve().parents[2]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
-PARAMETERS = {"seg": 17_262_977, "unet-tconv": 31_037_633}
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-@pytest.fixture(scope="module")
-def bench():
-    return spec.Bench(ROOT)
+@pytest.fixture(scope="module", params=["repository", "toy-family"])
+def bench(request):
+    if request.param == "repository":
+        return spec.Bench(ROOT)
+    return spec.Bench(request.getfixturevalue("toy_root"))
 
 
 def _line(text):
@@ -31,7 +34,7 @@ def test_top_level_keys_and_sizes(bench):
     doc = bench.doc
     assert set(doc) == {"command", "paths", "run_seconds", "configs",
                         "workloads", "end_to_end", "per_layer"}
-    assert (ROOT / "BENCHMARK.json").stat().st_size <= 64 * 1024
+    assert (bench.root / "BENCHMARK.json").stat().st_size <= 64 * 1024
     assert isinstance(doc["run_seconds"], int) and 1 <= doc["run_seconds"] <= 51
     # a full check of the full 24 cells has to fit into 43200 s
     runs = 2 + 14 * 24
@@ -39,7 +42,7 @@ def test_top_level_keys_and_sizes(bench):
     assert 1 <= len(doc["paths"]) <= 16
     for p in doc["paths"]:
         assert PATH.match(p) and not p.startswith("/") and ".." not in p
-        assert (ROOT / p).is_dir()
+        assert (bench.root / p).is_dir()
     assert 1 <= len(doc["command"]) <= 32
     for word in doc["command"]:
         assert _line(word) and not word.startswith("/") and ".." not in word
@@ -49,11 +52,11 @@ def test_top_level_keys_and_sizes(bench):
 
 def test_files_under_paths_are_named_from_name_characters(bench):
     for p in bench.doc["paths"]:
-        for f in (ROOT / p).rglob("*"):
+        for f in (bench.root / p).rglob("*"):
             if "__pycache__" in f.parts:
                 continue
             assert re.fullmatch(r"[A-Za-z0-9_.\-/]+",
-                                str(f.relative_to(ROOT))), f
+                                str(f.relative_to(bench.root))), f
 
 
 def test_configs(bench):
@@ -68,17 +71,17 @@ def test_configs(bench):
         assert NAME.match(c["name"]) and c["name"] in used
         assert _line(c["source"]) and _line(c["why"])
         assert any(c["file"].startswith(p + "/") for p in bench.doc["paths"])
-        body = json.loads((ROOT / c["file"]).read_text())
+        body = json.loads((bench.root / c["file"]).read_text())
         assert len(c["reduced"]) <= 16 and body["reduced"] == c["reduced"]
-        assert {"source", "model", "train", "assumed"} <= set(body)
-        # the plain reference sits beside the configuration, by its name
+        assert {"family", "parameters", "source", "model", "train",
+                "assumed"} <= set(body)
+        assert NAME.match(body["family"])
+        # the plain reference sits beside the configuration, by its name,
+        # and has the parameters that the configuration's own file states
         assert (bench.home / "reference" / f"{c['name']}.py").is_file()
-        # ... and builds the decoder the configuration states
         shapes = bench.reference(c["name"]).param_shapes(body["model"])
-        assert sum(math.prod(v) for v in shapes.values()) == PARAMETERS[
-            c["name"]]
-        assert any("ConvTranspose" in k for k in shapes) != \
-            body["model"]["bilinear"]
+        assert sum(math.prod(v) for v in shapes.values()) == \
+            body["parameters"]
 
 
 def test_workloads(bench):
@@ -95,8 +98,9 @@ def test_workloads(bench):
         bench.config(w["config"])
         traffic = bench.traffic(w["traffic"])
         driver = bench.driver(traffic["driver"])
-        for fn in ("setup", "window", "end_to_end", "counters", "check"):
-            assert callable(getattr(driver, fn))
+        for fn in ("setup", "window", "end_to_end", "counters", "check",
+                   "follow", "readings", "controls", "abstract_step"):
+            assert callable(getattr(driver, fn)), fn
         limits = bench.limits(w["name"])
         assert limits and all(v >= 0 for v in limits.values())
 
