@@ -725,6 +725,23 @@ TRAIN_RUNNERS = REGISTRY.counter(
     "(rdp_jit_traces_total says whether one did all the same).",
     ("family", "result"),
 )
+MOE_ROUTED_ROWS = REGISTRY.counter(
+    families.MOE_ROUTED_ROWS,
+    "Rows the expert layers' held experts took in training steps (one per "
+    "position and held expert among its top-k, over all layers), sampled "
+    "once an epoch from what the epoch's scan returns with its losses. "
+    "Routing drops nothing, so this is the experts' whole work.",
+)
+MOE_LOAD_RATIO = REGISTRY.gauge(
+    families.MOE_LOAD_RATIO,
+    "Largest over mean load among the experts held here, over the last "
+    "epoch's training steps and all layers (1 = perfectly even).",
+)
+TRAIN_TOKENS_RATE = REGISTRY.gauge(
+    families.TRAIN_TOKENS_RATE,
+    "Tokens consumed by optimiser steps per second over the last epoch's "
+    "train phase (token data sets only).",
+)
 
 _BREAKER_STATE_VALUES = {"closed": 0, "open": 1, "half_open": 2}
 

@@ -27,7 +27,7 @@ from pathlib import Path
 from types import SimpleNamespace
 
 from robotic_discovery_platform_tpu.tracking.store import FileStore
-from robotic_discovery_platform_tpu.utils.config import ModelConfig, from_dict
+from robotic_discovery_platform_tpu.utils.config import from_dict
 
 _DEFAULT_URI = "file:ml/mlruns"
 
@@ -160,39 +160,65 @@ def get_metric_history(run_id: str, key: str) -> list[dict]:
 
 _MODEL_CONFIG_FILE = "model_config.json"
 _MODEL_WEIGHTS_FILE = "variables.msgpack"
+#: the training task that built the model (``training/tasks.py``); an
+#: artifact without the file is the segmenter's, as all were before tasks
+_MODEL_FAMILY_FILE = "model_family.txt"
+#: weights above this many bytes are written as one raw ``.npy`` file a leaf
+#: under ``variables/`` instead of one msgpack blob: flax's ``to_bytes``
+#: builds the blob in memory at 0.3 GB/s, which for 2.6 GB of parameters
+#: made the registry write the longest phase of a retraining job
+_LEAF_FILES_ABOVE = 1024**3
+_MODEL_LEAVES_DIR = "variables"
 
 
-def save_model(variables, model_cfg: ModelConfig, path: Path) -> None:
-    """Write a self-describing model artifact directory."""
+def save_model(variables, model_cfg, path: Path) -> None:
+    """Write a self-describing model artifact directory: the configuration,
+    the task that trains it (found from the configuration's type) and the
+    weights."""
     from flax import serialization
+
+    from robotic_discovery_platform_tpu.training import checkpoint, tasks
 
     path = Path(path)
     path.mkdir(parents=True, exist_ok=True)
     (path / _MODEL_CONFIG_FILE).write_text(
         json.dumps(dataclasses.asdict(model_cfg), indent=2)
     )
-    (path / _MODEL_WEIGHTS_FILE).write_bytes(serialization.to_bytes(variables))
+    (path / _MODEL_FAMILY_FILE).write_text(tasks.task_for(model_cfg).name)
+    if checkpoint.tree_bytes(variables) > _LEAF_FILES_ABOVE:
+        checkpoint.write_leaves(path / _MODEL_LEAVES_DIR, variables)
+    else:
+        (path / _MODEL_WEIGHTS_FILE).write_bytes(
+            serialization.to_bytes(variables))
 
 
 def load_model_dir(path: Path):
-    """Load (model, variables) from an artifact directory."""
+    """Load (model, variables) from an artifact directory; the model is
+    built by the task the artifact names."""
     from flax import serialization
 
-    from robotic_discovery_platform_tpu.models.unet import build_unet, init_unet
+    from robotic_discovery_platform_tpu.training import tasks
 
     path = Path(path)
-    cfg = from_dict(ModelConfig, json.loads((path / _MODEL_CONFIG_FILE).read_text()))
-    model = build_unet(cfg)
-    import jax
+    family = path / _MODEL_FAMILY_FILE
+    task = tasks.task_named(
+        family.read_text().strip() if family.is_file() else tasks.UNET.name)
+    cfg = from_dict(task.config_type,
+                    json.loads((path / _MODEL_CONFIG_FILE).read_text()))
+    model = task.build(cfg)
+    if (path / _MODEL_LEAVES_DIR).is_dir():
+        from robotic_discovery_platform_tpu.training import checkpoint
 
-    template = init_unet(model, jax.random.key(0))
-    variables = serialization.from_bytes(
-        template, (path / _MODEL_WEIGHTS_FILE).read_bytes()
-    )
+        variables = checkpoint.read_leaves(
+            path / _MODEL_LEAVES_DIR, task.template(model))
+    else:
+        variables = serialization.from_bytes(
+            task.template(model), (path / _MODEL_WEIGHTS_FILE).read_bytes()
+        )
     return model, variables
 
 
-def log_model(variables, model_cfg: ModelConfig, artifact_path: str = "model",
+def log_model(variables, model_cfg, artifact_path: str = "model",
               registered_model_name: str | None = None) -> int | None:
     """Save the model under the active run's artifacts and optionally register
     a new version (the reference's ``mlflow.pytorch.log_model(...,

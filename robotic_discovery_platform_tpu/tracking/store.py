@@ -43,6 +43,13 @@ def _resolve_uri(uri: str) -> Path:
     return Path(uri)
 
 
+def _link_or_copy(src, dst):
+    try:
+        os.link(src, dst)
+    except OSError:
+        shutil.copy2(src, dst)
+
+
 class FileStore:
     """All mutating operations are guarded by a process-local lock and use
     atomic JSON rewrites (tmp + rename); metric appends are O(1) JSONL."""
@@ -162,7 +169,10 @@ class FileStore:
             if source_dir is not None:
                 if dest.exists():
                     shutil.rmtree(dest)
-                shutil.copytree(source_dir, dest)
+                # hard links where the registry shares the artifacts' file
+                # system (a version's files are written once): registering
+                # gigabytes of weights then costs no second copy of them
+                shutil.copytree(source_dir, dest, copy_function=_link_or_copy)
             versions.append(
                 {
                     "version": version,

@@ -248,3 +248,52 @@ class StreamingBatches:
 
     def __len__(self):
         return max(1, int(np.ceil(len(self.names) / self.batch_size)))
+
+
+# -- token data sets and block-diffusion noise --------------------------------
+
+#: streams of :func:`block_diffusion_noise`: training steps draw from the
+#: first, validation from the second
+TRAIN_NOISE, EVAL_NOISE = 0, 1
+
+
+def token_arrays(tokens) -> np.ndarray:
+    """A resident token data set: ``[n, L]`` int32, sequences full (packed,
+    no padding; attention crosses document boundaries inside a sequence)."""
+    tokens = np.asarray(tokens)
+    if tokens.ndim != 2 or not np.issubdtype(tokens.dtype, np.integer):
+        raise ValueError(
+            f"a token data set is [n, L] integers, got {tokens.dtype} "
+            f"{tokens.shape}")
+    return np.ascontiguousarray(tokens, np.int32)
+
+
+def block_diffusion_noise(seed, stream: int, index, batch: int,
+                          seq_len: int, block: int):
+    """The noise of one batch, by a rule a reference can re-derive from
+    ``jax.random`` alone::
+
+        key    = fold_in(fold_in(jax.random.key(seed), stream), index)
+        kt, ku = jax.random.split(key)
+        t      = 1 - uniform(kt, [batch, seq_len / block])    in (0, 1]
+        masked = uniform(ku, [batch, seq_len]) < t of the position's block
+
+    ``stream`` is :data:`TRAIN_NOISE` with ``index`` the number of optimiser
+    steps the job has taken before this one (over all its epochs and calls:
+    a resumed job continues the sequence), or :data:`EVAL_NOISE` with
+    ``index`` 0: every validation batch of every epoch sees the same noise,
+    so validation losses compare across epochs. Returns ``(masked [batch,
+    seq_len] bool, t [batch, seq_len] float32)``; ``seed`` (a 32-bit
+    integer) and ``index`` may be traced: a traced seed gives the key of the
+    same number, so one compiled step serves every job.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    key = jax.random.fold_in(
+        jax.random.fold_in(jax.random.key(seed), stream), index)
+    kt, ku = jax.random.split(key)
+    t = 1.0 - jax.random.uniform(kt, (batch, seq_len // block), jnp.float32)
+    t = jnp.repeat(t, block, axis=1)
+    masked = jax.random.uniform(ku, (batch, seq_len), jnp.float32) < t
+    return masked, t

@@ -33,11 +33,11 @@ from flax import struct
 
 from robotic_discovery_platform_tpu import tracking
 from robotic_discovery_platform_tpu.analysis import recompile
-from robotic_discovery_platform_tpu.models import losses as losses_lib
-from robotic_discovery_platform_tpu.models.unet import build_unet, init_unet
 from robotic_discovery_platform_tpu.observability import instruments as obs
 from robotic_discovery_platform_tpu.training import data as data_lib
-from robotic_discovery_platform_tpu.training.checkpoint import CheckpointManager
+from robotic_discovery_platform_tpu.training import tasks as tasks_lib
+from robotic_discovery_platform_tpu.training.checkpoint import (
+    CheckpointManager, tree_bytes)
 from robotic_discovery_platform_tpu.utils import transferguard
 from robotic_discovery_platform_tpu.utils.config import ModelConfig, TrainConfig
 from robotic_discovery_platform_tpu.utils.logging import get_logger
@@ -61,40 +61,41 @@ class TrainState(struct.PyTreeNode):
     best_val_loss: jnp.ndarray  # scalar f32
 
 
-def create_state(model, tx, rng, img_size: int) -> TrainState:
-    variables = init_unet(model, rng, img_size)
-    params = variables["params"]
+def task_state(task, model, tx, rng, cfg: TrainConfig) -> TrainState:
+    """The initial state of a job of ``task`` (``batch_stats`` empty for a
+    family without running statistics)."""
+    params, batch_stats = task.init_variables(model, rng, cfg)
     return TrainState(
         params=params,
         opt_state=tx.init(params),
-        batch_stats=variables.get("batch_stats", {}),
+        batch_stats=batch_stats,
         epoch=jnp.asarray(0, jnp.int32),
         best_val_loss=jnp.asarray(jnp.inf, jnp.float32),
     )
 
 
-def core_train_step(model, tx, loss_fn: Callable):
+def create_state(model, tx, rng, img_size: int) -> TrainState:
+    """The segmenter's initial state (:func:`task_state` of the U-Net)."""
+    return task_state(tasks_lib.UNET, model, tx, rng,
+                      TrainConfig(img_size=img_size))
+
+
+def core_train_step(model, tx, loss_fn: Callable, task=None):
     """Unjitted (state, x, y) -> (state, loss); the parallel layer jits this
-    with explicit shardings, the single-device path with plain jit."""
+    with explicit shardings, the single-device path with plain jit. What is
+    trained comes from ``task`` (``training/tasks.py``; the segmenter's when
+    not given); a task whose loss returns per-step numbers beside it makes
+    the second result a dict, the loss under ``"loss"``."""
+    task = tasks_lib.UNET if task is None else task
 
     def step(state: TrainState, x, y):
         # named scopes go into the operations' op_name metadata; the
         # backward pass keeps each under JAX's transpose(jvp(...)) prefix
         def compute(params):
-            variables = {"params": params}
-            with jax.named_scope("rdp.forward"):
-                if state.batch_stats:
-                    variables["batch_stats"] = state.batch_stats
-                    logits, updates = model.apply(
-                        variables, x, train=True, mutable=["batch_stats"]
-                    )
-                else:
-                    logits, updates = (
-                        model.apply(variables, x, train=True), {})
-            with jax.named_scope("rdp.loss"):
-                return loss_fn(logits, y), updates
+            return task.train_loss(model, loss_fn, params, state, x, y)
 
-        (loss, updates), grads = jax.value_and_grad(compute, has_aux=True)(state.params)
+        (loss, (updates, aux)), grads = jax.value_and_grad(
+            compute, has_aux=True)(state.params)
         with jax.named_scope("rdp.optimizer"):
             grad_updates, opt_state = tx.update(
                 grads, state.opt_state, state.params)
@@ -104,12 +105,13 @@ def core_train_step(model, tx, loss_fn: Callable):
             opt_state=opt_state,
             batch_stats=updates.get("batch_stats", state.batch_stats),
         )
-        return new_state, loss
+        return new_state, ({"loss": loss, **aux} if aux else loss)
 
     return step
 
 
-def make_train_step(model, tx, loss_fn: Callable, donate: bool = True):
+def make_train_step(model, tx, loss_fn: Callable, donate: bool = True,
+                    task=None):
     """Single-device jitted train step: a NEW ``jax.jit`` object on every
     call (``train_model`` keeps one per configuration and batch shape
     through :func:`memoized_runners`).
@@ -123,42 +125,43 @@ def make_train_step(model, tx, loss_fn: Callable, donate: bool = True):
     # no implicit bytes (prefetch_to_device is the sanctioned H2D path)
     return transferguard.apply(jax.jit(
         recompile.trace_guard("trainer.train_step", budget=1)(
-            core_train_step(model, tx, loss_fn)
+            core_train_step(model, tx, loss_fn, **_task_kw(task))
         ),
         donate_argnums=(0,) if donate else (),
     ))
 
 
-def core_eval_step(model, loss_fn: Callable):
-    """Unjitted (state, x, y) -> dict(loss, miou, dice, accuracy)."""
+def _task_kw(task) -> dict:
+    """The ``task`` argument of a builder, left out for the segmenter's: a
+    builder put in a sound one's place (a test's planted fault) keeps the
+    signature the builders had before there were tasks."""
+    return {} if task is None or task is tasks_lib.UNET else {"task": task}
+
+
+def core_eval_step(model, loss_fn: Callable, task=None):
+    """Unjitted (state, x, y) -> the task's evaluation metrics, ``"loss"``
+    among them (the segmenter's: loss, miou, dice, accuracy)."""
+    task = tasks_lib.UNET if task is None else task
 
     @jax.named_scope("rdp.eval")
     def step(state: TrainState, x, y):
-        variables = {"params": state.params}
-        if state.batch_stats:
-            variables["batch_stats"] = state.batch_stats
-        logits = model.apply(variables, x, train=False)
-        return {
-            "loss": loss_fn(logits, y),
-            "miou": losses_lib.mean_iou(logits, y),
-            "dice": losses_lib.dice_coefficient(logits, y),
-            "accuracy": losses_lib.pixel_accuracy(logits, y),
-        }
+        return task.evaluate(model, loss_fn, state, x, y)
 
     return step
 
 
-def make_eval_step(model, loss_fn: Callable):
+def make_eval_step(model, loss_fn: Callable, task=None):
     """Single-device jitted evaluation step, built and budgeted as
     :func:`make_train_step`'s."""
     return transferguard.apply(jax.jit(
         recompile.trace_guard("trainer.eval_step", budget=1)(
-            core_eval_step(model, loss_fn)
+            core_eval_step(model, loss_fn, **_task_kw(task))
         )
     ))
 
 
-def make_epoch_runners(model, tx, loss_fn: Callable, donate: bool = True):
+def make_epoch_runners(model, tx, loss_fn: Callable, donate: bool = True,
+                       task=None):
     """Whole-epoch runners: one compiled dispatch + one host fetch per epoch.
 
     The per-batch Python loop pays a host->device dispatch and a loss fetch
@@ -171,7 +174,9 @@ def make_epoch_runners(model, tx, loss_fn: Callable, donate: bool = True):
     pipeline).
 
     Returns ``(train_epoch, eval_epoch)``:
-      train_epoch(state, xs, ys, order) -> (state, mean_loss)
+      train_epoch(state, xs, ys, order) -> (state, mean_loss), or for a
+        task with per-step numbers (state, dict of their means, the mean
+        loss under "loss", and each step's loss under "step_loss")
       eval_epoch(state, xs, ys, order) -> dict of mean metrics
 
     Two NEW ``jax.jit`` objects on every call, each budgeted at one trace
@@ -179,8 +184,8 @@ def make_epoch_runners(model, tx, loss_fn: Callable, donate: bool = True):
     configuration AND data-set size (:func:`memoized_runners`), so an
     ``order`` of another length reaching the same object is a leak.
     """
-    step = core_train_step(model, tx, loss_fn)
-    estep = core_eval_step(model, loss_fn)
+    step = core_train_step(model, tx, loss_fn, **_task_kw(task))
+    estep = core_eval_step(model, loss_fn, **_task_kw(task))
 
     def train_epoch(state, xs, ys, order):
         def body(s, idx):
@@ -188,7 +193,10 @@ def make_epoch_runners(model, tx, loss_fn: Callable, donate: bool = True):
             return s2, loss
 
         state, losses = jax.lax.scan(body, state, order)
-        return state, jnp.mean(losses)
+        out = jax.tree.map(lambda v: jnp.mean(v, axis=0), losses)
+        if isinstance(out, dict):
+            out["step_loss"] = losses["loss"]
+        return state, out
 
     def eval_epoch(state, xs, ys, order):
         def body(_, idx):
@@ -219,25 +227,47 @@ def make_epoch_runners(model, tx, loss_fn: Callable, donate: bool = True):
 #: at two data-set sizes, a test process many small ones in turn.
 RUNNER_MEMO_BOUND = 4
 
+#: A train state (params + optimiser state + statistics) above this many
+#: bytes is never duplicated on the device: its checkpoints are fetched to
+#: the host leaf by leaf before the next donated step, a restore places leaf
+#: by leaf, and "best" is the saved step that holds it instead of a second
+#: tree in HBM. A smaller state keeps its on-device snapshot and best copy:
+#: the copy is a device-side pass, the fetch would be synchronous every
+#: epoch at the 3.8 GB/s a v5e's host takes whole leaves.
+_DEVICE_SNAPSHOT_MAX_BYTES = 1024**3
+
 _runner_memo_lock = threading.Lock()
 
 
 @functools.lru_cache(maxsize=RUNNER_MEMO_BOUND)
-def _kept_runners(family, builders, model_cfg, learning_rate, loss,
-                  dice_weight, donate, guard_mode, shapes):
+def _kept_runners(family, builders, task, model_cfg, cfg, donate,
+                  guard_mode, shapes):
     """The memo behind :func:`memoized_runners`: every argument is part of
     the key, and model, optimiser and loss are built from it here, so a
-    kept runner can close over nothing its key does not say."""
-    model = build_unet(model_cfg)
-    tx = optax.adam(learning_rate)
-    loss_fn = losses_lib.make_loss_fn(loss, dice_weight)
+    kept runner can close over nothing its key does not say. ``cfg`` is the
+    job's ``TrainConfig`` cut down to what shapes the programs
+    (:func:`_program_settings`)."""
+    model = task.build(model_cfg)
+    tx = optax.adam(cfg.learning_rate)
+    loss_fn = task.make_loss(cfg)
+    kw = _task_kw(task)
     if family == "epoch":
-        return builders[0](model, tx, loss_fn, donate=donate)
-    return (builders[0](model, tx, loss_fn, donate=donate),
-            builders[1](model, loss_fn))
+        return builders[0](model, tx, loss_fn, donate=donate, **kw)
+    return (builders[0](model, tx, loss_fn, donate=donate, **kw),
+            builders[1](model, loss_fn, **kw))
 
 
-def memoized_runners(family: str, cfg: TrainConfig, model_cfg: ModelConfig,
+def _program_settings(task, cfg: TrainConfig) -> TrainConfig:
+    """A ``TrainConfig`` that differs from the default only in what the
+    runners of ``task`` close over: the learning rate and whatever the
+    task's loss reads (``task.memo_fields``). Epochs, directories and, for
+    a task that does not read it, the seed stay out of the key."""
+    return TrainConfig(
+        learning_rate=cfg.learning_rate,
+        **{name: getattr(cfg, name) for name in task.memo_fields})
+
+
+def memoized_runners(family: str, cfg: TrainConfig, model_cfg,
                      shapes: tuple) -> tuple:
     """``train_model``'s single-device runners, the same ``jax.jit``
     objects for every call whose program-shaping settings and shapes are
@@ -247,8 +277,9 @@ def memoized_runners(family: str, cfg: TrainConfig, model_cfg: ModelConfig,
     ``family`` is ``"epoch"`` (:func:`make_epoch_runners`) or ``"step"``
     (:func:`make_train_step` with :func:`make_eval_step`); either way a
     ``(train, evaluate)`` pair. The key is everything the runners close
-    over, by value: ``model_cfg`` as the job uses it, the optimiser's and
-    the loss's hyper-parameters, donation, the transfer guard's mode; the
+    over, by value: the task (found from ``model_cfg``'s type) and
+    ``model_cfg`` as the job uses it, the optimiser's and the loss's
+    hyper-parameters, donation, the transfer guard's mode; the
     builder functions themselves, looked up through this module at call
     time, so that a replaced builder (a test's planted fault) is never
     served a sound entry nor leaves its own behind for a sound call; and
@@ -261,9 +292,10 @@ def memoized_runners(family: str, cfg: TrainConfig, model_cfg: ModelConfig,
                 else (make_train_step, make_eval_step))
     with _runner_memo_lock:     # exact counts, and no pair built twice
         built = _kept_runners.cache_info().misses
+        task = tasks_lib.task_for(model_cfg)
         runners = _kept_runners(
-            family, builders + (core_train_step, core_eval_step), model_cfg,
-            cfg.learning_rate, cfg.loss, cfg.dice_weight, cfg.donate_state,
+            family, builders + (core_train_step, core_eval_step), task,
+            model_cfg, _program_settings(task, cfg), cfg.donate_state,
             transferguard.resolve_transfer_guard(), shapes)
         built = _kept_runners.cache_info().misses > built
     obs.TRAIN_RUNNERS.labels(
@@ -363,7 +395,7 @@ class TrainResult:
 
 def train_model(
     cfg: TrainConfig = TrainConfig(),
-    model_cfg: ModelConfig = ModelConfig(),
+    model_cfg: ModelConfig | Any = ModelConfig(),
     arrays: tuple | None = None,
     resume: bool = False,
     mesh=None,
@@ -374,8 +406,12 @@ def train_model(
 
     Args:
         cfg / model_cfg: configuration (defaults = reference constants).
+            The type of ``model_cfg`` says what is trained
+            (``training/tasks.py``): a ``ModelConfig`` the segmenter, a
+            ``BlockDiffLMConfig`` a block-diffusion language model.
         arrays: optional in-memory ((xs, ys)) dataset overriding
-            ``cfg.dataset_dir`` (tests, synthetic smoke runs).
+            ``cfg.dataset_dir`` (tests, synthetic smoke runs); for a token
+            task ``(tokens [n, L] int32, None)``.
         resume: restore the latest orbax checkpoint under
             ``cfg.checkpoint_dir`` and continue from its epoch. In a
             multi-host job the restore is collective (every process calls
@@ -401,45 +437,13 @@ def train_model(
     with phases.stage("rdp.train.job"):
         t_start = time.perf_counter()
         with phases.stage("rdp.train.init"):
+            task = tasks_lib.task_for(model_cfg)
             if arrays is not None:
-                xs, ys = arrays
-                # normalize to ndarrays once (dtype preserved, so integer inputs
-                # are normalized identically whether they arrive as arrays or
-                # lists): the index-array batching below needs fancy indexing
-                if not hasattr(xs, "nbytes"):
-                    xs = np.asarray(xs)
-                if not hasattr(ys, "nbytes"):
-                    ys = np.asarray(ys)
-                # Integer inputs get the same float normalization the file loader
-                # applies (data.PairedSegmentationData.load): images /255, masks
-                # /255 when 0/255-coded but a plain cast when already {0, 1} class
-                # indices -- dividing those by 255 would silently train against
-                # ~0.004 targets. Besides the wrong scale, u8 arrays reaching the
-                # jitted train step trip an XLA CPU space_to_batch crash on conv
-                # backprop (e.g. synthetic.generate_arrays' raw uint8 output).
-                if not np.issubdtype(xs.dtype, np.floating):
-                    xs = np.asarray(xs, np.float32) / 255.0
-                if not np.issubdtype(ys.dtype, np.floating):
-                    if np.max(ys, initial=0) > 1:
-                        # only the file loader's 0/255 coding gets the /255 path;
-                        # any other integer coding (class indices {0,2}, 0..K
-                        # multi-class labels) would silently become ~K/255 targets,
-                        # so reject it loudly instead of training against noise
-                        # (one O(N) pass; the sort for the message only on error)
-                        if not ((ys == 0) | (ys == 255)).all():
-                            raise ValueError(
-                                "integer masks must be coded {0,1} or {0,255}; got "
-                                f"values {np.unique(ys)[:8].tolist()}"
-                            )
-                        ys = np.asarray(ys, np.float32) / 255.0
-                    else:
-                        ys = np.asarray(ys, np.float32)
+                xs, ys = task.prepare(arrays, cfg)
                 n_samples = len(xs)
                 ds = None
             else:
-                # file-backed: decoded batch-by-batch by StreamingBatches below, so
-                # dataset size is bounded by disk, not host RAM
-                ds = data_lib.PairedSegmentationData(cfg.dataset_dir, cfg.img_size)
+                ds = task.file_data(cfg)
                 n_samples = len(ds)
             train_idx, val_idx = data_lib.train_val_split(
                 n_samples, cfg.validation_split, cfg.seed
@@ -447,12 +451,8 @@ def train_model(
             if len(val_idx) == 0:
                 raise ValueError("dataset too small for a validation split")
 
-            if mesh is not None and model_cfg.conv_impl != "flax":
-                # the custom-VJP Pallas convs carry no pjit partitioning rules;
-                # under a mesh the nn.Conv/XLA path is the sharding-correct one
-                from robotic_discovery_platform_tpu.utils.config import replace as _rep
-
-                model_cfg = _rep(model_cfg, conv_impl="flax")
+            if mesh is not None:
+                model_cfg = task.for_mesh(model_cfg)
             if (mesh is not None and mesh.size == 1
                     and mesh.devices.flat[0] == jax.devices()[0]):
                 # a mesh of the default device alone shards nothing (what
@@ -460,20 +460,6 @@ def train_model(
                 # replica's cycle): the same model as under any mesh, run by
                 # the single-device runners, which the process keeps
                 mesh = None
-            model = build_unet(model_cfg)
-            tx = optax.adam(cfg.learning_rate)
-            loss_fn = losses_lib.make_loss_fn(cfg.loss, cfg.dice_weight)
-            state = create_state(model, tx, jax.random.key(cfg.seed), cfg.img_size)
-
-            # Best-so-far candidate params/stats, held as independent DEVICE buffers
-            # (_copy_tree) so they survive donation of the live state and checkpoint
-            # as sharded global arrays under tensor parallelism.
-            best_params = None
-            best_stats = None
-
-            # Whole-epoch lax.scan mode: single device with the dataset resident in
-            # HBM (in-memory arrays, no mesh). One dispatch + one fetch per epoch
-            # instead of per step -- see make_epoch_runners.
             if cfg.epoch_mode not in ("auto", "scan", "stream"):
                 raise ValueError(
                     f"epoch_mode must be auto|scan|stream, got {cfg.epoch_mode!r}"
@@ -484,6 +470,49 @@ def train_model(
                 raise ValueError(
                     f"checkpoint_every must be >= 1, got {cfg.checkpoint_every}"
                 )
+            model = task.build(model_cfg)
+            tx = optax.adam(cfg.learning_rate)
+            loss_fn = task.make_loss(cfg)
+
+            def fresh_state():
+                return task_state(
+                    task, model, tx, jax.random.key(cfg.seed), cfg)
+
+            # Checkpoints carry the best-so-far candidate alongside the live state so
+            # a resumed run registers the params that actually achieved
+            # ``best_val_loss``, not whatever the last epoch happened to hold.
+            ckpt = CheckpointManager(cfg.checkpoint_dir, keep=cfg.keep_checkpoints)
+            latest = ckpt.latest_step() if resume else None
+            resuming = latest is not None
+            # A state too large to hold twice on the device is streamed: no
+            # on-device snapshot or best copy, leaf-by-leaf fetch and
+            # placement, "best" as the saved step that holds it. A job that
+            # resumes from a streamed checkpoint never builds the initial
+            # state it would throw away (its shapes are enough); any other
+            # job builds it, and its size decides.
+            if resuming and ckpt.is_streamed(latest):
+                streamed, state = True, None
+                abstract_state = jax.eval_shape(fresh_state)
+            else:
+                state = fresh_state()
+                streamed = (mesh is None and jax.process_count() == 1
+                            and tree_bytes(state)
+                            > _DEVICE_SNAPSHOT_MAX_BYTES)
+                abstract_state = jax.eval_shape(lambda: state) \
+                    if streamed else None
+
+            # Best-so-far candidate params/stats, held as independent DEVICE buffers
+            # (_copy_tree) so they survive donation of the live state and checkpoint
+            # as sharded global arrays under tensor parallelism. A streamed
+            # state keeps none: ``best_step`` names its checkpoint.
+            best_params = None
+            best_stats = None
+            best_step = ckpt.best_step() if streamed else None
+            best_host = None    # the best step's parameters, if fetched here
+
+            # Whole-epoch lax.scan mode: single device with the dataset resident in
+            # HBM (in-memory arrays, no mesh). One dispatch + one fetch per epoch
+            # instead of per step -- see make_epoch_runners.
             def _nbytes(a) -> int:
                 # no np.asarray here: that would copy (or device-fetch) the whole
                 # dataset just to read a byte count
@@ -554,16 +583,18 @@ def train_model(
                     # state is a consistent global array on every host
                     return jax.device_put(jnp.asarray(v, dtype), _rep)
 
-            # Checkpoints carry the best-so-far candidate alongside the live state so
-            # a resumed run registers the params that actually achieved
-            # ``best_val_loss``, not whatever the last epoch happened to hold.
             # Restore happens AFTER parallelize_training so the abstract template
             # carries the final (possibly TP-sharded) shardings and orbax lands each
             # host's shards directly on its devices.
-            ckpt = CheckpointManager(cfg.checkpoint_dir, keep=cfg.keep_checkpoints)
-            resuming = resume and ckpt.latest_step() is not None
 
-        if resuming:
+        if resuming and streamed:
+            with phases.stage("rdp.train.restore"):
+                # leaf after leaf from the files onto the device: neither
+                # side ever holds a second copy
+                state = ckpt.restore_streamed(
+                    {"state": abstract_state}, place=jax.device_put)["state"]
+                log.info("resumed from checkpoint at epoch %d", int(state.epoch))
+        elif resuming:
             with phases.stage("rdp.train.restore"):
                 template = {
                     "state": state,
@@ -670,14 +701,10 @@ def train_model(
                                 "batch_size": batch_size,
                                 "epochs": cfg.epochs,
                                 "validation_split": cfg.validation_split,
-                                "image_size": cfg.img_size,
                                 "optimizer": "adam",
-                                "loss": cfg.loss,
-                                "model": "UNet",
-                                "bilinear": model_cfg.bilinear,
-                                "base_features": model_cfg.base_features,
                                 "backend": jax.default_backend(),
                                 "num_devices": divisor,
+                                **task.run_params(cfg, model_cfg),
                             }
                         )
 
@@ -698,9 +725,14 @@ def train_model(
                                 order = jnp.asarray(data_lib.epoch_order(
                                     len(train_idx), batch_size, True, order_rng
                                 ))
-                                state, loss = train_epoch(
+                                state, out = train_epoch(
                                     state, xs_tr, ys_tr, order)
-                                train_loss = float(loss)
+                                if isinstance(out, dict):
+                                    out = jax.device_get(out)
+                                    train_loss = float(out["loss"])
+                                    step_losses = out.pop("step_loss")
+                                else:
+                                    train_loss, out = float(out), None
                             else:
                                 train_losses = []
                                 # device-prefetch: batch k+1 decodes + stages
@@ -717,8 +749,20 @@ def train_model(
                                         state, loss = train_step(state, dx, dy)
                                     train_losses.append(loss)
                                     step_num += 1
-                                train_loss = float(
-                                    np.mean([float(l) for l in train_losses]))
+                                if train_losses and isinstance(
+                                        train_losses[0], dict):
+                                    train_losses = jax.device_get(
+                                        train_losses)
+                                    out = jax.tree.map(
+                                        lambda *v: np.mean(v, axis=0),
+                                        *train_losses)
+                                    train_loss = float(out["loss"])
+                                    step_losses = [
+                                        l["loss"] for l in train_losses]
+                                else:
+                                    out = None
+                                    train_loss = float(np.mean(
+                                        [float(l) for l in train_losses]))
 
                             # Train-phase throughput (the float() above synced
                             # the device, so the measured window covers real
@@ -736,6 +780,10 @@ def train_model(
                             obs.TRAIN_STEP.observe(train_time / n_steps)
                             obs.TRAIN_RATE.set(
                                 n_steps * batch_size / train_time)
+                            if out is not None:
+                                task.observe(
+                                    out, n_steps, batch_size, train_time,
+                                    None if ds is not None else xs.shape[1:])
 
                         with phases.stage("rdp.train.validation"):
                             val = run_val()
@@ -747,19 +795,38 @@ def train_model(
                                     "train_loss", train_loss, step=epoch)
                                 tracking.log_metric(
                                     "val_loss", val["loss"], step=epoch)
-                                tracking.log_metric(
-                                    "val_miou", val["miou"], step=epoch)
-                                tracking.log_metric(
-                                    "val_dice", val["dice"], step=epoch)
+                                for name in task.val_logged:
+                                    tracking.log_metric(
+                                        f"val_{name}", val[name], step=epoch)
+                                if out is not None:
+                                    # a task with per-step numbers: every
+                                    # step's loss, numbered through the epochs
+                                    for i, v in enumerate(step_losses):
+                                        tracking.log_metric(
+                                            "train_step_loss", float(v),
+                                            step=epoch * n_steps + i)
                             epoch_seconds.append(time.perf_counter() - t_epoch)
                             log.info(
                                 "epoch %d/%d train_loss=%.4f val_loss=%.4f "
-                                "miou=%.4f (%.1fs)",
+                                "%s=%.4f (%.1fs)",
                                 epoch + 1, cfg.epochs, train_loss, val["loss"],
-                                val["miou"], epoch_seconds[-1],
+                                task.val_logged[0], val[task.val_logged[0]],
+                                epoch_seconds[-1],
                             )
 
-                        if val["loss"] < float(state.best_val_loss):
+                        saving = not ((epoch + 1) % cfg.checkpoint_every
+                                      and epoch + 1 < cfg.epochs)
+                        improved = val["loss"] < float(state.best_val_loss)
+                        if streamed:
+                            # no second tree in HBM: the candidates for
+                            # "best" are the epochs whose state is saved
+                            improved = improved and saving
+                            if improved:
+                                state = state.replace(
+                                    best_val_loss=scalarize(
+                                        val["loss"], jnp.float32))
+                                best_step = epoch + 1
+                        elif improved:
                             with phases.stage("rdp.train.best_copy"):
                                 state = state.replace(
                                     best_val_loss=scalarize(
@@ -771,8 +838,22 @@ def train_model(
 
                         state = state.replace(
                             epoch=scalarize(epoch + 1, jnp.int32))
-                        if ((epoch + 1) % cfg.checkpoint_every
-                                and epoch + 1 < cfg.epochs):
+                        if not saving:
+                            continue
+                        if streamed:
+                            # the state comes to the host leaf by leaf
+                            # before the next donated step; a background
+                            # worker writes it while that step runs
+                            with phases.stage("rdp.train.checkpoint.wait"):
+                                ckpt.wait()
+                            with phases.stage("rdp.train.checkpoint.snapshot"):
+                                host = ckpt.save_streamed(
+                                    epoch + 1, {"state": state},
+                                    best=improved)
+                                if improved:
+                                    best_host = (host["state"].params,
+                                                 host["state"].batch_stats)
+                                del host
                             continue
                         # Collective: every process calls save; orbax
                         # coordinates its own cross-host barriers and each host
@@ -823,17 +904,31 @@ def train_model(
                         tracking.log_metric(
                             "best_val_loss", float(state.best_val_loss))
 
-                if register and best_params is not None:
+                if register and (best_params is not None
+                                 or best_step is not None):
                     with phases.stage("rdp.train.register"):
-                        # collective all-gather of any TP-sharded leaves, then
-                        # host fetch on every process; only process 0 writes the
-                        # registry
-                        host_params = _fetch_to_host(best_params)
-                        host_stats = _fetch_to_host(best_stats)
+                        if best_step is not None:
+                            # the parameters this call fetched for its best
+                            # save, or those of the checkpoint that holds
+                            # the best of an earlier call
+                            if best_host is None:
+                                ckpt.wait()
+                                best = ckpt.restore_streamed(
+                                    {"state": abstract_state.replace(
+                                        opt_state=None, epoch=None,
+                                        best_val_loss=None)},
+                                    step=best_step)["state"]
+                                best_host = (best.params, best.batch_stats)
+                            host_params, host_stats = best_host
+                        else:
+                            # collective all-gather of any TP-sharded leaves,
+                            # then host fetch on every process; only process
+                            # 0 writes the registry
+                            host_params = _fetch_to_host(best_params)
+                            host_stats = _fetch_to_host(best_stats)
                         if is_main:
-                            variables = {"params": host_params}
-                            if host_stats:
-                                variables["batch_stats"] = host_stats
+                            variables = task.variables(
+                                host_params, host_stats)
                             registry_version = tracking.log_model(
                                 variables, model_cfg,
                                 registered_model_name=cfg.registered_model_name,

@@ -63,6 +63,49 @@ class ModelConfig:
 
 
 @dataclass(frozen=True)
+class BlockDiffLMConfig:
+    """A sparse-expert decoder trained by block diffusion, as one chip's
+    share of an expert-parallel job (models/blockdiff_lm.py). Widths are a
+    published model's; ``num_layers``, ``experts_held`` and ``vocab_size``
+    are what this chip holds of it. The defaults are the unit tests' size.
+    """
+
+    vocab_size: int = 64          # rows of the embedding and head held here
+    hidden_size: int = 64
+    num_layers: int = 2
+    num_heads: int = 4
+    num_kv_heads: int = 2         # each shared by num_heads // num_kv_heads
+    head_dim: int = 16
+    num_experts: int = 8          # the router's outputs, all chips' experts
+    experts_per_token: int = 2
+    experts_held: int = 2         # ids first_expert .. first_expert + held - 1
+    first_expert: int = 0
+    expert_width: int = 32
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1e6
+    norm_topk_prob: bool = True
+    seq_len: int = 32             # L tokens; the model sees 2 L positions
+    block_length: int = 4
+    mask_token_id: int = 63       # inside the slice; no data token takes it
+    # sorted rows an expert layer gathers, multiplies and scatters at a
+    # time; chunks past the last routed row are skipped (a multiple of the
+    # grouped product's row tile; it divides positions x experts_per_token)
+    moe_chunk_rows: int = 64
+    compute_dtype: str = "bfloat16"  # params stay float32
+    # normal(0, init_std) matrices, the embedding at embed_init_std. Seeded
+    # weights that stand in for trained ones set it well above init_std:
+    # attention passes on what a sequence's positions share and averages a
+    # token's own part away, so at equal scales the layers' hidden states
+    # end on one direction and every position picks the same experts
+    init_std: float = 0.02
+    embed_init_std: float = 0.02
+    # "auto": the Pallas kernels (attention under the block-diffusion mask,
+    # the experts' grouped product) on a TPU, dense jax.numpy elsewhere;
+    # "pallas" / "interpret" / "xla" pin one for tests.
+    kernel_impl: str = "auto"
+
+
+@dataclass(frozen=True)
 class TrainConfig:
     """Reference hyperparameters: scripts/train_segmenter.py:45-50,143-145."""
 
