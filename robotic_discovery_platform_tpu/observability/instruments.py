@@ -725,6 +725,16 @@ TRAIN_RUNNERS = REGISTRY.counter(
     "(rdp_jit_traces_total says whether one did all the same).",
     ("family", "result"),
 )
+TRAIN_STATE = REGISTRY.counter(
+    families.TRAIN_STATE,
+    "train_model calls by where the state they train came from, one "
+    "sample a call, by model family (the task's name: unet, blockdiff_lm): "
+    "built = the job built its initial state (a start from nothing; under "
+    "a device mesh also a resumed job, which shards a built state before "
+    "the restore overwrites it); restored = a resumed single-device job, "
+    "which took the state's shapes and built nothing.",
+    ("family", "result"),
+)
 MOE_ROUTED_ROWS = REGISTRY.counter(
     families.MOE_ROUTED_ROWS,
     "Rows the expert layers' held experts took in training steps (one per "

@@ -6,6 +6,10 @@ scripts/train_segmenter.py:148-189; SURVEY.md section 5.4). Here every epoch
 checkpoints the full train state (params, optimizer state, batch stats,
 epoch counter, best-val bookkeeping) through orbax -- which is also
 sharding-aware, so the same path serves the data-parallel trainer.
+The trainer gives ``restore`` shapes, not a state: a tree of
+``jax.ShapeDtypeStruct``s, with the mesh's shardings where there is one
+and none for the host, so a job that resumes builds no state to restore
+into; a checkpoint whose shapes differ raises there.
 
 Two save paths:
 
@@ -250,15 +254,26 @@ class CheckpointManager:
             return steps[-1]
         return orbax_step
 
-    def is_streamed(self, step: int) -> bool:
-        return step in self._streamed_steps()
-
     def restore(self, template: Any, step: int | None = None) -> Any:
+        """The orbax checkpoint of ``step`` (the latest) as ``template``'s
+        tree: arrays or ``ShapeDtypeStruct``s, whose shardings say where a
+        leaf lands (none: the host, as numpy). A leaf saved under another
+        shape raises: orbax holds only sharded leaves to the template's."""
         self.wait()
         step = self._mgr.latest_step() if step is None else step
         if step is None:
             raise FileNotFoundError("no checkpoint to restore")
-        return self._mgr.restore(step, args=ocp.args.StandardRestore(template))
+        restored = self._mgr.restore(
+            step, args=ocp.args.StandardRestore(template))
+
+        def held(path, want, got):
+            if np.shape(got) != tuple(want.shape):
+                raise ValueError(
+                    f"leaf {jax.tree_util.keystr(path)}: saved "
+                    f"{np.shape(got)}, wanted {tuple(want.shape)}")
+
+        jax.tree_util.tree_map_with_path(held, template, restored)
+        return restored
 
     def close(self, raise_errors: bool = True) -> None:
         """Drain any in-flight save and close the orbax manager (which is

@@ -80,6 +80,30 @@ class PairedSegmentationData:
         return xs, ys
 
 
+#: threads of :func:`unit_floats` (the pass is bound by memory and page
+#: faults: beyond a handful they no longer add)
+_UNIT_FLOAT_THREADS = 8
+
+
+def unit_floats(a: np.ndarray) -> np.ndarray:
+    """``np.asarray(a, np.float32) / 255.0`` to the last bit, for integer
+    rows: one pass that casts and divides block by block (not a float32
+    copy and then a quotient, two arrays four times the rows' size) on a
+    few threads, since numpy's loops release the interpreter lock. A
+    resident data set of 1,024 pairs at 256 x 256 took a ``train_model``
+    call 2.4 s the other way, every call (PERF.md, PR 32)."""
+    out = np.empty(a.shape, np.float32)
+    rows = max(1, -(-len(a) // _UNIT_FLOAT_THREADS))
+
+    def scale(start):
+        np.divide(a[start:start + rows], 255.0, out=out[start:start + rows],
+                  dtype=np.float32)
+
+    with ThreadPoolExecutor(_UNIT_FLOAT_THREADS) as pool:
+        list(pool.map(scale, range(0, len(a), rows)))
+    return out
+
+
 def train_val_split(n: int, val_fraction: float, seed: int = 0):
     """Deterministic shuffled split (reference uses torch random_split 80/20,
     train_segmenter.py:134-136)."""

@@ -108,7 +108,7 @@ class UNetTask:
         # jitted train step trip an XLA CPU space_to_batch crash on conv
         # backprop (e.g. synthetic.generate_arrays' raw uint8 output).
         if not np.issubdtype(xs.dtype, np.floating):
-            xs = np.asarray(xs, np.float32) / 255.0
+            xs = data_lib.unit_floats(xs)
         if not np.issubdtype(ys.dtype, np.floating):
             if np.max(ys, initial=0) > 1:
                 # only the file loader's 0/255 coding gets the /255 path;
@@ -121,7 +121,7 @@ class UNetTask:
                         "integer masks must be coded {0,1} or {0,255}; got "
                         f"values {np.unique(ys)[:8].tolist()}"
                     )
-                ys = np.asarray(ys, np.float32) / 255.0
+                ys = data_lib.unit_floats(ys)
             else:
                 ys = np.asarray(ys, np.float32)
         return xs, ys

@@ -239,9 +239,26 @@ _DEVICE_SNAPSHOT_MAX_BYTES = 1024**3
 _runner_memo_lock = threading.Lock()
 
 
+class _Kept(tuple):
+    """An entry of the memo: the ``(train, evaluate)`` pair of runners and,
+    from the first job that asks, the shapes of the state such jobs start
+    from. The key names all that those shapes follow from (task and model
+    configuration; Adam's moments mirror the parameters whatever its
+    rates), and flax traces ``init`` anew on every ``jax.eval_shape``:
+    0.13 s a U-Net on the chip's host (PERF.md, PR 32)."""
+
+    _state_shapes = None
+
+    def state_shapes(self, fresh_state: Callable):
+        """``jax.eval_shape(fresh_state)``, traced once an entry."""
+        if self._state_shapes is None:
+            self._state_shapes = jax.eval_shape(fresh_state)
+        return self._state_shapes
+
+
 @functools.lru_cache(maxsize=RUNNER_MEMO_BOUND)
 def _kept_runners(family, builders, task, model_cfg, cfg, donate,
-                  guard_mode, shapes):
+                  guard_mode, shapes) -> _Kept:
     """The memo behind :func:`memoized_runners`: every argument is part of
     the key, and model, optimiser and loss are built from it here, so a
     kept runner can close over nothing its key does not say. ``cfg`` is the
@@ -252,9 +269,9 @@ def _kept_runners(family, builders, task, model_cfg, cfg, donate,
     loss_fn = task.make_loss(cfg)
     kw = _task_kw(task)
     if family == "epoch":
-        return builders[0](model, tx, loss_fn, donate=donate, **kw)
-    return (builders[0](model, tx, loss_fn, donate=donate, **kw),
-            builders[1](model, loss_fn, **kw))
+        return _Kept(builders[0](model, tx, loss_fn, donate=donate, **kw))
+    return _Kept((builders[0](model, tx, loss_fn, donate=donate, **kw),
+                  builders[1](model, loss_fn, **kw)))
 
 
 def _program_settings(task, cfg: TrainConfig) -> TrainConfig:
@@ -268,11 +285,13 @@ def _program_settings(task, cfg: TrainConfig) -> TrainConfig:
 
 
 def memoized_runners(family: str, cfg: TrainConfig, model_cfg,
-                     shapes: tuple) -> tuple:
+                     shapes: tuple) -> _Kept:
     """``train_model``'s single-device runners, the same ``jax.jit``
     objects for every call whose program-shaping settings and shapes are
     equal, so that JAX's own per-object cache spares a repeated job the
-    trace, the lowering and the load of programs its process already holds.
+    trace, the lowering and the load of programs its process already holds;
+    the pair also keeps the shapes of the state such a job starts from
+    (:class:`_Kept`).
 
     ``family`` is ``"epoch"`` (:func:`make_epoch_runners`) or ``"step"``
     (:func:`make_train_step` with :func:`make_eval_step`); either way a
@@ -293,14 +312,14 @@ def memoized_runners(family: str, cfg: TrainConfig, model_cfg,
     with _runner_memo_lock:     # exact counts, and no pair built twice
         built = _kept_runners.cache_info().misses
         task = tasks_lib.task_for(model_cfg)
-        runners = _kept_runners(
+        kept = _kept_runners(
             family, builders + (core_train_step, core_eval_step), task,
             model_cfg, _program_settings(task, cfg), cfg.donate_state,
             transferguard.resolve_transfer_guard(), shapes)
         built = _kept_runners.cache_info().misses > built
     obs.TRAIN_RUNNERS.labels(
         family=family, result="built" if built else "reused").inc()
-    return runners
+    return kept
 
 
 def prefetch_to_device(batches, put):
@@ -412,11 +431,14 @@ def train_model(
         arrays: optional in-memory ((xs, ys)) dataset overriding
             ``cfg.dataset_dir`` (tests, synthetic smoke runs); for a token
             task ``(tokens [n, L] int32, None)``.
-        resume: restore the latest orbax checkpoint under
-            ``cfg.checkpoint_dir`` and continue from its epoch. In a
-            multi-host job the restore is collective (every process calls
-            it, sharded leaves land on their home devices), so
-            ``checkpoint_dir`` must be shared storage across hosts.
+        resume: restore the latest checkpoint under
+            ``cfg.checkpoint_dir`` and continue from its epoch; a start
+            from nothing where there is none. A single-device job restores
+            into the shapes of its initial state, by either save path, and
+            never builds that state (``rdp_train_state_total`` says which a
+            call did). In a multi-host job the restore is collective (every
+            process calls it, sharded leaves land on their home devices),
+            so ``checkpoint_dir`` must be shared storage across hosts.
         mesh: optional ``jax.sharding.Mesh``; when given, batches are sharded
             over the mesh's "data" axis and gradients allreduce over ICI
             (see parallel/). A mesh of the default device alone trains
@@ -484,31 +506,6 @@ def train_model(
             ckpt = CheckpointManager(cfg.checkpoint_dir, keep=cfg.keep_checkpoints)
             latest = ckpt.latest_step() if resume else None
             resuming = latest is not None
-            # A state too large to hold twice on the device is streamed: no
-            # on-device snapshot or best copy, leaf-by-leaf fetch and
-            # placement, "best" as the saved step that holds it. A job that
-            # resumes from a streamed checkpoint never builds the initial
-            # state it would throw away (its shapes are enough); any other
-            # job builds it, and its size decides.
-            if resuming and ckpt.is_streamed(latest):
-                streamed, state = True, None
-                abstract_state = jax.eval_shape(fresh_state)
-            else:
-                state = fresh_state()
-                streamed = (mesh is None and jax.process_count() == 1
-                            and tree_bytes(state)
-                            > _DEVICE_SNAPSHOT_MAX_BYTES)
-                abstract_state = jax.eval_shape(lambda: state) \
-                    if streamed else None
-
-            # Best-so-far candidate params/stats, held as independent DEVICE buffers
-            # (_copy_tree) so they survive donation of the live state and checkpoint
-            # as sharded global arrays under tensor parallelism. A streamed
-            # state keeps none: ``best_step`` names its checkpoint.
-            best_params = None
-            best_stats = None
-            best_step = ckpt.best_step() if streamed else None
-            best_host = None    # the best step's parameters, if fetched here
 
             # Whole-epoch lax.scan mode: single device with the dataset resident in
             # HBM (in-memory arrays, no mesh). One dispatch + one fetch per epoch
@@ -545,11 +542,23 @@ def train_model(
             # shared filesystem) in a multi-host job, as is standard on TPU pods.
             is_main = jax.process_index() == 0
 
+            # A job that will restore its state takes the state's shapes
+            # and builds nothing: whichever save path wrote the checkpoint,
+            # a resumed single-device job never makes the initial state it
+            # would throw away. One that starts from nothing builds it; so
+            # does any job under a mesh, where parallelize_training shards
+            # a concrete one. The shapes' size picks the save path: a state
+            # too large to hold twice on the device is streamed (no
+            # on-device snapshot or best copy, leaf-by-leaf fetch and
+            # placement, "best" as the saved step that holds it).
+            single = mesh is None and jax.process_count() == 1
+            abstract_state = state = None
             if mesh is not None:
                 from robotic_discovery_platform_tpu import parallel
 
                 train_step, eval_step, state = parallel.parallelize_training(
-                    mesh, model, tx, loss_fn, state, donate=cfg.donate_state,
+                    mesh, model, tx, loss_fn, fresh_state(),
+                    donate=cfg.donate_state,
                     tp_min_channels=cfg.tp_min_channels,
                 )
                 spatial_on = dict(mesh.shape).get("spatial", 1) > 1
@@ -564,12 +573,31 @@ def train_model(
                     (cfg.img_size,) if ds is not None else
                     (xs.shape[1:], str(xs.dtype), ys.shape[1:], str(ys.dtype)))
                 if use_scan:
-                    train_epoch, eval_epoch = memoized_runners(
+                    train_epoch, eval_epoch = kept = memoized_runners(
                         "epoch", cfg, model_cfg,
                         shapes + (len(train_idx), len(val_idx)))
                 else:
-                    train_step, eval_step = memoized_runners(
+                    train_step, eval_step = kept = memoized_runners(
                         "step", cfg, model_cfg, shapes)
+                if single:
+                    abstract_state = kept.state_shapes(fresh_state)
+                if not (single and resuming):
+                    state = fresh_state()
+            streamed = (single and tree_bytes(abstract_state)
+                        > _DEVICE_SNAPSHOT_MAX_BYTES)
+            obs.TRAIN_STATE.labels(
+                family=task.name,
+                result="restored" if state is None else "built").inc()
+
+            # Best-so-far candidate params/stats, held as independent DEVICE buffers
+            # (_copy_tree) so they survive donation of the live state and checkpoint
+            # as sharded global arrays under tensor parallelism. A streamed
+            # state keeps none: ``best_step`` names its checkpoint.
+            best_params = None
+            best_stats = None
+            best_step = ckpt.best_step() if streamed else None
+            best_host = None    # the best step's parameters, if fetched here
+
             if mesh is None:
                 to_device = jnp.asarray
                 def scalarize(v, dtype):
@@ -596,26 +624,22 @@ def train_model(
                 log.info("resumed from checkpoint at epoch %d", int(state.epoch))
         elif resuming:
             with phases.stage("rdp.train.restore"):
-                template = {
-                    "state": state,
-                    "best_params": state.params,
-                    "best_stats": state.batch_stats,
-                }
-                if mesh is not None:
-                    template = jax.tree.map(
-                        lambda a: jax.ShapeDtypeStruct(
-                            a.shape, a.dtype, sharding=a.sharding
-                        ),
-                        template,
-                    )
-                else:
-                    template = jax.device_get(template)
-                restored = ckpt.restore(template)
+                # shapes and dtypes and, under a mesh, where each leaf
+                # lives (the built state's shardings). With no sharding
+                # the leaves land on the host, in half the time orbax takes
+                # to place them one by one (PERF.md, PR 32)
+                like = abstract_state if state is None else state
+                restored = ckpt.restore(jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype,
+                        sharding=None if mesh is None else a.sharding),
+                    {"state": like, "best_params": like.params,
+                     "best_stats": like.batch_stats}))
                 state = restored["state"]
                 if mesh is None:
-                    # host arrays from the host template: staged explicitly,
-                    # because a reused runner is warm from its first step and
-                    # the transfer guard exempts only a cold call's transfers
+                    # host arrays: staged explicitly, because a reused
+                    # runner is warm from its first step and the transfer
+                    # guard exempts only a cold call's transfers
                     state = jax.device_put(state)
                 log.info("resumed from checkpoint at epoch %d", int(state.epoch))
                 if np.isfinite(float(state.best_val_loss)):

@@ -1,13 +1,25 @@
 """Trainer tests: end-to-end train->track->register on synthetic data,
-loss descent, checkpoint resume."""
+loss descent, checkpoint resume, and where a job's state comes from (built
+by a start from nothing, restored into its shapes by a resumed job)."""
 
+import contextlib
+import dataclasses
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
 from robotic_discovery_platform_tpu import tracking
-from robotic_discovery_platform_tpu.training import synthetic, trainer
-from robotic_discovery_platform_tpu.utils.config import ModelConfig, TrainConfig
+from robotic_discovery_platform_tpu.observability import instruments as obs
+from robotic_discovery_platform_tpu.tracking import api as tracking_api
+from robotic_discovery_platform_tpu.training import (
+    data as data_lib, synthetic, tasks as tasks_lib, trainer)
+from robotic_discovery_platform_tpu.training.checkpoint import (
+    CheckpointManager)
+from robotic_discovery_platform_tpu.utils.config import (
+    BlockDiffLMConfig, ModelConfig, TrainConfig)
 
 
 TINY_MODEL = ModelConfig(base_features=8, compute_dtype="float32")
@@ -71,6 +83,25 @@ def test_integer_masks_0_255_normalized_other_codings_rejected(tmp_path):
         )
 
 
+@pytest.mark.parametrize("dtype", [np.uint8, np.uint16, np.int32, np.int64])
+def test_integer_rows_are_scaled_in_one_pass_to_the_last_bit(dtype):
+    """``data.unit_floats`` (what ``prepare`` hands integer images and
+    0/255 masks to) is ``float32(rows) / 255`` exactly, whatever the block
+    edges: fewer rows than threads, none, and a count no thread divides."""
+    rng = np.random.default_rng(0)
+    for n in (0, 1, 5, 19):
+        rows = rng.integers(0, 256, (n, 6, 6, 3)).astype(dtype)
+        got = data_lib.unit_floats(rows)
+        want = np.asarray(rows, np.float32) / 255.0
+        assert got.dtype == np.float32 and got.shape == want.shape
+        np.testing.assert_array_equal(got, want)
+    imgs, masks = synthetic.generate_arrays(5, 32, 32, seed=3)
+    xs, ys = tasks_lib.UNET.prepare((imgs.astype(dtype), masks.astype(dtype)),
+                                    TrainConfig())
+    np.testing.assert_array_equal(xs, imgs.astype(np.float32) / 255.0)
+    np.testing.assert_array_equal(ys, masks.astype(np.float32) / 255.0)
+
+
 def test_loss_decreases(tmp_path, arrays):
     cfg = tiny_cfg(tmp_path, epochs=5)
     res = trainer.train_model(cfg, TINY_MODEL, arrays=arrays, register=False)
@@ -87,6 +118,201 @@ def test_resume_from_checkpoint(tmp_path, arrays):
         cfg2, TINY_MODEL, arrays=arrays, resume=True, register=False
     )
     assert res.epochs_run == 2  # 3 total - 1 already done
+
+
+# -- where a job's state comes from -------------------------------------------
+
+#: the children of rdp.train.job that a resumed call with a registry write
+#: runs once each (tests/test_train_phases.py pins their order and nesting)
+JOB_PHASES = ("rdp.train.init", "rdp.train.restore", "rdp.train.stage_data",
+              "rdp.train.register", "rdp.train.flush")
+TINY_LM = BlockDiffLMConfig(compute_dtype="float32", kernel_impl="xla")
+#: name -> (the configuration trained, one of other leaf shapes under the
+#: same keys): tiny likenesses of the benchmark's three configurations, the
+#: language model's state through the streamed save path
+STATE_JOBS = {
+    "seg": (TINY_MODEL, dataclasses.replace(TINY_MODEL, base_features=16)),
+    "unet-tconv": (
+        dataclasses.replace(TINY_MODEL, bilinear=False),
+        dataclasses.replace(TINY_MODEL, bilinear=False, base_features=16)),
+    "lm-streamed": (TINY_LM, dataclasses.replace(TINY_LM, expert_width=48)),
+}
+
+
+def state_job(name, arrays, tmp):
+    """``(epochs, ...) -> TrainResult``: calls of one job under ``tmp``,
+    each continuing it unless told ``resume=False``."""
+    lm = name == "lm-streamed"
+    if lm:
+        arrays = (np.random.default_rng(5).integers(
+            0, TINY_LM.mask_token_id, (20, TINY_LM.seq_len)), None)
+
+    def call(epochs, model_cfg=STATE_JOBS[name][0], resume=True,
+             register=False):
+        cfg = tiny_cfg(tmp, epochs=epochs, batch_size=2 if lm else 4)
+        return trainer.train_model(cfg, model_cfg, arrays=arrays,
+                                   resume=resume, register=register)
+
+    return call
+
+
+@contextlib.contextmanager
+def watched(name, model_cfg, spy=False):
+    """What a ``train_model`` call does about its state: ``built`` counts
+    the calls of ``task.init_variables`` that made values (under
+    ``jax.eval_shape`` it returns tracers), ``counter`` is what the call
+    added to ``rdp_train_state_total``, and with ``spy`` the whole-epoch
+    runner keeps every state it is handed. The tiny language model's state
+    counts as too large to hold twice, as the real one is."""
+    task = tasks_lib.task_for(model_cfg)
+    sound_init, sound_runners = task.init_variables, trainer.make_epoch_runners
+    seen = {"built": 0, "states": []}
+
+    def init_variables(model, rng, cfg):
+        out = sound_init(model, rng, cfg)
+        seen["built"] += not any(isinstance(leaf, jax.core.Tracer)
+                                 for leaf in jax.tree.leaves(out))
+        return out
+
+    def make_epoch_runners(*args, **kw):
+        train_epoch, eval_epoch = sound_runners(*args, **kw)
+
+        def spying(state, xs, ys, order):
+            seen["states"].append(jax.device_get(state))
+            return train_epoch(state, xs, ys, order)
+
+        return spying, eval_epoch
+
+    def counter():
+        return {result: obs.TRAIN_STATE.labels(
+            family=task.name, result=result).value
+            for result in ("built", "restored")}
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(task, "init_variables", init_variables)
+        if spy:
+            patch.setattr(trainer, "make_epoch_runners", make_epoch_runners)
+        if name == "lm-streamed":
+            patch.setattr(trainer, "_DEVICE_SNAPSHOT_MAX_BYTES", 1000)
+            patch.setattr(tracking_api, "_LEAF_FILES_ABOVE", 1000)
+        before = counter()
+        yield seen
+        seen["counter"] = {k: v - before[k] for k, v in counter().items()}
+
+
+def saved_at(name, cfg, step):
+    """What the job's checkpoint of ``step`` holds, read the way a resumed
+    job read it before it restored into shapes: into a state built for the
+    purpose, on the host. ``(state, best_params, best_stats)``."""
+    model_cfg = STATE_JOBS[name][0]
+    task = tasks_lib.task_for(model_cfg)
+    built = jax.device_get(trainer.task_state(
+        task, task.build(model_cfg), optax.adam(cfg.learning_rate),
+        jax.random.key(cfg.seed), cfg))
+    ckpt = CheckpointManager(cfg.checkpoint_dir, keep=cfg.keep_checkpoints)
+    try:
+        if name == "lm-streamed":
+            state, best = (
+                ckpt.restore_streamed({"state": built}, step=at)["state"]
+                for at in (step, ckpt.best_step()))
+            return state, best.params, best.batch_stats
+        got = ckpt.restore({"state": built, "best_params": built.params,
+                            "best_stats": built.batch_stats}, step=step)
+        return got["state"], got["best_params"], got["best_stats"]
+    finally:
+        ckpt.close()
+
+
+@pytest.fixture(scope="module", params=list(STATE_JOBS))
+def resumed(request, arrays, tmp_path_factory):
+    """One job in three calls, each watched: ``resume=True`` on an empty
+    checkpoint directory (two epochs), a resumed call with nothing left to
+    train that registers, a resumed call that trains a third epoch under
+    the spy; then the first call again as a plain start elsewhere."""
+    name = request.param
+    model_cfg = STATE_JOBS[name][0]
+    tmp = tmp_path_factory.mktemp(name)
+    call = state_job(name, arrays, tmp)
+    out = {"name": name, "call": call}
+    with watched(name, model_cfg) as out["first_seen"]:
+        out["first"] = call(2)
+    phases = {p: obs.TRAIN_PHASE.labels(phase=p).count for p in JOB_PHASES}
+    with watched(name, model_cfg) as out["idle_seen"]:
+        out["idle"] = call(2, register=True)
+        _, out["registered"] = tracking.load_model(
+            f"models:/{TrainConfig().registered_model_name}/latest")
+    out["idle_phases"] = {p: obs.TRAIN_PHASE.labels(phase=p).count - n
+                          for p, n in phases.items()}
+    with watched(name, model_cfg, spy=True) as out["more_seen"]:
+        out["more"] = call(3)
+    out["saved"] = saved_at(name, tiny_cfg(tmp), 2)
+    with watched(name, model_cfg) as out["plain_seen"]:
+        out["plain"] = state_job(name, arrays, tmp / "plain")(
+            2, resume=False)
+    return out
+
+
+def test_a_resumed_job_builds_no_state_and_the_counter_says_so(resumed):
+    """``fresh_state()`` never runs on a resumed single-device job,
+    whichever save path wrote its checkpoint: its shapes are enough."""
+    for which in ("idle", "more"):
+        seen = resumed[f"{which}_seen"]
+        assert seen["built"] == 0, which
+        assert seen["counter"] == {"built": 0, "restored": 1}, which
+    assert (resumed["idle"].epochs_run, resumed["more"].epochs_run) == (0, 1)
+
+
+def test_a_resumed_job_trains_from_the_saved_state_bit_for_bit(resumed):
+    """Params, ``opt_state``, ``batch_stats``, ``epoch`` and
+    ``best_val_loss``, as the resumed call's first epoch is handed them."""
+    handed, = resumed["more_seen"]["states"]
+    saved, _, _ = resumed["saved"]
+    assert int(saved.epoch) == 2 and np.isfinite(float(saved.best_val_loss))
+    assert jax.tree.structure(handed) == jax.tree.structure(saved)
+    for got, want in zip(jax.tree.leaves(handed), jax.tree.leaves(saved)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+
+
+def test_a_resumed_job_registers_the_saved_best_candidate(resumed):
+    """``best_params`` / ``best_stats``: a resumed call with nothing left
+    to train registers what the checkpoint holds as the best so far."""
+    _, best_params, best_stats = resumed["saved"]
+    assert resumed["idle"].registry_version == 1
+    task = tasks_lib.task_for(STATE_JOBS[resumed["name"]][0])
+    want = task.variables(best_params, best_stats)
+    assert jax.tree.structure(resumed["registered"]) \
+        == jax.tree.structure(want)
+    jax.tree.map(np.testing.assert_array_equal, resumed["registered"], want)
+
+
+def test_a_checkpoint_of_other_shapes_raises_before_any_step(resumed):
+    """The restore is held to the configuration's shapes: a resumed call
+    under a configuration of other widths raises there and trains nothing."""
+    other = STATE_JOBS[resumed["name"]][1]
+    with watched(resumed["name"], other, spy=True) as seen:
+        with pytest.raises(ValueError, match="shape|saved"):
+            resumed["call"](4, model_cfg=other)
+    assert seen["states"] == [] and seen["built"] == 0
+
+
+def test_resume_with_no_checkpoint_is_a_start_from_nothing(resumed):
+    """``resume=True`` on an empty checkpoint directory (the supervisor's
+    first attempt) builds its state and trains as a plain first call."""
+    for which in ("first", "plain"):
+        seen = resumed[f"{which}_seen"]
+        assert seen["built"] == 1, which
+        assert seen["counter"] == {"built": 1, "restored": 0}, which
+    first, plain = resumed["first"], resumed["plain"]
+    assert first.epochs_run == plain.epochs_run == 2
+    assert first.final_metrics == plain.final_metrics
+    assert first.best_val_loss == plain.best_val_loss
+
+
+def test_a_resumed_job_runs_the_pinned_phases_once_each(resumed):
+    """The ``rdp.train.*`` children of a resumed call's job, by the
+    histogram the spans feed: init, restore, stage_data, register, flush."""
+    assert resumed["idle_phases"] == dict.fromkeys(JOB_PHASES, 1)
 
 
 def test_checkpoint_every_skips_intermediate_saves(tmp_path, arrays):
