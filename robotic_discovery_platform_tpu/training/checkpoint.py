@@ -17,7 +17,7 @@ Two save paths:
   (orbax coordinates cross-host barriers; every process calls in
   lockstep).
 - ``save_async``: single-process overlap. The caller hands an
-  INDEPENDENT on-device snapshot (the trainer's ``_copy_tree``); a single
+  INDEPENDENT on-device snapshot (``_copy_tree``); a single
   worker thread then pays the host fetch (~350 MB of
   params+optimizer+best-candidate at full width) and
   the disk write while the next epoch's compute runs on the chip. One
@@ -39,6 +39,13 @@ decision of the trainer by the state's size,
   holds a second copy. ``best`` marks the step whose checkpoint holds the
   best candidate; pruning keeps it, and ``restore_streamed(step=best)``
   with a template of the parameters alone reads just those leaves.
+
+Which of them a job takes, and what it keeps as the best candidate, is a
+**save policy**, chosen once a ``train_model`` call: :class:`DeviceSnapshotSaves`
+(orbax, the candidate a second tree on the device) or :class:`StreamedSaves`
+(leaf files, the candidate a saved step). The trainer asks a policy four
+things: ``restore(like)``, ``epoch_done(state, epoch, val_loss, saving)``,
+``best()`` and ``close(raise_errors)``.
 """
 
 from __future__ import annotations
@@ -51,6 +58,7 @@ from pathlib import Path
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import orbax.checkpoint as ocp
 
@@ -292,3 +300,183 @@ class CheckpointManager:
             )
         finally:
             self._mgr.close()
+
+
+# -- the save policies --------------------------------------------------------
+
+#: Independent device buffers for a pytree: safe to hold across later
+#: donated train steps, and checkpointable as (possibly sharded) global
+#: arrays. jit outputs never alias non-donated inputs, so every leaf is a
+#: fresh buffer with its input sharding preserved. Module-level so the
+#: compiled copy program is cached across improving epochs.
+@jax.jit
+def _copy_tree(tree):
+    return jax.tree.map(jnp.copy, tree)
+
+
+def _fetch_to_host(tree):
+    """``device_get`` that first re-replicates any non-fully-replicated
+    leaves (tensor-parallel shards) through ONE collective identity jit.
+    Must be called on EVERY process of a multi-host job (the re-replication
+    is an all-gather)."""
+
+    def sharded(a):
+        return (
+            hasattr(a, "is_fully_replicated") and not a.is_fully_replicated
+        )
+
+    if any(sharded(a) for a in jax.tree.leaves(tree)):
+        from robotic_discovery_platform_tpu.parallel import mesh as mesh_lib
+
+        out_shardings = jax.tree.map(
+            lambda a: mesh_lib.replicated(a.sharding.mesh)
+            if sharded(a) else a.sharding,
+            tree,
+        )
+        tree = jax.jit(lambda t: t, out_shardings=out_shardings)(tree)
+    return jax.device_get(tree)
+
+
+class _Saves:
+    """What the two save policies share: the manager they save through
+    and ``scalarize(value, dtype)``, the trainer's placement of a progress
+    counter (``epoch``, ``best_val_loss``) where the state lives."""
+
+    def __init__(self, ckpt: CheckpointManager, scalarize):
+        self._ckpt, self._scalarize = ckpt, scalarize
+
+    def close(self, raise_errors: bool = True) -> None:
+        self._ckpt.close(raise_errors)
+
+
+class DeviceSnapshotSaves(_Saves):
+    """The policy of a state small enough to hold twice on the device (the
+    U-Nets) and of every job under a mesh or of several processes.
+
+    The best candidate's parameters and statistics are independent DEVICE
+    buffers (:func:`_copy_tree`), so they survive donation of the live
+    state and checkpoint as sharded global arrays under tensor parallelism;
+    every checkpoint carries them beside the live state, so a resumed job
+    registers the parameters that achieved ``best_val_loss``, not whatever
+    the last epoch held. ``sharding_of(leaf)`` says where a restored leaf
+    lands (a mesh's sharding; ``None`` the host, in half the time orbax
+    takes to place leaves one by one: PERF.md, PR 32) and ``land(state)``
+    stages what landed on the host."""
+
+    def __init__(self, ckpt, scalarize, sharding_of, land):
+        super().__init__(ckpt, scalarize)
+        self._sharding_of, self._land = sharding_of, land
+        # multi-host saves are collective: orbax's cross-host barriers must
+        # run in lockstep on every process, on the main thread
+        self._collective = jax.process_count() > 1
+        self._best_params = self._best_stats = None
+
+    def restore(self, like):
+        """The latest checkpoint into the shapes of ``like`` (a state or
+        its ``ShapeDtypeStruct``s); a checkpoint of other shapes raises."""
+        restored = self._ckpt.restore(jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=self._sharding_of(a)),
+            {"state": like, "best_params": like.params,
+             "best_stats": like.batch_stats}))
+        state = self._land(restored["state"])
+        if np.isfinite(float(state.best_val_loss)):
+            self._best_params = restored["best_params"]
+            self._best_stats = restored["best_stats"]
+        return state
+
+    def epoch_done(self, state, epoch: int, val_loss: float, saving: bool):
+        if val_loss < float(state.best_val_loss):
+            with obs.TRAIN_PHASES.stage("rdp.train.best_copy"):
+                state = state.replace(
+                    best_val_loss=self._scalarize(val_loss, jnp.float32))
+                self._best_params, self._best_stats = _copy_tree(
+                    (state.params, state.batch_stats))
+        state = state.replace(epoch=self._scalarize(epoch + 1, jnp.int32))
+        if not saving:
+            return state
+        none_yet = self._best_params is None
+        payload = {
+            "state": state,
+            "best_params": state.params if none_yet else self._best_params,
+            "best_stats": state.batch_stats if none_yet else self._best_stats,
+        }
+        if self._collective:
+            with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.snapshot"):
+                self._ckpt.save(epoch + 1, payload)
+            return state
+        # One process: snapshot to independent device buffers (a cheap HBM
+        # copy, and required: the live state is donated into the next
+        # epoch's step), then a background worker pays the ONE bulk host
+        # fetch and the disk write while the next epoch's compute runs.
+        # Orbax pulling device arrays leaf by leaf would cost a round trip
+        # a leaf (~270), a synchronous fetch ~350 MB of D2H every epoch.
+        # The PREVIOUS save is waited for before the new snapshot is made:
+        # otherwise three copies of the state (live, old snapshot, new
+        # snapshot) coexist in HBM whenever saves outlast epochs.
+        with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.wait"):
+            self._ckpt.wait()
+        with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.snapshot"):
+            self._ckpt.save_async(epoch + 1, _copy_tree(payload))
+        return state
+
+    def best(self):
+        """``(params, statistics)`` of the best candidate on the host, or
+        ``None``. Collective: any tensor-parallel leaves are all-gathered,
+        so every process of a multi-host job calls it."""
+        if self._best_params is None:
+            return None
+        return (_fetch_to_host(self._best_params),
+                _fetch_to_host(self._best_stats))
+
+
+class StreamedSaves(_Saves):
+    """The policy of a state too large to hold twice on the device
+    (``sdar``): no on-device snapshot or best copy. The state comes to the
+    host leaf by leaf before the next donated step and a background worker
+    writes it while that step runs; a restore places leaf after leaf, so
+    neither side ever holds a second copy; and only a saved epoch can be
+    best: ``best_step`` names the checkpoint that holds the candidate.
+    ``shapes`` are the state's ``ShapeDtypeStruct``s. One process only."""
+
+    def __init__(self, ckpt, scalarize, shapes):
+        super().__init__(ckpt, scalarize)
+        self._shapes = shapes
+        self._best_step = ckpt.best_step()
+        self._best_host = None  # the best step's parameters, if fetched here
+
+    def restore(self, like):
+        return self._ckpt.restore_streamed(
+            {"state": like}, place=jax.device_put)["state"]
+
+    def epoch_done(self, state, epoch: int, val_loss: float, saving: bool):
+        improved = val_loss < float(state.best_val_loss) and saving
+        if improved:
+            state = state.replace(
+                best_val_loss=self._scalarize(val_loss, jnp.float32))
+            self._best_step = epoch + 1
+        state = state.replace(epoch=self._scalarize(epoch + 1, jnp.int32))
+        if not saving:
+            return state
+        with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.wait"):
+            self._ckpt.wait()
+        with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.snapshot"):
+            host = self._ckpt.save_streamed(
+                epoch + 1, {"state": state}, best=improved)["state"]
+            if improved:
+                self._best_host = (host.params, host.batch_stats)
+        return state
+
+    def best(self):
+        """The parameters this call fetched for its best save, or those of
+        the checkpoint that holds the best of an earlier call."""
+        if self._best_step is None:
+            return None
+        if self._best_host is None:
+            self._ckpt.wait()
+            best = self._ckpt.restore_streamed(
+                {"state": self._shapes.replace(
+                    opt_state=None, epoch=None, best_val_loss=None)},
+                step=self._best_step)["state"]
+            self._best_host = (best.params, best.batch_stats)
+        return self._best_host

@@ -19,8 +19,7 @@ INTER_NEAREST for masks to ``img_size``, /255 normalization, deterministic
   -- a handful of samples are seen twice per epoch. The reference instead
   yields a short ragged batch (torch DataLoader default); at the reference
   config (51 train images, batch 4) the difference is one duplicated
-  sample per epoch, and measured convergence parity is unaffected
-  (TRAINBENCH*.json).
+  sample per epoch.
 """
 
 from __future__ import annotations

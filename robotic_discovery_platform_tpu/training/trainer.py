@@ -19,11 +19,12 @@ plus the things the reference lacks (SURVEY.md sections 2.3, 5.3-5.4):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -34,10 +35,10 @@ from flax import struct
 from robotic_discovery_platform_tpu import tracking
 from robotic_discovery_platform_tpu.analysis import recompile
 from robotic_discovery_platform_tpu.observability import instruments as obs
+from robotic_discovery_platform_tpu.training import checkpoint as checkpoint_lib
 from robotic_discovery_platform_tpu.training import data as data_lib
 from robotic_discovery_platform_tpu.training import tasks as tasks_lib
-from robotic_discovery_platform_tpu.training.checkpoint import (
-    CheckpointManager, tree_bytes)
+from robotic_discovery_platform_tpu.training.checkpoint import CheckpointManager
 from robotic_discovery_platform_tpu.utils import transferguard
 from robotic_discovery_platform_tpu.utils.config import ModelConfig, TrainConfig
 from robotic_discovery_platform_tpu.utils.logging import get_logger
@@ -231,10 +232,18 @@ RUNNER_MEMO_BOUND = 4
 #: bytes is never duplicated on the device: its checkpoints are fetched to
 #: the host leaf by leaf before the next donated step, a restore places leaf
 #: by leaf, and "best" is the saved step that holds it instead of a second
-#: tree in HBM. A smaller state keeps its on-device snapshot and best copy:
+#: tree in HBM (``checkpoint.StreamedSaves``). A smaller state keeps its
+#: on-device snapshot and best copy (``checkpoint.DeviceSnapshotSaves``):
 #: the copy is a device-side pass, the fetch would be synchronous every
-#: epoch at the 3.8 GB/s a v5e's host takes whole leaves.
+#: epoch at the 3.8 GB/s a v5e's host takes whole leaves. ``train_model``
+#: reads it through this module at call time.
 _DEVICE_SNAPSHOT_MAX_BYTES = 1024**3
+
+#: An in-memory data set above this many bytes is not kept resident on the
+#: device: under ``epoch_mode="auto"`` its epochs run step by step from the
+#: host (a v5e has 16 GiB of HBM; this leaves room for parameters,
+#: activations and the donated state's copy).
+_SCAN_MAX_BYTES = 4 * 1024**3
 
 _runner_memo_lock = threading.Lock()
 
@@ -352,37 +361,265 @@ def prefetch_to_device(batches, put):
         yield staged
 
 
-#: Independent device buffers for a pytree: safe to hold across later
-#: donated train steps, and checkpointable as (possibly sharded) global
-#: arrays. jit outputs never alias non-donated inputs, so every leaf is a
-#: fresh buffer with its input sharding preserved. Module-level so the
-#: compiled copy program is cached across improving epochs.
-@jax.jit
-def _copy_tree(tree):
-    return jax.tree.map(jnp.copy, tree)
+class _Placement(NamedTuple):
+    """Where a job's arrays live, decided once a call from ``mesh`` and the
+    process's place in the job (:func:`_placement`)."""
+
+    mesh: Any               # None: the default device alone
+    single: bool            # no mesh, one process
+    track: Any              # ``tracking`` on process 0, else :class:`_Untracked`
+    divisor: int            # data-parallel world size a global batch divides by
+    to_device: Callable     # a host batch onto the device(s)
+    scalarize: Callable     # (value, dtype) -> a progress counter of the state
+    sharding_of: Callable   # where a restored leaf lands; None = the host
+    land: Callable          # a restored state, staged where it trains
 
 
-def _fetch_to_host(tree):
-    """``device_get`` that first re-replicates any non-fully-replicated
-    leaves (tensor-parallel shards) through ONE collective identity jit.
-    Must be called on EVERY process of a multi-host job (the re-replication
-    is an all-gather)."""
+class _Untracked:
+    """What a process other than 0 of a multi-host job calls in
+    ``tracking``'s place: every process runs the identical program, process
+    0 alone writes tracking and the registry."""
 
-    def sharded(a):
-        return (
-            hasattr(a, "is_fully_replicated") and not a.is_fully_replicated
+    @staticmethod
+    def start_run():
+        return contextlib.nullcontext(
+            tracking.ActiveRun(f"process-{jax.process_index()}"))
+
+    @staticmethod
+    def _nothing(*args, **kwargs):
+        return None
+
+    set_tracking_uri = set_experiment = log_params = log_metric = _nothing
+    log_model = _nothing
+
+
+def _placement(mesh, task, model_cfg):
+    """``(placement, the model configuration the job trains)``. A mesh of
+    the default device alone shards nothing (what ``serving/rollout.py``'s
+    ``training_mesh()`` hands a one-chip replica's cycle): the same model
+    as under any mesh, run by the single-device runners, which the process
+    keeps.
+
+    Multi-host: checkpoint save/restore are COLLECTIVE -- every process
+    calls them and orbax coordinates its own cross-host barriers,
+    writing/reading per-host shards (tensor-parallel state included).
+    ``checkpoint_dir`` must be shared storage (GCS or a shared filesystem)
+    in a multi-host job, as is standard on TPU pods."""
+    track = tracking if jax.process_index() == 0 else _Untracked
+    if mesh is not None:
+        model_cfg = task.for_mesh(model_cfg)
+        if mesh.size == 1 and mesh.devices.flat[0] == jax.devices()[0]:
+            mesh = None
+    if mesh is None:
+        # restored leaves land on the host and are staged explicitly,
+        # because a reused runner is warm from its first step and the
+        # transfer guard exempts only a cold call's transfers
+        return _Placement(
+            mesh=None, single=jax.process_count() == 1, track=track,
+            divisor=1, to_device=jnp.asarray, scalarize=jnp.asarray,
+            sharding_of=lambda leaf: None, land=jax.device_put), model_cfg
+    from robotic_discovery_platform_tpu import parallel
+    from robotic_discovery_platform_tpu.parallel import mesh as mesh_lib
+
+    rep = mesh_lib.replicated(mesh)
+    return _Placement(
+        mesh=mesh, single=False, track=track,
+        divisor=mesh.shape.get("data", 1),
+        to_device=functools.partial(
+            parallel.put_global_batch, mesh,
+            spatial=dict(mesh.shape).get("spatial", 1) > 1),
+        # progress counters live replicated on the mesh so the saved state
+        # is a consistent global array on every host
+        scalarize=lambda v, dtype: jax.device_put(jnp.asarray(v, dtype), rep),
+        # orbax lands each host's shards directly on its devices, under
+        # the built state's (possibly tensor-parallel) shardings
+        sharding_of=lambda leaf: leaf.sharding,
+        land=lambda state: state), model_cfg
+
+
+class _Data(NamedTuple):
+    """A job's data set, split: in-memory arrays (``xs``, ``ys``) or a file
+    data set (``ds``), one of the two ``None``."""
+
+    xs: Any
+    ys: Any
+    ds: Any
+    train_idx: np.ndarray
+    val_idx: np.ndarray
+
+    def shapes(self, cfg: TrainConfig) -> tuple:
+        """What decides every shape a single-device runner will be fed:
+        full batches (``data.epoch_order``) of these samples."""
+        return (max(cfg.batch_size, 1),) + (
+            (cfg.img_size,) if self.ds is not None else
+            (self.xs.shape[1:], str(self.xs.dtype),
+             self.ys.shape[1:], str(self.ys.dtype)))
+
+    def resident_bytes(self) -> int:
+        """Bytes of the in-memory arrays (0 for a file data set). No
+        ``np.asarray``: that would copy (or device-fetch) the whole data
+        set just to read a byte count."""
+        return sum(
+            int(a.nbytes) if hasattr(a, "nbytes") else
+            int(np.prod(np.shape(a)) * np.dtype(np.float32).itemsize)
+            for a in (self.xs, self.ys) if self.ds is None)
+
+
+class _ResidentScan:
+    """The epoch runner of a single device with the data set resident in
+    HBM: a whole epoch is one ``lax.scan`` dispatch and one host fetch
+    (:func:`make_epoch_runners`), the same ``jax.jit`` pair for every call
+    of equal settings and split sizes (:func:`memoized_runners`)."""
+
+    def __init__(self, cfg, model_cfg, data: _Data, batch_size: int):
+        self._cfg, self._data, self._batch_size = cfg, data, batch_size
+        self._train_epoch, self._eval_epoch = self._kept = memoized_runners(
+            "epoch", cfg, model_cfg,
+            data.shapes(cfg) + (len(data.train_idx), len(data.val_idx)))
+
+    def state_shapes(self, fresh_state: Callable):
+        return self._kept.state_shapes(fresh_state)
+
+    def initial_state(self, fresh_state: Callable):
+        return fresh_state()
+
+    def stage(self) -> None:
+        xs, ys, _, train_idx, val_idx = self._data
+        self._train = jnp.asarray(xs[train_idx]), jnp.asarray(ys[train_idx])
+        self._val = jnp.asarray(xs[val_idx]), jnp.asarray(ys[val_idx])
+        self._order_rng = np.random.default_rng(self._cfg.seed)
+        self._val_order = jnp.asarray(data_lib.epoch_order(
+            len(val_idx), self._batch_size, False, self._order_rng))
+
+    def train(self, state, epoch: int):
+        del epoch   # the one generator draws every epoch's order in turn
+        order = jnp.asarray(data_lib.epoch_order(
+            len(self._data.train_idx), self._batch_size, True,
+            self._order_rng))
+        state, out = self._train_epoch(state, *self._train, order)
+        step_losses = None
+        if isinstance(out, dict):
+            out = jax.device_get(out)
+            train_loss = float(out["loss"])
+            step_losses = out.pop("step_loss")
+        else:
+            train_loss, out = float(out), None
+        return state, train_loss, out, step_losses, int(order.shape[0])
+
+    def validate(self, state) -> dict:
+        metrics = self._eval_epoch(state, *self._val, self._val_order)
+        return {k: float(v) for k, v in metrics.items()}
+
+
+class _Stepped:
+    """The epoch runner of every other job (files, a mesh, ``epoch_mode``
+    "stream", a data set over :data:`_SCAN_MAX_BYTES`): a dispatch a step,
+    batch k+1 decoded and staged while the donated step of batch k runs
+    (:func:`prefetch_to_device`), the losses fetched at the epoch's end so
+    that nothing blocks per step. On one device the kept jitted pair of
+    :func:`memoized_runners`; under a mesh
+    ``parallel.parallelize_training``'s, which also shards the initial
+    state it is handed."""
+
+    def __init__(self, cfg, model_cfg, data: _Data, batch_size: int,
+                 place: _Placement, model, tx, loss_fn,
+                 fresh_state: Callable):
+        self._cfg, self._data, self._batch_size = cfg, data, batch_size
+        self._to_device, self._divisor = place.to_device, place.divisor
+        self._kept = self._sharded_state = None
+        if place.mesh is None:
+            self._train_step, self._eval_step = self._kept = (
+                memoized_runners("step", cfg, model_cfg, data.shapes(cfg)))
+        else:
+            from robotic_discovery_platform_tpu import parallel
+
+            self._train_step, self._eval_step, self._sharded_state = (
+                parallel.parallelize_training(
+                    place.mesh, model, tx, loss_fn, fresh_state(),
+                    donate=cfg.donate_state,
+                    tp_min_channels=cfg.tp_min_channels))
+
+    def state_shapes(self, fresh_state: Callable):
+        """Of a single device's job: under a mesh the state has its
+        shardings only once ``parallelize_training`` has built it."""
+        return self._kept.state_shapes(fresh_state)
+
+    def initial_state(self, fresh_state: Callable):
+        return (fresh_state() if self._sharded_state is None
+                else self._sharded_state)
+
+    def stage(self) -> None:
+        cfg, (xs, ys, ds, train_idx, val_idx) = self._cfg, self._data
+        if ds is not None:
+            self._train, self._val = (
+                data_lib.StreamingBatches(
+                    ds, idx, self._batch_size, shuffle=shuffle, seed=cfg.seed,
+                    divisor=self._divisor, workers=cfg.loader_workers)
+                for idx, shuffle in ((train_idx, True), (val_idx, False)))
+        else:
+            self._train, self._val = (
+                data_lib.Batches(
+                    xs[idx], ys[idx], self._batch_size, shuffle=shuffle,
+                    seed=cfg.seed, divisor=self._divisor)
+                for idx, shuffle in ((train_idx, True), (val_idx, False)))
+
+    def train(self, state, epoch: int):
+        losses = []
+        step_num = epoch * len(self._train)
+        for dx, dy in prefetch_to_device(self._train, self._to_device):
+            with jax.profiler.StepTraceAnnotation(
+                    "rdp.train.step", step_num=step_num):
+                state, loss = self._train_step(state, dx, dy)
+            losses.append(loss)
+            step_num += 1
+        out = step_losses = None
+        if losses and isinstance(losses[0], dict):
+            losses = jax.device_get(losses)
+            out = jax.tree.map(lambda *v: np.mean(v, axis=0), *losses)
+            train_loss = float(out["loss"])
+            step_losses = [l["loss"] for l in losses]
+        else:
+            train_loss = float(np.mean([float(l) for l in losses]))
+        return state, train_loss, out, step_losses, len(losses)
+
+    def validate(self, state) -> dict:
+        agg: dict[str, list] = {}
+        for bx, by in self._val:
+            m = self._eval_step(
+                state, self._to_device(bx), self._to_device(by))
+            for k, v in m.items():
+                agg.setdefault(k, []).append(float(v))
+        return {k: float(np.mean(v)) for k, v in agg.items()}
+
+
+def _epoch_runner(cfg, model_cfg, data: _Data, batch_size: int,
+                  place: _Placement, model, tx, loss_fn,
+                  fresh_state: Callable):
+    """How a job's epochs run, decided once: a whole-epoch ``lax.scan``
+    needs one device and the data set resident in HBM (in-memory arrays, no
+    mesh); ``cfg.epoch_mode`` demands it ("scan"), takes it where the data
+    set fits :data:`_SCAN_MAX_BYTES` ("auto"), or forgoes it ("stream")."""
+    if cfg.epoch_mode not in ("auto", "scan", "stream"):
+        raise ValueError(
+            f"epoch_mode must be auto|scan|stream, got {cfg.epoch_mode!r}"
         )
-
-    if any(sharded(a) for a in jax.tree.leaves(tree)):
-        from robotic_discovery_platform_tpu.parallel import mesh as mesh_lib
-
-        out_shardings = jax.tree.map(
-            lambda a: mesh_lib.replicated(a.sharding.mesh)
-            if sharded(a) else a.sharding,
-            tree,
+    resident = data.ds is None and place.mesh is None
+    nbytes = data.resident_bytes()
+    fits = nbytes <= _SCAN_MAX_BYTES
+    if cfg.epoch_mode == "scan" and not resident:
+        raise ValueError(
+            "epoch_mode='scan' needs an in-memory dataset and no mesh"
         )
-        tree = jax.jit(lambda t: t, out_shardings=out_shardings)(tree)
-    return jax.device_get(tree)
+    if cfg.epoch_mode == "auto" and resident and not fits:
+        log.info(
+            "dataset is %.1f GiB, over the %.0f GiB kept resident; using "
+            "the streamed per-batch path",
+            nbytes / 2**30, _SCAN_MAX_BYTES / 2**30)
+    if resident and (cfg.epoch_mode == "scan"
+                     or (cfg.epoch_mode == "auto" and fits)):
+        return _ResidentScan(cfg, model_cfg, data, batch_size)
+    return _Stepped(cfg, model_cfg, data, batch_size, place, model, tx,
+                    loss_fn, fresh_state)
 
 
 @dataclass
@@ -449,49 +686,39 @@ def train_model(
     The call is one ``rdp.train.job`` phase whose children tile it
     (:data:`phases`): init, restore, stage_data, then per epoch steps,
     validation, log, best_copy and the checkpoint hand-over, then register
-    and flush.
+    and flush. Its three decisions are taken once, in init, and are objects
+    from there on: where arrays live (:class:`_Placement`), how an epoch
+    runs (:class:`_ResidentScan` or :class:`_Stepped`), and how the state is
+    saved and the best candidate kept (``checkpoint.DeviceSnapshotSaves`` or
+    ``checkpoint.StreamedSaves``).
     """
-    # one function on purpose: with the body in a helper of its own
-    # (train_model -> _train_job) a call that traced its runners took 2.2 s
-    # longer on the chip (PERF.md, PR 25). Since PR 26 only the first call
-    # with a shape traces, and the split costs a later call nothing
-    # (PERF.md, PR 26); the first calls would still pay it
     with phases.stage("rdp.train.job"):
         t_start = time.perf_counter()
         with phases.stage("rdp.train.init"):
             task = tasks_lib.task_for(model_cfg)
             if arrays is not None:
                 xs, ys = task.prepare(arrays, cfg)
-                n_samples = len(xs)
-                ds = None
+                n_samples, ds = len(xs), None
             else:
+                xs = ys = None
                 ds = task.file_data(cfg)
                 n_samples = len(ds)
-            train_idx, val_idx = data_lib.train_val_split(
-                n_samples, cfg.validation_split, cfg.seed
-            )
-            if len(val_idx) == 0:
+            data = _Data(xs, ys, ds, *data_lib.train_val_split(
+                n_samples, cfg.validation_split, cfg.seed))
+            if len(data.val_idx) == 0:
                 raise ValueError("dataset too small for a validation split")
-
-            if mesh is not None:
-                model_cfg = task.for_mesh(model_cfg)
-            if (mesh is not None and mesh.size == 1
-                    and mesh.devices.flat[0] == jax.devices()[0]):
-                # a mesh of the default device alone shards nothing (what
-                # serving/rollout.py's training_mesh() hands a one-chip
-                # replica's cycle): the same model as under any mesh, run by
-                # the single-device runners, which the process keeps
-                mesh = None
-            if cfg.epoch_mode not in ("auto", "scan", "stream"):
-                raise ValueError(
-                    f"epoch_mode must be auto|scan|stream, got {cfg.epoch_mode!r}"
-                )
             if cfg.checkpoint_every < 1:
                 # 0 would be a ZeroDivisionError deep in the epoch loop; negatives
                 # would silently save every epoch
                 raise ValueError(
                     f"checkpoint_every must be >= 1, got {cfg.checkpoint_every}"
                 )
+
+            place, model_cfg = _placement(mesh, task, model_cfg)
+            # the global batch rounded up to a multiple of the data-parallel
+            # world size, so every jit-sharded batch divides over the mesh
+            batch_size = -(-max(cfg.batch_size, place.divisor)
+                           // place.divisor) * place.divisor
             model = task.build(model_cfg)
             tx = optax.adam(cfg.learning_rate)
             loss_fn = task.make_loss(cfg)
@@ -500,211 +727,49 @@ def train_model(
                 return task_state(
                     task, model, tx, jax.random.key(cfg.seed), cfg)
 
-            # Checkpoints carry the best-so-far candidate alongside the live state so
-            # a resumed run registers the params that actually achieved
-            # ``best_val_loss``, not whatever the last epoch happened to hold.
-            ckpt = CheckpointManager(cfg.checkpoint_dir, keep=cfg.keep_checkpoints)
-            latest = ckpt.latest_step() if resume else None
-            resuming = latest is not None
-
-            # Whole-epoch lax.scan mode: single device with the dataset resident in
-            # HBM (in-memory arrays, no mesh). One dispatch + one fetch per epoch
-            # instead of per step -- see make_epoch_runners.
-            def _nbytes(a) -> int:
-                # no np.asarray here: that would copy (or device-fetch) the whole
-                # dataset just to read a byte count
-                if hasattr(a, "nbytes"):
-                    return int(a.nbytes)
-                return int(np.prod(np.shape(a)) * np.dtype(np.float32).itemsize)
-
-            data_bytes = 0 if arrays is None else _nbytes(xs) + _nbytes(ys)
-            fits = data_bytes <= cfg.scan_max_bytes
-            use_scan = (
-                ds is None and mesh is None
-                and (cfg.epoch_mode == "scan"
-                     or (cfg.epoch_mode == "auto" and fits))
-            )
-            if cfg.epoch_mode == "scan" and (ds is not None or mesh is not None):
-                raise ValueError(
-                    "epoch_mode='scan' needs an in-memory dataset and no mesh"
-                )
-            if cfg.epoch_mode == "auto" and ds is None and mesh is None and not fits:
-                log.info(
-                    "dataset is %.1f GiB > scan_max_bytes; using the streamed "
-                    "per-batch path", data_bytes / 2**30,
-                )
-
-            # Multi-host: every process runs the identical program; process 0 alone
-            # writes tracking and the registry. Checkpoint save/restore are
-            # COLLECTIVE -- every process calls them and orbax coordinates its own
-            # cross-host barriers, writing/reading per-host shards (tensor-parallel
-            # state included). ``checkpoint_dir`` must be shared storage (GCS or a
-            # shared filesystem) in a multi-host job, as is standard on TPU pods.
-            is_main = jax.process_index() == 0
+            runner = _epoch_runner(cfg, model_cfg, data, batch_size, place,
+                                   model, tx, loss_fn, fresh_state)
 
             # A job that will restore its state takes the state's shapes
             # and builds nothing: whichever save path wrote the checkpoint,
             # a resumed single-device job never makes the initial state it
             # would throw away. One that starts from nothing builds it; so
             # does any job under a mesh, where parallelize_training shards
-            # a concrete one. The shapes' size picks the save path: a state
-            # too large to hold twice on the device is streamed (no
-            # on-device snapshot or best copy, leaf-by-leaf fetch and
-            # placement, "best" as the saved step that holds it).
-            single = mesh is None and jax.process_count() == 1
-            abstract_state = state = None
-            if mesh is not None:
-                from robotic_discovery_platform_tpu import parallel
-
-                train_step, eval_step, state = parallel.parallelize_training(
-                    mesh, model, tx, loss_fn, fresh_state(),
-                    donate=cfg.donate_state,
-                    tp_min_channels=cfg.tp_min_channels,
-                )
-                spatial_on = dict(mesh.shape).get("spatial", 1) > 1
-
-                def to_device(b):
-                    return parallel.put_global_batch(mesh, b, spatial=spatial_on)
-            else:
-                # what decides every shape the runners will be fed: full
-                # batches (data.epoch_order) of these samples and, for a
-                # whole-epoch scan, the two splits' sizes
-                shapes = (max(cfg.batch_size, 1),) + (
-                    (cfg.img_size,) if ds is not None else
-                    (xs.shape[1:], str(xs.dtype), ys.shape[1:], str(ys.dtype)))
-                if use_scan:
-                    train_epoch, eval_epoch = kept = memoized_runners(
-                        "epoch", cfg, model_cfg,
-                        shapes + (len(train_idx), len(val_idx)))
-                else:
-                    train_step, eval_step = kept = memoized_runners(
-                        "step", cfg, model_cfg, shapes)
-                if single:
-                    abstract_state = kept.state_shapes(fresh_state)
-                if not (single and resuming):
-                    state = fresh_state()
-            streamed = (single and tree_bytes(abstract_state)
-                        > _DEVICE_SNAPSHOT_MAX_BYTES)
+            # a concrete one.
+            ckpt = CheckpointManager(cfg.checkpoint_dir, keep=cfg.keep_checkpoints)
+            resuming = resume and ckpt.latest_step() is not None
+            abstract_state = (runner.state_shapes(fresh_state)
+                              if place.single else None)
+            state = (None if resuming and place.single
+                     else runner.initial_state(fresh_state))
             obs.TRAIN_STATE.labels(
                 family=task.name,
                 result="restored" if state is None else "built").inc()
+            # How the state is saved, by its size (_DEVICE_SNAPSHOT_MAX_BYTES)
+            saves = (
+                checkpoint_lib.StreamedSaves(
+                    ckpt, place.scalarize, abstract_state)
+                if place.single and checkpoint_lib.tree_bytes(abstract_state)
+                > _DEVICE_SNAPSHOT_MAX_BYTES
+                else checkpoint_lib.DeviceSnapshotSaves(
+                    ckpt, place.scalarize, place.sharding_of, place.land))
 
-            # Best-so-far candidate params/stats, held as independent DEVICE buffers
-            # (_copy_tree) so they survive donation of the live state and checkpoint
-            # as sharded global arrays under tensor parallelism. A streamed
-            # state keeps none: ``best_step`` names its checkpoint.
-            best_params = None
-            best_stats = None
-            best_step = ckpt.best_step() if streamed else None
-            best_host = None    # the best step's parameters, if fetched here
-
-            if mesh is None:
-                to_device = jnp.asarray
-                def scalarize(v, dtype):
-                    return jnp.asarray(v, dtype)
-            else:
-                from robotic_discovery_platform_tpu.parallel import mesh as mesh_lib
-
-                _rep = mesh_lib.replicated(mesh)
-                def scalarize(v, dtype):
-                    # progress counters live replicated on the mesh so the saved
-                    # state is a consistent global array on every host
-                    return jax.device_put(jnp.asarray(v, dtype), _rep)
-
-            # Restore happens AFTER parallelize_training so the abstract template
-            # carries the final (possibly TP-sharded) shardings and orbax lands each
-            # host's shards directly on its devices.
-
-        if resuming and streamed:
+        if resuming:
+            # after parallelize_training, so that under a mesh the template
+            # carries the final (possibly TP-sharded) shardings
             with phases.stage("rdp.train.restore"):
-                # leaf after leaf from the files onto the device: neither
-                # side ever holds a second copy
-                state = ckpt.restore_streamed(
-                    {"state": abstract_state}, place=jax.device_put)["state"]
+                state = saves.restore(
+                    abstract_state if state is None else state)
                 log.info("resumed from checkpoint at epoch %d", int(state.epoch))
-        elif resuming:
-            with phases.stage("rdp.train.restore"):
-                # shapes and dtypes and, under a mesh, where each leaf
-                # lives (the built state's shardings). With no sharding
-                # the leaves land on the host, in half the time orbax takes
-                # to place them one by one (PERF.md, PR 32)
-                like = abstract_state if state is None else state
-                restored = ckpt.restore(jax.tree.map(
-                    lambda a: jax.ShapeDtypeStruct(
-                        a.shape, a.dtype,
-                        sharding=None if mesh is None else a.sharding),
-                    {"state": like, "best_params": like.params,
-                     "best_stats": like.batch_stats}))
-                state = restored["state"]
-                if mesh is None:
-                    # host arrays: staged explicitly, because a reused
-                    # runner is warm from its first step and the transfer
-                    # guard exempts only a cold call's transfers
-                    state = jax.device_put(state)
-                log.info("resumed from checkpoint at epoch %d", int(state.epoch))
-                if np.isfinite(float(state.best_val_loss)):
-                    best_params = restored["best_params"]
-                    best_stats = restored["best_stats"]
 
         with phases.stage("rdp.train.stage_data"):
-            divisor = mesh.shape.get("data", 1) if mesh is not None else 1
-            # round the global batch up to a multiple of the data-parallel
-            # world size so every jit-sharded batch divides evenly over the mesh
-            batch_size = (
-                (max(cfg.batch_size, divisor) + divisor - 1) // divisor
-            ) * divisor
-            train_batches = val_batches = None
-            if use_scan:
-                xs_tr = jnp.asarray(xs[train_idx])
-                ys_tr = jnp.asarray(ys[train_idx])
-                xs_va = jnp.asarray(xs[val_idx])
-                ys_va = jnp.asarray(ys[val_idx])
-                order_rng = np.random.default_rng(cfg.seed)
-                val_order = jnp.asarray(data_lib.epoch_order(
-                    len(val_idx), batch_size, False, order_rng
-                ))
+            runner.stage()
 
-                def run_val():
-                    metrics = eval_epoch(state, xs_va, ys_va, val_order)
-                    return {k: float(v) for k, v in metrics.items()}
-            elif ds is not None:
-                train_batches = data_lib.StreamingBatches(
-                    ds, train_idx, batch_size, shuffle=True, seed=cfg.seed,
-                    divisor=divisor, workers=cfg.loader_workers,
-                )
-                val_batches = data_lib.StreamingBatches(
-                    ds, val_idx, batch_size, shuffle=False, divisor=divisor,
-                    workers=cfg.loader_workers,
-                )
-            else:
-                train_batches = data_lib.Batches(
-                    xs[train_idx], ys[train_idx], batch_size, shuffle=True,
-                    seed=cfg.seed, divisor=divisor,
-                )
-                val_batches = data_lib.Batches(
-                    xs[val_idx], ys[val_idx], batch_size, shuffle=False,
-                    divisor=divisor,
-                )
-            if not use_scan:
-                def run_val():
-                    agg: dict[str, list] = {}
-                    for bx, by in val_batches:
-                        m = eval_step(state, to_device(bx), to_device(by))
-                        for k, v in m.items():
-                            agg.setdefault(k, []).append(float(v))
-                    return {k: float(np.mean(v)) for k, v in agg.items()}
-
+        track = place.track
         with phases.stage("rdp.train.log"):
-            if is_main:
-                tracking.set_tracking_uri(cfg.tracking_uri)
-                tracking.set_experiment(cfg.experiment_name)
-                run_ctx = tracking.start_run()
-            else:
-                import contextlib
-
-                run_ctx = contextlib.nullcontext(
-                    tracking.ActiveRun(f"process-{jax.process_index()}")
-                )
+            track.set_tracking_uri(cfg.tracking_uri)
+            track.set_experiment(cfg.experiment_name)
+            run_ctx = track.start_run()
 
         registry_version = None
         final_metrics: dict = {}
@@ -715,22 +780,21 @@ def train_model(
         # original error; the clean path surfaces save failures by raising
         try:
             with run_ctx as run:
-                if is_main:
-                    with phases.stage("rdp.train.log"):
-                        tracking.log_params(
-                            {
-                                # exact reference param-name surface
-                                # (train_segmenter.py:119-128)
-                                "learning_rate": cfg.learning_rate,
-                                "batch_size": batch_size,
-                                "epochs": cfg.epochs,
-                                "validation_split": cfg.validation_split,
-                                "optimizer": "adam",
-                                "backend": jax.default_backend(),
-                                "num_devices": divisor,
-                                **task.run_params(cfg, model_cfg),
-                            }
-                        )
+                with phases.stage("rdp.train.log"):
+                    track.log_params(
+                        {
+                            # exact reference param-name surface
+                            # (train_segmenter.py:119-128)
+                            "learning_rate": cfg.learning_rate,
+                            "batch_size": batch_size,
+                            "epochs": cfg.epochs,
+                            "validation_split": cfg.validation_split,
+                            "optimizer": "adam",
+                            "backend": jax.default_backend(),
+                            "num_devices": place.divisor,
+                            **task.run_params(cfg, model_cfg),
+                        }
+                    )
 
                 epoch_seconds: list = []
                 start_epoch = min(int(state.epoch), cfg.epochs)
@@ -740,65 +804,21 @@ def train_model(
                         "evaluating only", int(state.epoch), cfg.epochs,
                     )
                     with phases.stage("rdp.train.validation"):
-                        final_metrics = run_val()
+                        final_metrics = runner.validate(state)
                 for epoch in range(start_epoch, cfg.epochs):
                     with phases.stage("rdp.train.epoch", epoch=epoch):
                         t_epoch = time.perf_counter()
                         with phases.stage("rdp.train.steps"):
-                            if use_scan:
-                                order = jnp.asarray(data_lib.epoch_order(
-                                    len(train_idx), batch_size, True, order_rng
-                                ))
-                                state, out = train_epoch(
-                                    state, xs_tr, ys_tr, order)
-                                if isinstance(out, dict):
-                                    out = jax.device_get(out)
-                                    train_loss = float(out["loss"])
-                                    step_losses = out.pop("step_loss")
-                                else:
-                                    train_loss, out = float(out), None
-                            else:
-                                train_losses = []
-                                # device-prefetch: batch k+1 decodes + stages
-                                # while the donated step for batch k runs on
-                                # device (losses are fetched at epoch end, so
-                                # nothing here blocks per step)
-                                step_num = epoch * len(train_batches)
-                                for dx, dy in prefetch_to_device(
-                                    train_batches, to_device
-                                ):
-                                    with jax.profiler.StepTraceAnnotation(
-                                        "rdp.train.step", step_num=step_num
-                                    ):
-                                        state, loss = train_step(state, dx, dy)
-                                    train_losses.append(loss)
-                                    step_num += 1
-                                if train_losses and isinstance(
-                                        train_losses[0], dict):
-                                    train_losses = jax.device_get(
-                                        train_losses)
-                                    out = jax.tree.map(
-                                        lambda *v: np.mean(v, axis=0),
-                                        *train_losses)
-                                    train_loss = float(out["loss"])
-                                    step_losses = [
-                                        l["loss"] for l in train_losses]
-                                else:
-                                    out = None
-                                    train_loss = float(np.mean(
-                                        [float(l) for l in train_losses]))
-
-                            # Train-phase throughput (the float() above synced
-                            # the device, so the measured window covers real
-                            # step time). One histogram sample per epoch at the
-                            # mean step time: the scan path is one whole-epoch
-                            # dispatch with no per-step boundary to time, and
-                            # the streamed path's per-step wall time is
-                            # dispatch-only (losses are fetched at epoch end),
-                            # so the epoch mean is the honest per-step number
-                            # for both.
-                            n_steps = (int(order.shape[0]) if use_scan
-                                       else len(train_losses))
+                            state, train_loss, out, step_losses, n_steps = (
+                                runner.train(state, epoch))
+                            # Train-phase throughput (the runner's fetch of
+                            # the losses synced the device, so the window
+                            # covers real step time). One histogram sample
+                            # per epoch at the mean step time: the scan is
+                            # one dispatch with no per-step boundary to
+                            # time, and the stepped loop's per-step wall
+                            # time is dispatch-only, so the epoch mean is
+                            # the honest per-step number for both.
                             train_time = time.perf_counter() - t_epoch
                         if n_steps and train_time > 0:
                             obs.TRAIN_STEP.observe(train_time / n_steps)
@@ -810,25 +830,23 @@ def train_model(
                                     None if ds is not None else xs.shape[1:])
 
                         with phases.stage("rdp.train.validation"):
-                            val = run_val()
-                        final_metrics = val
+                            final_metrics = val = runner.validate(state)
 
                         with phases.stage("rdp.train.log"):
-                            if is_main:
-                                tracking.log_metric(
-                                    "train_loss", train_loss, step=epoch)
-                                tracking.log_metric(
-                                    "val_loss", val["loss"], step=epoch)
-                                for name in task.val_logged:
-                                    tracking.log_metric(
-                                        f"val_{name}", val[name], step=epoch)
-                                if out is not None:
-                                    # a task with per-step numbers: every
-                                    # step's loss, numbered through the epochs
-                                    for i, v in enumerate(step_losses):
-                                        tracking.log_metric(
-                                            "train_step_loss", float(v),
-                                            step=epoch * n_steps + i)
+                            track.log_metric(
+                                "train_loss", train_loss, step=epoch)
+                            track.log_metric(
+                                "val_loss", val["loss"], step=epoch)
+                            for name in task.val_logged:
+                                track.log_metric(
+                                    f"val_{name}", val[name], step=epoch)
+                            if out is not None:
+                                # a task with per-step numbers: every
+                                # step's loss, numbered through the epochs
+                                for i, v in enumerate(step_losses):
+                                    track.log_metric(
+                                        "train_step_loss", float(v),
+                                        step=epoch * n_steps + i)
                             epoch_seconds.append(time.perf_counter() - t_epoch)
                             log.info(
                                 "epoch %d/%d train_loss=%.4f val_loss=%.4f "
@@ -838,125 +856,27 @@ def train_model(
                                 epoch_seconds[-1],
                             )
 
-                        saving = not ((epoch + 1) % cfg.checkpoint_every
-                                      and epoch + 1 < cfg.epochs)
-                        improved = val["loss"] < float(state.best_val_loss)
-                        if streamed:
-                            # no second tree in HBM: the candidates for
-                            # "best" are the epochs whose state is saved
-                            improved = improved and saving
-                            if improved:
-                                state = state.replace(
-                                    best_val_loss=scalarize(
-                                        val["loss"], jnp.float32))
-                                best_step = epoch + 1
-                        elif improved:
-                            with phases.stage("rdp.train.best_copy"):
-                                state = state.replace(
-                                    best_val_loss=scalarize(
-                                        val["loss"], jnp.float32)
-                                )
-                                best_params, best_stats = _copy_tree(
-                                    (state.params, state.batch_stats)
-                                )
+                        # every checkpoint_every-th epoch and the last one
+                        state = saves.epoch_done(
+                            state, epoch, val["loss"],
+                            saving=not ((epoch + 1) % cfg.checkpoint_every
+                                        and epoch + 1 < cfg.epochs))
 
-                        state = state.replace(
-                            epoch=scalarize(epoch + 1, jnp.int32))
-                        if not saving:
-                            continue
-                        if streamed:
-                            # the state comes to the host leaf by leaf
-                            # before the next donated step; a background
-                            # worker writes it while that step runs
-                            with phases.stage("rdp.train.checkpoint.wait"):
-                                ckpt.wait()
-                            with phases.stage("rdp.train.checkpoint.snapshot"):
-                                host = ckpt.save_streamed(
-                                    epoch + 1, {"state": state},
-                                    best=improved)
-                                if improved:
-                                    best_host = (host["state"].params,
-                                                 host["state"].batch_stats)
-                                del host
-                            continue
-                        # Collective: every process calls save; orbax
-                        # coordinates its own cross-host barriers and each host
-                        # writes its shards.
-                        payload = {
-                            "state": state,
-                            "best_params": (
-                                best_params if best_params is not None
-                                else state.params
-                            ),
-                            "best_stats": (
-                                best_stats if best_stats is not None
-                                else state.batch_stats
-                            ),
-                        }
-                        if jax.process_count() == 1 and cfg.async_checkpointing:
-                            # single-controller: snapshot to independent device
-                            # buffers (cheap HBM copy, and required -- the live
-                            # state is donated into the next epoch's step), then
-                            # a background worker pays the ONE bulk host fetch +
-                            # disk write while the next epoch's compute runs.
-                            # Letting orbax pull device arrays leaf by leaf
-                            # would cost a device round-trip per leaf (~270
-                            # leaves), and a synchronous fetch would serialize
-                            # ~350 MB of D2H traffic into every epoch.
-                            # wait for the PREVIOUS epoch's save before building
-                            # the new snapshot: otherwise three copies of the
-                            # state (live + old snapshot + new snapshot) coexist
-                            # in HBM whenever saves run longer than epochs
-                            with phases.stage("rdp.train.checkpoint.wait"):
-                                ckpt.wait()
-                            with phases.stage("rdp.train.checkpoint.snapshot"):
-                                ckpt.save_async(epoch + 1, _copy_tree(payload))
-                        else:
-                            with phases.stage("rdp.train.checkpoint.snapshot"):
-                                if jax.process_count() == 1:
-                                    # synchronous opt-out keeps the
-                                    # one-bulk-fetch shape
-                                    ckpt.save(epoch + 1, jax.device_get(payload))
-                                else:
-                                    # multi-host saves are collective; orbax's
-                                    # cross-host barriers must run in lockstep
-                                    # on every process
-                                    ckpt.save(epoch + 1, payload)
+                with phases.stage("rdp.train.log"):
+                    track.log_metric(
+                        "best_val_loss", float(state.best_val_loss))
 
-                if is_main:
-                    with phases.stage("rdp.train.log"):
-                        tracking.log_metric(
-                            "best_val_loss", float(state.best_val_loss))
-
-                if register and (best_params is not None
-                                 or best_step is not None):
+                if register:
                     with phases.stage("rdp.train.register"):
-                        if best_step is not None:
-                            # the parameters this call fetched for its best
-                            # save, or those of the checkpoint that holds
-                            # the best of an earlier call
-                            if best_host is None:
-                                ckpt.wait()
-                                best = ckpt.restore_streamed(
-                                    {"state": abstract_state.replace(
-                                        opt_state=None, epoch=None,
-                                        best_val_loss=None)},
-                                    step=best_step)["state"]
-                                best_host = (best.params, best.batch_stats)
-                            host_params, host_stats = best_host
-                        else:
-                            # collective all-gather of any TP-sharded leaves,
-                            # then host fetch on every process; only process
-                            # 0 writes the registry
-                            host_params = _fetch_to_host(best_params)
-                            host_stats = _fetch_to_host(best_stats)
-                        if is_main:
-                            variables = task.variables(
-                                host_params, host_stats)
-                            registry_version = tracking.log_model(
-                                variables, model_cfg,
+                        # on every process (a collective all-gather of any
+                        # TP-sharded leaves); only process 0 writes
+                        best = saves.best()
+                        if best is not None:
+                            registry_version = track.log_model(
+                                task.variables(*best), model_cfg,
                                 registered_model_name=cfg.registered_model_name,
                             )
+                        if registry_version is not None:
                             log.info(
                                 "registered %s version %s",
                                 cfg.registered_model_name, registry_version,
@@ -968,11 +888,11 @@ def train_model(
             # close without raising: a pending save failure must not mask the
             # already-propagating training exception (it is logged instead)
             with phases.stage("rdp.train.flush"):
-                ckpt.close(raise_errors=False)
+                saves.close(raise_errors=False)
             raise
         else:
             with phases.stage("rdp.train.flush"):
-                ckpt.close()
+                saves.close()
         return TrainResult(
             run_id=run_id,
             registry_version=registry_version,

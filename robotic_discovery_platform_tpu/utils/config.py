@@ -130,29 +130,19 @@ class TrainConfig:
     # checkpoint every N epochs (the final epoch always saves); raise for
     # short-epoch runs where per-epoch state serialization dominates
     checkpoint_every: int = 1
-    # Overlap checkpoint IO with the next epoch's compute (single-process
-    # only; multi-host saves are collective and always synchronous): the
-    # state is snapshot on device, and a background worker pays the host
-    # fetch + disk write. False forces the synchronous save everywhere.
-    async_checkpointing: bool = True
     # TPU-first:
     donate_state: bool = True
-    log_every: int = 1
     # tensor parallelism: shard conv kernels with >= this many output
     # channels over the mesh "model" axis (see parallel.mesh.tp_param_specs)
     tp_min_channels: int = 256
     # decode threads for the streaming file loader (StreamingBatches)
     loader_workers: int = 4
     # Epoch execution: "auto" runs whole epochs in one lax.scan dispatch
-    # when the dataset is in-memory, fits scan_max_bytes, and no mesh is
-    # given (one host fetch per epoch instead of per step); "stream"
-    # forces the per-batch loop; "scan" requires the scan path and errors
-    # if unavailable.
+    # when the dataset is in-memory, fits the device beside the job (4 GiB,
+    # a constant of training/trainer.py), and no mesh is given (one host
+    # fetch per epoch instead of per step); "stream" forces the per-batch
+    # loop; "scan" requires the scan path and errors if unavailable.
     epoch_mode: str = "auto"
-    # device-residency cap for "auto" scan mode; datasets above this fall
-    # back to the streamed per-batch path (v5e has 16 GiB HBM; leave room
-    # for params, activations, and the donated state copy)
-    scan_max_bytes: int = 4 * 1024**3
 
 
 @dataclass(frozen=True)

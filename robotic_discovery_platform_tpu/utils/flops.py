@@ -295,17 +295,6 @@ def unet_forward_flops(img_size: int = 256, base: int = 64,
     return total
 
 
-def unet_train_step_flops(batch: int, img_size: int = 256, base: int = 64,
-                          in_ch: int = 3, num_classes: int = 1,
-                          bilinear: bool = True) -> int:
-    """FLOPs of one optimizer step: forward + backward. The backward pass
-    costs ~2x the forward (dx and dw are each a conv-sized contraction),
-    the standard 3x-forward rule."""
-    return 3 * batch * unet_forward_flops(
-        img_size, base, in_ch, num_classes, bilinear
-    )
-
-
 def mfu(flops: int, seconds: float, peaks: ChipPeaks) -> float:
     """Fraction of the chip's bf16 peak: (flops / seconds) / peak."""
     return (flops / max(seconds, 1e-12)) / (peaks.bf16_tflops * 1e12)

@@ -156,6 +156,22 @@ def state_job(name, arrays, tmp):
     return call
 
 
+def spying_on(run, states):
+    """A train runner that keeps, on the host, every state it is handed."""
+    def spying(state, *batch):
+        states.append(jax.device_get(state))
+        return run(state, *batch)
+
+    return spying
+
+
+def assert_trees_equal(got, want):
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+
+
 @contextlib.contextmanager
 def watched(name, model_cfg, spy=False):
     """What a ``train_model`` call does about its state: ``built`` counts
@@ -176,12 +192,7 @@ def watched(name, model_cfg, spy=False):
 
     def make_epoch_runners(*args, **kw):
         train_epoch, eval_epoch = sound_runners(*args, **kw)
-
-        def spying(state, xs, ys, order):
-            seen["states"].append(jax.device_get(state))
-            return train_epoch(state, xs, ys, order)
-
-        return spying, eval_epoch
+        return spying_on(train_epoch, seen["states"]), eval_epoch
 
     def counter():
         return {result: obs.TRAIN_STATE.labels(
@@ -200,18 +211,18 @@ def watched(name, model_cfg, spy=False):
         seen["counter"] = {k: v - before[k] for k, v in counter().items()}
 
 
-def saved_at(name, cfg, step):
-    """What the job's checkpoint of ``step`` holds, read the way a resumed
-    job read it before it restored into shapes: into a state built for the
-    purpose, on the host. ``(state, best_params, best_stats)``."""
-    model_cfg = STATE_JOBS[name][0]
+def saved_at(model_cfg, cfg, step, streamed):
+    """What the job's checkpoint of ``step`` holds, by either save path,
+    read the way a resumed job read it before it restored into shapes: into
+    a state built for the purpose, on the host. ``(state, best_params,
+    best_stats)``."""
     task = tasks_lib.task_for(model_cfg)
     built = jax.device_get(trainer.task_state(
         task, task.build(model_cfg), optax.adam(cfg.learning_rate),
         jax.random.key(cfg.seed), cfg))
     ckpt = CheckpointManager(cfg.checkpoint_dir, keep=cfg.keep_checkpoints)
     try:
-        if name == "lm-streamed":
+        if streamed:
             state, best = (
                 ckpt.restore_streamed({"state": built}, step=at)["state"]
                 for at in (step, ckpt.best_step()))
@@ -245,7 +256,8 @@ def resumed(request, arrays, tmp_path_factory):
                           for p, n in phases.items()}
     with watched(name, model_cfg, spy=True) as out["more_seen"]:
         out["more"] = call(3)
-    out["saved"] = saved_at(name, tiny_cfg(tmp), 2)
+    out["saved"] = saved_at(model_cfg, tiny_cfg(tmp), 2,
+                            name == "lm-streamed")
     with watched(name, model_cfg) as out["plain_seen"]:
         out["plain"] = state_job(name, arrays, tmp / "plain")(
             2, resume=False)
@@ -268,10 +280,7 @@ def test_a_resumed_job_trains_from_the_saved_state_bit_for_bit(resumed):
     handed, = resumed["more_seen"]["states"]
     saved, _, _ = resumed["saved"]
     assert int(saved.epoch) == 2 and np.isfinite(float(saved.best_val_loss))
-    assert jax.tree.structure(handed) == jax.tree.structure(saved)
-    for got, want in zip(jax.tree.leaves(handed), jax.tree.leaves(saved)):
-        assert got.dtype == want.dtype
-        np.testing.assert_array_equal(got, want)
+    assert_trees_equal(handed, saved)
 
 
 def test_a_resumed_job_registers_the_saved_best_candidate(resumed):
@@ -313,6 +322,74 @@ def test_a_resumed_job_runs_the_pinned_phases_once_each(resumed):
     """The ``rdp.train.*`` children of a resumed call's job, by the
     histogram the spans feed: init, restore, stage_data, register, flush."""
     assert resumed["idle_phases"] == dict.fromkeys(JOB_PHASES, 1)
+
+
+@pytest.mark.parametrize("streamed", [False, True],
+                         ids=["device-snapshot", "streamed"])
+@pytest.mark.parametrize("epoch_mode", ["auto", "stream"])
+def test_each_epoch_runner_under_each_save_policy_resumes_and_registers(
+        tmp_path, arrays, epoch_mode, streamed):
+    """``train_model``'s two seams, every pairing, on a model with batch
+    statistics: the whole-epoch scan and the per-step loop, the device
+    snapshot and (by the threshold the trainer reads) the streamed save.
+    Two epochs, then the job resumed for a third that registers: the
+    resumed call's first step is handed the saved state bit for bit, what
+    is registered is the best epoch's parameters AND statistics, and
+    ``streamed/`` exists exactly when told."""
+    cfg = tiny_cfg(tmp_path, epoch_mode=epoch_mode)
+    family = "epoch" if epoch_mode == "auto" else "step"
+    handed = []
+
+    def call(epochs, **kw):
+        return trainer.train_model(
+            dataclasses.replace(cfg, epochs=epochs), TINY_MODEL,
+            arrays=arrays, resume=True, **kw)
+
+    sound_epoch, sound_step = (trainer.make_epoch_runners,
+                               trainer.make_train_step)
+
+    def epoch_runners(*args, **kw):
+        train_epoch, eval_epoch = sound_epoch(*args, **kw)
+        return spying_on(train_epoch, handed), eval_epoch
+
+    def lookups():
+        return sum(obs.TRAIN_RUNNERS.labels(family=family, result=r).value
+                   for r in ("built", "reused"))
+
+    with pytest.MonkeyPatch.context() as patch:
+        if streamed:
+            patch.setattr(trainer, "_DEVICE_SNAPSHOT_MAX_BYTES", 1000)
+        before = lookups()
+        first = call(2, register=False)
+        assert lookups() == before + 1      # the runner this mode names
+        patch.setattr(trainer, "make_epoch_runners", epoch_runners)
+        patch.setattr(
+            trainer, "make_train_step",
+            lambda *args, **kw: spying_on(sound_step(*args, **kw), handed))
+        second = call(3, register=True)
+    assert (first.epochs_run, second.epochs_run) == (2, 1)
+    assert (tmp_path / "ckpt" / "streamed").is_dir() == streamed
+
+    saved, _, _ = saved_at(TINY_MODEL, cfg, 2, streamed)
+    assert int(saved.epoch) == 2 and saved.batch_stats
+    assert_trees_equal(handed[0], saved)
+
+    losses = [m["value"] for result in (first, second)
+              for m in tracking.get_metric_history(result.run_id, "val_loss")]
+    assert len(losses) == 3
+    best = int(np.argmin(losses))   # the first epoch to reach the least
+    assert second.best_val_loss == pytest.approx(losses[best], rel=1e-6)
+    of_best, _, _ = saved_at(TINY_MODEL, cfg, best + 1, streamed)
+    last, best_params, best_stats = saved_at(TINY_MODEL, cfg, 3, streamed)
+    assert int(last.epoch) == 3
+    assert_trees_equal((best_params, best_stats),
+                       (of_best.params, of_best.batch_stats))
+    assert second.registry_version == 1
+    _, registered = tracking.load_model(
+        f"models:/{cfg.registered_model_name}/latest")
+    assert set(registered) == {"params", "batch_stats"}
+    assert_trees_equal(registered, tasks_lib.UNET.variables(
+        of_best.params, of_best.batch_stats))
 
 
 def test_checkpoint_every_skips_intermediate_saves(tmp_path, arrays):
