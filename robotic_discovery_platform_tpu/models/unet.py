@@ -126,14 +126,17 @@ def _norm(norm: str, dtype: DType, train: bool, features: int):
 
 
 class TrainConv3x3(nn.Module):
-    """3x3 SAME no-bias conv backed by the custom-VJP Pallas kernels
-    (ops/pallas/conv.conv3x3: Pallas forward, Pallas dx and dw) so the
-    TRAINING step's hot op runs hand-written kernels too, not only the
-    folded inference path. Same parameter name/shape as ``nn.Conv``
-    ("kernel", [3, 3, Cin, Cout]), so checkpoints, torch-weight import,
-    and the PallasUNet variable walk are layout-identical.
+    """3x3 SAME no-bias conv of the TRAINING step through
+    ops/pallas/conv.conv3x3, which picks the form by the layer's shape:
+    the custom-VJP Pallas kernels (forward, dx, dw) where its one
+    predicate says they win, else the plain XLA convolution with JAX's own
+    derivative -- what ``nn.Conv`` issues, so ``conv_impl="auto"`` and
+    ``"flax"`` compile one program wherever no layer goes to Pallas. Same
+    parameter name/shape as ``nn.Conv`` ("kernel", [3, 3, Cin, Cout]), so
+    checkpoints, torch-weight import, and the PallasUNet variable walk are
+    layout-identical.
 
-    The custom-VJP path engages only under ``train=True``: inference
+    ``conv3x3`` engages only under ``train=True``: inference
     consumers of ``model.apply`` keep the plain XLA conv (per-layer
     Pallas/XLA mixing measures ~24% slower end-to-end, and the Pallas
     serving path is the uniformly-fused ``PallasUNet``, not this module).
@@ -169,8 +172,9 @@ class DoubleConv(nn.Module):
     (reference: pkg/segmentation_model.py:24-40).
 
     ``conv_impl="flax"`` uses ``nn.Conv`` (XLA convs end to end);
-    anything else routes the convs through :class:`TrainConv3x3`'s
-    custom-VJP Pallas kernels with that dispatch mode.
+    anything else routes the convs through :class:`TrainConv3x3` with
+    that dispatch mode ("auto": by the layer's shape; the rest pin the
+    custom VJP for the tests).
     """
 
     features: int

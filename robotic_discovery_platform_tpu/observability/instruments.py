@@ -735,6 +735,16 @@ TRAIN_STATE = REGISTRY.counter(
     "which took the state's shapes and built nothing.",
     ("family", "result"),
 )
+TRAIN_CONV_DISPATCH = REGISTRY.counter(
+    families.TRAIN_CONV_DISPATCH,
+    "3x3 training convolutions by the form ops/pallas/conv.conv3x3 picked "
+    "for the layer's shape, one sample per convolution each time a step "
+    "that holds it is traced (so it reads beside rdp_jit_traces_total: a "
+    "memoised runner adds none): pallas = the custom VJP's Pallas forward, "
+    "dx and dw kernels; xla = XLA convolutions (under conv_impl auto the "
+    "plain convolution with JAX's own derivative).",
+    ("impl",),
+)
 MOE_ROUTED_ROWS = REGISTRY.counter(
     families.MOE_ROUTED_ROWS,
     "Rows the expert layers' held experts took in training steps (one per "

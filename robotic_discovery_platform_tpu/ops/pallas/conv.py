@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from robotic_discovery_platform_tpu.analysis.contracts import shape_contract
+from robotic_discovery_platform_tpu.observability import instruments as obs
 
 
 def _element_block_spec(shape, index_map) -> pl.BlockSpec:
@@ -606,9 +607,20 @@ def conv3x3_grad_weights_xla(x, g):
     return jnp.transpose(dw, (1, 2, 0, 3))
 
 
+#: Where "auto" sends a 3x3 training convolution to the Pallas kernels: a
+#: batch of at most 4 and at most 2^18 output pixels (batch x H x W), the
+#: reference configuration's regime (batch 4 at 256^2,
+#: scripts/train_segmenter.py:46) and the only one measured in which they
+#: win (:func:`_vjp_pallas` has the numbers).
+_PALLAS_TRAIN_MAX_BATCH = 4
+_PALLAS_TRAIN_MAX_PIXELS = 2 ** 18
+
+
 def _vjp_pallas(x, cin: int, cout: int, impl: str, interpret: bool) -> bool:
-    """ONE dispatch predicate shared by the custom-VJP forward, dx, and dw
-    (so the rules cannot drift apart between them). True -> Pallas kernels.
+    """ONE dispatch predicate for a 3x3 training convolution, shared by
+    :func:`conv3x3` (custom VJP or JAX's own derivative) and by the custom
+    VJP's forward, dx and dw (so the rules cannot drift apart between
+    them). True -> Pallas kernels.
 
     - interpret always exercises the interpreted Pallas kernels (they are
       what the CPU tests exist to validate);
@@ -617,12 +629,21 @@ def _vjp_pallas(x, cin: int, cout: int, impl: str, interpret: bool) -> bool:
       large batch under an earlier JAX; those layers are a negligible FLOP fraction
       and already sit at XLA boundaries, so they run the XLA forms under
       every COMPILED dispatch mode, forced "pallas" included;
-    - measured v5e crossover for the TRAIN step (chained scan, 256^2):
-      full-Pallas custom-VJP 21.8 ms vs XLA 22.6 at batch 4 (the reference
-      config, train_segmenter.py:46; volume 4 * 256^2 == 2^18) but 210 vs
-      115 ms at batch 32 -- the same "batched wide maps favor XLA" physics
-      as inference. "auto" therefore gates at the measured 2^18 anchor;
-      the b8/b16 region is unmeasured and conservatively routed to XLA.
+    - "auto" reads the layer's shape. Measured on one TPU v5e ("TPU v5
+      lite", jax 0.9.0, PR 34; the train step of the 64-128-256-512-512
+      U-Net at 256^2 inside the whole-epoch scan, device ms a step, every
+      layer Pallas against every layer the plain XLA convolution with
+      JAX's own derivative): batch 4: 22.43 vs 23.56; batch 8: 48.96 vs
+      28.96; batch 16: 100.56 vs 56.47; batch 32: 207.62 vs 115.72 (and
+      215.75 vs 89.19 on the 1024-wide up-convolution U-Net). The Pallas
+      step costs 5.6-6.5 ms an image at every batch; XLA's costs 5.9 at
+      batch 4 and 3.5-3.6 from batch 8 on. The rule this replaced, batch
+      x H x W <= 2^18 alone, sent the blocks at 64^2 and below of a
+      batch-32 step to Pallas, and each was slower there (that mixed step:
+      125.31 ms). So "auto" keeps Pallas for a batch of at most 4 with at
+      most 2^18 pixels, the one regime measured where it wins; batches
+      5-7, and 1-2 at other sizes, are unmeasured. The table by block and
+      by layer is in PERF.md section 5.
     """
     if interpret or impl == "interpret":
         return True
@@ -632,7 +653,9 @@ def _vjp_pallas(x, cin: int, cout: int, impl: str, interpret: bool) -> bool:
         return True
     if impl == "xla":
         return False
-    small = x.shape[0] * x.shape[1] * x.shape[2] <= 2 ** 18
+    batch, h, width = x.shape[:3]
+    small = (batch <= _PALLAS_TRAIN_MAX_BATCH
+             and batch * h * width <= _PALLAS_TRAIN_MAX_PIXELS)
     return use_pallas() and small
 
 
@@ -650,17 +673,7 @@ def _conv3x3_raw(x, w, impl: str, interpret: bool):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def conv3x3(x, w, impl: str = "auto", interpret: bool = False):
-    """Differentiable stride-1 SAME 3x3 no-bias conv with Pallas forward
-    and backward kernels -- the training-path form of the DoubleConv
-    half-block's conv (reference: pkg/segmentation_model.py:30-33).
-
-    ``impl``: "auto" (Pallas on TPU, XLA elsewhere), "pallas", or "xla" --
-    the same measured-dispatch convention as the inference path.
-
-    Forward, dx and dw run under the named scope ``rdp.conv3x3``, whichever
-    implementation the dispatch picks.
-    """
+def _conv3x3_vjp(x, w, impl: str, interpret: bool):
     return _conv3x3_raw(x, w, impl, interpret)
 
 
@@ -686,4 +699,35 @@ def _conv3x3_bwd(impl, interpret, res, g):
     return dx.astype(x.dtype), dw.astype(w.dtype)
 
 
-conv3x3.defvjp(_conv3x3_fwd, _conv3x3_bwd)
+_conv3x3_vjp.defvjp(_conv3x3_fwd, _conv3x3_bwd)
+
+
+def conv3x3(x, w, impl: str = "auto", interpret: bool = False):
+    """Differentiable stride-1 SAME 3x3 no-bias conv of the training step
+    -- the DoubleConv half-block's conv (reference:
+    pkg/segmentation_model.py:30-33).
+
+    ``impl="auto"`` asks :func:`_vjp_pallas` about the layer's shape. Where
+    it says yes, and under every pinned ``impl`` ("pallas", "xla",
+    "interpret": the tests'), the conv is a ``jax.custom_vjp`` whose
+    forward, dx and dw are the Pallas kernels above (or their hand-written
+    XLA oracles where the predicate says no to a pinned mode). Where
+    "auto" says no there is no custom VJP: the conv is the plain
+    ``lax.conv_general_dilated`` that ``nn.Conv`` issues (operands in the
+    compute dtype, float32 accumulation inside the product, the result in
+    the compute dtype) and JAX derives dx and dw, so XLA chooses every
+    layout and fuses the neighbouring norm and ReLU as it sees fit.
+
+    Counts one sample of ``rdp_train_conv_dispatch_total`` per call, that
+    is per trace of the step that holds it. Forward, dx and dw run under
+    the named scope ``rdp.conv3x3``, whichever form is picked.
+    """
+    pallas = _vjp_pallas(x, w.shape[2], w.shape[3], impl, interpret)
+    obs.TRAIN_CONV_DISPATCH.labels(impl="pallas" if pallas else "xla").inc()
+    if impl == "auto" and not pallas:
+        with jax.named_scope("rdp.conv3x3"):
+            return jax.lax.conv_general_dilated(
+                x, w, (1, 1), "SAME",
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            )
+    return _conv3x3_vjp(x, w, impl, interpret)

@@ -51,14 +51,17 @@ class ModelConfig:
     # the Flax default family.
     init: str = "torch"
     # Training-path conv implementation for the DoubleConv 3x3 convs.
-    # "auto" (default): the custom-VJP ops/pallas/conv.conv3x3 (Pallas
-    # forward + backward kernels), engaging Pallas on TPU at small
-    # batch-spatial volume where it measures faster than XLA (21.8 vs
-    # 22.6 ms/step at the reference batch 4 @ 256^2) and XLA above it
-    # (115 vs 210 ms at batch 32). "flax" = nn.Conv end to end -- the
-    # trainer forces this under a device mesh, where the custom kernels
-    # have no pjit partitioning rules. "pallas"/"xla"/"interpret" pin the
-    # custom-VJP dispatch for tests.
+    # "auto" (default): ops/pallas/conv.conv3x3 picks by the layer's shape.
+    # On a TPU a batch of at most 4 with at most 2^18 pixels (batch x H x
+    # W) runs the custom-VJP Pallas forward, dx and dw kernels, every
+    # other shape the plain XLA convolution with JAX's own derivative --
+    # the program "flax" compiles. Measured on one TPU v5e under jax 0.9.0
+    # (PR 34, device ms a step in the scan epoch at 256^2, all-Pallas vs
+    # plain): 22.43 vs 23.56 at the reference batch 4, 48.96 vs 28.96 at
+    # batch 8, 207.62 vs 115.72 at batch 32. "flax" = nn.Conv end to end
+    # -- the trainer forces this under a device mesh, where the custom
+    # kernels have no pjit partitioning rules. "pallas"/"xla"/"interpret"
+    # pin the custom-VJP dispatch for tests.
     conv_impl: str = "auto"
 
 

@@ -184,6 +184,160 @@ def test_conv3x3_custom_vjp_matches_autodiff():
     )
 
 
+# -- which form a 3x3 training convolution traces to --------------------------
+
+#: (side, Cin, Cout) of every distinct 3x3 layer of the benchmark's two
+#: U-Nets at 256x256: `seg` (64-128-256-512-512, bilinear) and `unet-tconv`
+#: (64 to 1024, up-convolutions), block by block, encoder then decoder
+SEG_LAYERS = (
+    (256, 3, 64), (256, 64, 64), (128, 64, 128), (128, 128, 128),
+    (64, 128, 256), (64, 256, 256), (32, 256, 512), (32, 512, 512),
+    (16, 512, 512), (32, 1024, 512), (32, 512, 256), (64, 512, 256),
+    (64, 256, 128), (128, 256, 128), (128, 128, 64), (256, 128, 64))
+TCONV_ONLY_LAYERS = ((16, 512, 1024), (16, 1024, 1024))
+#: batch -> layers: the cells' batch 32 (both models) and the reference
+#: configuration's batch 4 (scripts/train_segmenter.py:46)
+DISPATCH_CASES = [(32, *ly) for ly in SEG_LAYERS + TCONV_ONLY_LAYERS] + [
+    (4, *ly) for ly in SEG_LAYERS]
+
+
+def _primitives(jaxpr, found=None):
+    """Every primitive's name in a jaxpr and the jaxprs its equations hold
+    (a custom VJP's forward, a ``pallas_call``'s kernel, a ``pjit``)."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        found.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, found)
+    return found
+
+
+def _expected_form(batch, side, cin, cout):
+    """What the chip settled (PERF.md section 5, PR 34), written out and
+    not asked of the predicate: no layer of a batch-32 step goes to Pallas;
+    the reference batch 4 at 256^2 keeps it in every layer but the RGB
+    input's, whose 3 channels no compiled Pallas kernel takes."""
+    return "pallas" if batch == 4 and cin >= 8 else "xla"
+
+
+def _dispatch_counts():
+    from robotic_discovery_platform_tpu.observability import instruments as obs
+
+    return {impl: obs.TRAIN_CONV_DISPATCH.labels(impl=impl).value
+            for impl in ("pallas", "xla")}
+
+
+@pytest.mark.parametrize("batch,side,cin,cout", DISPATCH_CASES)
+def test_auto_dispatch_by_layer_shape(monkeypatch, batch, side, cin, cout):
+    """``conv3x3(x, w, "auto")`` as a process that sees a TPU traces it,
+    from shapes alone (nothing runs): either a ``pallas_call`` under a
+    ``custom_vjp`` with Pallas dx and dw, or one bare
+    ``conv_general_dilated`` with no ``custom_vjp`` around it, whose dx and
+    dw JAX derives (two more); ``rdp_train_conv_dispatch_total`` moves by
+    one under the matching label a trace."""
+    from robotic_discovery_platform_tpu.ops.pallas import conv as pconv
+
+    monkeypatch.setattr(pconv, "use_pallas", lambda: True)
+    x = jax.ShapeDtypeStruct((batch, side, side, cin), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((3, 3, cin, cout), jnp.bfloat16)
+    want = _expected_form(batch, side, cin, cout)
+
+    def conv(x, w):
+        return pconv.conv3x3(x, w, "auto")
+
+    def grads(x, w):
+        return jax.grad(lambda x, w: jnp.sum(conv(x, w).astype(jnp.float32)),
+                        argnums=(0, 1))(x, w)
+
+    before = _dispatch_counts()
+    forward = _primitives(jax.make_jaxpr(conv)(x, w).jaxpr)
+    after = _dispatch_counts()
+    assert {k: after[k] - before[k] for k in after} == {
+        "pallas": int(want == "pallas"), "xla": int(want == "xla")}
+    both = _primitives(jax.make_jaxpr(grads)(x, w).jaxpr)
+    if want == "pallas":
+        assert forward.count("custom_vjp_call") == 1
+        assert "pallas_call" in forward
+        assert "conv_general_dilated" not in forward + both
+        assert both.count("pallas_call") == 3      # forward, dx, dw
+    else:
+        assert forward.count("conv_general_dilated") == 1
+        assert not {"custom_vjp_call", "pallas_call"} & set(forward + both)
+        assert both.count("conv_general_dilated") == 3
+
+
+@pytest.mark.parametrize("bilinear", [True, False], ids=["seg", "unet-tconv"])
+def test_the_dispatch_cases_are_the_models_own_layers(monkeypatch, bilinear):
+    """The table above against what the two configurations' train steps
+    hand ``conv3x3`` at batch 32, 256x256: eighteen calls a model."""
+    from robotic_discovery_platform_tpu.models.unet import build_unet
+    from robotic_discovery_platform_tpu.ops.pallas import conv as pconv
+    from robotic_discovery_platform_tpu.utils.config import ModelConfig
+
+    seen = []
+    sound = pconv.conv3x3
+
+    def recording(x, w, impl="auto", interpret=False):
+        seen.append((x.shape[0], x.shape[1], w.shape[2], w.shape[3]))
+        assert x.shape[1] == x.shape[2] and x.dtype == w.dtype == jnp.bfloat16
+        return sound(x, w, impl, interpret)
+
+    monkeypatch.setattr(pconv, "conv3x3", recording)
+    model = build_unet(ModelConfig(bilinear=bilinear))
+    variables = jax.eval_shape(lambda: init_unet(model, jax.random.key(0), 32))
+    jax.eval_shape(
+        lambda v, x: model.apply(v, x, train=True, mutable=["batch_stats"]),
+        variables, jax.ShapeDtypeStruct((32, 256, 256, 3), jnp.float32))
+    assert len(seen) == 18
+    want = set(SEG_LAYERS) if bilinear else (
+        (set(SEG_LAYERS) - {(16, 512, 512), (32, 512, 256), (64, 256, 128),
+                            (128, 128, 64)}) | set(TCONV_ONLY_LAYERS))
+    assert {ly[1:] for ly in seen} == want and {ly[0] for ly in seen} == {32}
+
+
+@pytest.mark.parametrize("bilinear,compute_dtype", [
+    (True, "float32"), (False, "float32"), (True, "bfloat16")],
+    ids=["seg-float32", "unet-tconv-float32", "seg-bfloat16"])
+def test_auto_trains_as_flax_where_no_layer_goes_to_pallas(
+        monkeypatch, bilinear, compute_dtype):
+    """One optimiser step of a tiny U-Net under ``conv_impl="auto"`` where
+    the shape rule sends no layer to Pallas (a process that sees a TPU, the
+    rule's limit below every layer) is the step under ``"flax"`` to the
+    last digit: loss, updated parameters, Adam's moments, batch
+    statistics. The plain convolution is ``nn.Conv``'s own call."""
+    import optax
+
+    from robotic_discovery_platform_tpu.models import losses as losses_lib
+    from robotic_discovery_platform_tpu.models.unet import build_unet
+    from robotic_discovery_platform_tpu.ops.pallas import conv as pconv
+    from robotic_discovery_platform_tpu.training import trainer
+    from robotic_discovery_platform_tpu.utils.config import ModelConfig
+
+    monkeypatch.setattr(pconv, "use_pallas", lambda: True)
+    monkeypatch.setattr(pconv, "_PALLAS_TRAIN_MAX_PIXELS", 0)
+    x = _rand(2, 16, 16, 3)
+    y = jnp.asarray(RNG.random((2, 16, 16, 1)) > 0.5, jnp.float32)
+    loss_fn = losses_lib.make_loss_fn("bce", 0.5)
+    tx = optax.adam(1e-3)
+    out = {}
+    for impl in ("auto", "flax"):
+        model = build_unet(ModelConfig(
+            base_features=4, compute_dtype=compute_dtype, bilinear=bilinear,
+            conv_impl=impl))
+        state = trainer.create_state(model, tx, jax.random.key(0), 16)
+        before = _dispatch_counts()
+        out[impl] = jax.jit(trainer.core_train_step(model, tx, loss_fn))(
+            state, x, y)
+        after = _dispatch_counts()
+        assert {k: after[k] - before[k] for k in after} == {
+            "pallas": 0, "xla": 18 if impl == "auto" else 0}
+    assert jax.tree.structure(out["auto"]) == jax.tree.structure(out["flax"])
+    for a, b in zip(jax.tree.leaves(out["auto"]), jax.tree.leaves(out["flax"])):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert out["auto"][0].batch_stats and np.isfinite(float(out["auto"][1]))
+
+
 @pytest.mark.slow
 def test_train_step_with_pallas_convs_matches_flax():
     """One full optimizer step on a tiny U-Net: conv_impl="interpret"

@@ -392,6 +392,41 @@ def test_each_epoch_runner_under_each_save_policy_resumes_and_registers(
         of_best.params, of_best.batch_stats))
 
 
+@pytest.mark.parametrize("name", ["seg", "unet-tconv"])
+def test_a_repeated_call_traces_and_counts_no_convolution_again(
+        tmp_path, arrays, name):
+    """``rdp_train_conv_dispatch_total`` is sampled while a step is traced:
+    a first ``train_model`` call under ``conv_impl="auto"`` builds its
+    runners, traces each once and counts the train step's eighteen 3x3
+    convolutions (``xla`` here: this process sees no TPU); the same job
+    resumed gets the runners back (``reused``) and adds no trace and no
+    dispatch sample. Evaluation is ``train=False`` and counts none."""
+    from robotic_discovery_platform_tpu.analysis import recompile
+
+    def counts():
+        out = {r: obs.TRAIN_RUNNERS.labels(family="epoch", result=r).value
+               for r in ("built", "reused")}
+        out["traces"] = sum(obs.JIT_TRACES.labels(fn=g).value for g in (
+            "trainer.train_epoch", "trainer.eval_epoch"))
+        out.update({impl: obs.TRAIN_CONV_DISPATCH.labels(impl=impl).value
+                    for impl in ("pallas", "xla")})
+        return out
+
+    def added(call):
+        before = counts()
+        call()
+        return {k: v - before[k] for k, v in counts().items()}
+
+    trainer._kept_runners.cache_clear()     # what earlier tests kept
+    call = state_job(name, arrays, tmp_path)
+    assert STATE_JOBS[name][0].conv_impl == "auto"
+    with recompile.strict():
+        assert added(lambda: call(1)) == dict(
+            built=1, reused=0, traces=2, pallas=0, xla=18)
+        assert added(lambda: call(2)) == dict(
+            built=0, reused=1, traces=0, pallas=0, xla=0)
+
+
 def test_checkpoint_every_skips_intermediate_saves(tmp_path, arrays):
     """checkpoint_every=2 over 5 epochs saves steps {2, 4, 5}: every second
     epoch plus the final epoch unconditionally."""
