@@ -762,6 +762,16 @@ TRAIN_TOKENS_RATE = REGISTRY.gauge(
     "Tokens consumed by optimiser steps per second over the last epoch's "
     "train phase (token data sets only).",
 )
+ATTN_MASK_TILES = REGISTRY.counter(
+    families.ATTN_MASK_TILES,
+    "Tiles of one head's forward grid that a masked-attention kernel "
+    "(ops/pallas/masked_attention) visits or skips under its rule, one "
+    "sample each time a kernel's grid is built while a program is traced "
+    "(so a memoised runner adds none): kind = blockdiff, causal or window; "
+    "state = visited (some pair live) or skipped (none). A change of tile "
+    "or rule shows here without a device trace.",
+    ("kind", "state"),
+)
 
 _BREAKER_STATE_VALUES = {"closed": 0, "open": 1, "half_open": 2}
 

@@ -15,30 +15,27 @@ the two indices (:func:`live`) and is never read from memory: the kernel
 evaluates it on the tile's ``iota``s, and tiles that it empties are skipped
 in the forward pass and in both backward passes.
 
-The kernel is the splash-attention kernel that ships with JAX
-(``jax.experimental.pallas.ops.tpu.splash_attention``: blockwise, scores
-never materialised, grouped query heads, a custom VJP with a dq and a dkv
-kernel, block-sparse grids built from the mask), given ``M`` as one of its
-computable masks. What is added here: ``M`` itself (in a form that costs
-the kernel seven vector operations an element), padding to the tile, the
-batch, and a dense ``jax.numpy`` form for hosts without a TPU.
+The kernel, its padding, batch and dense form are
+``ops/pallas/masked_attention``'s, which this module hands ``M`` as one of
+its rules (:class:`BlockDiff`; the causal language model's two rules live
+there). What is here: ``M`` itself, in a form that costs the kernel seven
+vector operations an element.
 """
 
 from __future__ import annotations
 
-import functools
+import dataclasses
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
+
+from robotic_discovery_platform_tpu.ops.pallas.masked_attention import (
+    ATTN_RESIDUALS, masked_attention, splash_mask)
+
+__all__ = ["ATTN_RESIDUALS", "TILE", "BlockDiff", "blockdiff_attention",
+           "live", "live_packed", "live_pairs", "packed_rows"]
 
 #: tile edges on the chip: splash's q and kv blocks, forward and backward
 TILE = 1024
-#: ``jax.ad_checkpoint`` name of the kernel's output and row sums, for a
-#: rematerialisation policy that keeps them (``save_only_these_names``):
-#: the backward pass then runs no second forward kernel
-ATTN_RESIDUALS = "blockdiff_attn_residuals"
 
 
 def live(q_ids, kv_ids, seq_len: int, block: int):
@@ -90,84 +87,53 @@ def live_packed(rows, kv_ids, seq_len: int, block: int):
     return own | before
 
 
-def dense(rows, kv_ids, seq_len: int, block: int):
-    """Every pair live: the same kernel with no tile to skip, for the one
-    measurement of what skipping saves (``PERF.md``)."""
-    del rows, seq_len, block
-    return kv_ids >= 0
-
-
-MASKS = {"blockdiff": live_packed, "dense": dense}
-
-
 def live_pairs(seq_len: int, block: int) -> int:
     """Pairs that ``M`` leaves live in one sequence and head."""
     return seq_len * seq_len + seq_len * block
 
 
-def _dense_attention(q, k, v, mask):
-    """[b, h, s, d] x [b, g, s, d]: scores materialised, float32 softmax."""
-    b, h, s, d = q.shape
-    g = k.shape[1]
-    qg = q.reshape(b, g, h // g, s, d)
-    scores = jnp.einsum("bgrqd,bgkd->bgrqk", qg, k,
-                        preferred_element_type=jnp.float32)
-    scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bgrqk,bgkd->bgrqd", probs, v,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(b, h, s, d).astype(q.dtype)
+@dataclasses.dataclass(frozen=True)
+class BlockDiff:
+    """``M`` as a rule of ``masked_attention`` over ``2 * seq_len``
+    positions. ``every_pair`` leaves every pair live: the same kernel with
+    no tile to skip, for the one measurement of what skipping saves
+    (``PERF.md``)."""
+
+    seq_len: int
+    block: int
+    tile: int = TILE
+    every_pair: bool = False
+    kind = "blockdiff"
+
+    def definition(self, q_ids, kv_ids):
+        if self.every_pair:
+            return np.ones(np.broadcast_shapes(q_ids.shape, kv_ids.shape),
+                           bool)
+        return live(q_ids, kv_ids, self.seq_len, self.block)
+
+    def rows(self, padded: int) -> np.ndarray:
+        return packed_rows(padded, self.seq_len, self.block)
+
+    def live(self, rows, kv_ids):
+        if self.every_pair:
+            return kv_ids >= 0
+        return live_packed(rows, kv_ids, self.seq_len, self.block)
+
+    def live_pairs(self, positions: int) -> int:
+        return positions * positions if self.every_pair \
+            else live_pairs(self.seq_len, self.block)
+
+
+def _rule(seq_len: int, block: int, mask: str, tile: int = TILE):
+    if mask not in ("blockdiff", "dense"):
+        raise KeyError(mask)
+    return BlockDiff(seq_len, block, tile, every_pair=mask == "dense")
 
 
 def _splash_mask(padded: int, seq_len: int, block: int, mask: str):
-    """``MASKS[mask]`` as one of splash's computable masks over ``padded``
-    positions, with the row's part of the mask in the row index's place.
-
-    This leans on two internals of the splash kernels of JAX 0.9.0 (the
-    version this is written against; ``tests/test_blockdiff_lm.py`` pins
-    both): ``_ComputableMask`` builds the block map by calling
-    ``mask_function`` on ``q_sequence`` values, and the kernel hands
-    ``q_sequence`` on to ``mask_function`` unchanged. After an upgrade of
-    JAX those tests say whether tiles are still skipped and rows still
-    carry their intervals."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_mask as sm)
-
-    rule = MASKS[mask]
-
-    class _Mask(sm._ComputableMask):
-        def __init__(self):
-            super().__init__(
-                shape=(padded, padded),
-                mask_function=lambda q, kv: rule(q, kv, seq_len, block))
-            # the row's part of the mask in the row index's place
-            self.q_sequence = packed_rows(padded, seq_len, block)
-
-        def __eq__(self, other):
-            return isinstance(other, _Mask)
-
-        def __hash__(self):
-            return hash((_Mask, padded, seq_len, block, mask))
-
-    return _Mask()
-
-
-@functools.lru_cache(maxsize=8)
-def _splash_kernel(heads: int, padded: int, seq_len: int, block: int,
-                   tile: int, mask: str, interpret: bool):
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk, splash_attention_mask as sm)
-
-    sizes = sk.BlockSizes(
-        block_q=tile, block_kv=tile, block_kv_compute=tile,
-        block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=tile,
-        block_q_dq=tile, block_kv_dq=tile)
-    with jax.ensure_compile_time_eval():
-        return sk.make_splash_mha(
-            sm.MultiHeadMask([_splash_mask(padded, seq_len, block, mask)]
-                             * heads), block_sizes=sizes,
-            head_shards=1, q_seq_shards=1, interpret=interpret,
-            residual_checkpoint_name=ATTN_RESIDUALS)
+    """The rule as splash's computable mask (``masked_attention.splash_mask``
+    says which of splash's internals that leans on)."""
+    return splash_mask(padded, _rule(seq_len, block, mask))
 
 
 def blockdiff_attention(q, k, v, *, seq_len: int, block: int,
@@ -177,31 +143,13 @@ def blockdiff_attention(q, k, v, *, seq_len: int, block: int,
 
     ``q`` is ``[batch, heads, 2 * seq_len, head_dim]`` and already scaled by
     ``1 / sqrt(head_dim)``; ``k`` and ``v`` are ``[batch, kv_heads, ...]``,
-    each shared by ``heads // kv_heads`` query heads. ``impl``: ``"pallas"``
-    (the kernel), ``"interpret"`` (the kernel in the Pallas interpreter, for
-    CPU tests), ``"xla"`` (dense scores: small sizes only), ``"auto"`` (the
-    kernel on a TPU, dense elsewhere). ``mask`` names a rule of
-    :data:`MASKS`; ``"dense"`` is for the measurement alone.
+    each shared by ``heads // kv_heads`` query heads. ``impl`` as
+    ``masked_attention``'s. ``mask``: ``"blockdiff"``, or ``"dense"`` for
+    the measurement alone.
     """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    b, h, s, d = q.shape
+    s = q.shape[2]
     if s != 2 * seq_len or seq_len % block:
         raise ValueError(f"{s} positions are not two copies of {seq_len} "
                          f"tokens in blocks of {block}")
-    with jax.named_scope("rdp.attn.blockdiff"):
-        if impl == "xla":
-            ids = np.arange(s)
-            m = (live(ids[:, None], ids[None, :], seq_len, block)
-                 if mask == "blockdiff" else np.ones((s, s), bool))
-            return checkpoint_name(
-                _dense_attention(q, k, v, jnp.asarray(m)), ATTN_RESIDUALS)
-        tile = min(tile, -(-s // 128) * 128)
-        padded = -(-s // tile) * tile
-        if padded != s:
-            pad = ((0, 0), (0, 0), (0, padded - s), (0, 0))
-            q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
-        kernel = _splash_kernel(h, padded, seq_len, block, tile, mask,
-                                impl == "interpret")
-        out = jax.vmap(kernel)(q, k, v)
-        return out[:, :, :s] if padded != s else out
+    return masked_attention(q, k, v, _rule(seq_len, block, mask, tile),
+                            impl=impl)

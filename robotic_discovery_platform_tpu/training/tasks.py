@@ -3,12 +3,14 @@ and a model family.
 
 ``train_model`` owns the job (phases and spans, the kept jitted runners,
 the whole-epoch ``lax.scan``, optax Adam, checkpoints, tracking, registry);
-a task owns what differs between families: how the model is built from its
-configuration, the initial state, how a data set is staged, the loss of a
-batch, the evaluation metrics of a batch, which parameters and metrics are
-logged, and what the registry is handed. A task is found from the type of
-the model configuration (:func:`task_for`) and, for a logged model, from
-the name it wrote beside the weights (:func:`task_named`).
+a task owns what differs between families (the U-Net segmenters and two
+language models, one trained by block diffusion, one by next-token
+prediction): how the model is built from its configuration, the initial
+state, how a data set is staged, the loss of a batch, the evaluation
+metrics of a batch, which parameters and metrics are logged, and what the
+registry is handed. A task is found from the type of the model
+configuration (:func:`task_for`) and, for a logged model, from the name it
+wrote beside the weights (:func:`task_named`).
 
 Every task has:
 
@@ -50,7 +52,7 @@ from robotic_discovery_platform_tpu.models import losses as losses_lib
 from robotic_discovery_platform_tpu.observability import instruments as obs
 from robotic_discovery_platform_tpu.training import data as data_lib
 from robotic_discovery_platform_tpu.utils.config import (
-    BlockDiffLMConfig, ModelConfig, TrainConfig)
+    BlockDiffLMConfig, CausalLMConfig, ModelConfig, TrainConfig)
 
 
 class UNetTask:
@@ -173,16 +175,78 @@ class UNetTask:
         return init_unet(model, jax.random.key(0))
 
 
-class BlockDiffLMTask:
-    """A sparse-expert decoder trained by block diffusion on a resident
-    token data set (``models/blockdiff_lm``): ``xs`` are ``[n, L]`` int32
-    sequences. There are no targets beside them; ``ys`` carries, once a
-    sequence, the seed the job's noise is drawn from. The noise of a step
-    is a function of seed and step count (``data.block_diffusion_noise``),
-    and a seed that reached the step as a constant would make every job's
-    compiled programs its own: as data, one program serves every seed, in
-    this process (the kept runners) and from the compile cache in the
-    next."""
+class _SparseDecoderTask:
+    """What the two language-model tasks share: a sparse-expert decoder as
+    one chip's share of an expert-parallel job (``models/moe``), trained on
+    a resident token data set, ``xs`` ``[n, L]`` int32 sequences; a seeded
+    start a reference can re-derive; the routing's counters."""
+
+    def make_loss(self, cfg: TrainConfig):
+        """Nothing of the configuration shapes the loss (the arithmetic is
+        the model module's)."""
+        return None
+
+    memo_fields = ()
+
+    def for_mesh(self, model_cfg):
+        raise ValueError(
+            f"the {self.name} task trains on one device: experts are not "
+            "exchanged across chips")
+
+    def init_variables(self, model, rng, cfg: TrainConfig):
+        """The job's weights from its seed, by a rule a reference can
+        re-derive: ``model.init`` (``moe.seeded_params``) under one ``jit``
+        on ``jax.random.key(cfg.seed, impl="rbg")``, the device's
+        counter-based generator. (Under the default threefry keys the
+        program that draws a leaf of 1.5e8 elements takes the TPU compiler 9
+        to 15 s, a shape; a job that starts from nothing paid a minute for
+        its weights.)"""
+        del rng
+        return jax.jit(model.init)(
+            jax.random.key(cfg.seed, impl="rbg")), {}
+
+    def file_data(self, cfg: TrainConfig):
+        raise ValueError(f"the {self.name} task trains on an in-memory "
+                         "token data set (arrays=(tokens, None))")
+
+    def _sequences(self, model, tokens):
+        if tokens.shape[1] != model.cfg.seq_len:
+            raise ValueError(f"sequences of {tokens.shape[1]} tokens for a "
+                             f"model of seq_len {model.cfg.seq_len}")
+
+    def _routing(self, sizes) -> dict:
+        """A step's ``aux`` from the rows each layer's held experts took."""
+        sizes = sizes.astype(jnp.float32)       # [layers, experts held]
+        return {"routed_rows": jnp.sum(sizes),
+                "expert_load": jnp.sum(sizes, axis=0)}
+
+    def observe(self, out, n_steps, batch_size, seconds, sample_shape):
+        load = np.asarray(out["expert_load"], np.float64)
+        obs.MOE_ROUTED_ROWS.inc(float(out["routed_rows"]) * n_steps)
+        if load.mean() > 0:
+            obs.MOE_LOAD_RATIO.set(float(load.max() / load.mean()))
+        if seconds > 0:
+            obs.TRAIN_TOKENS_RATE.set(
+                n_steps * batch_size * sample_shape[0] / seconds)
+
+    def variables(self, params, stats) -> dict:
+        del stats
+        return {"params": params}
+
+    def template(self, model):
+        return jax.eval_shape(
+            lambda: {"params": model.init(jax.random.key(0))})
+
+
+class BlockDiffLMTask(_SparseDecoderTask):
+    """A sparse-expert decoder trained by block diffusion
+    (``models/blockdiff_lm``). There are no targets beside the sequences;
+    ``ys`` carries, once a sequence, the seed the job's noise is drawn
+    from. The noise of a step is a function of seed and step count
+    (``data.block_diffusion_noise``), and a seed that reached the step as a
+    constant would make every job's compiled programs its own: as data, one
+    program serves every seed, in this process (the kept runners) and from
+    the compile cache in the next."""
 
     name = "blockdiff_lm"
     config_type = BlockDiffLMConfig
@@ -194,43 +258,13 @@ class BlockDiffLMTask:
 
         return build_blockdiff_lm(model_cfg)
 
-    def make_loss(self, cfg: TrainConfig):
-        """Nothing of the configuration shapes the loss (the arithmetic is
-        ``blockdiff_lm.diffusion_loss``)."""
-        return None
-
-    memo_fields = ()
-
-    def for_mesh(self, model_cfg):
-        raise ValueError(
-            "the block-diffusion task trains on one device: experts are "
-            "not exchanged across chips")
-
-    def init_variables(self, model, rng, cfg: TrainConfig):
-        """The job's weights from its seed, by a rule a reference can
-        re-derive: ``model.init`` (``blockdiff_lm.init_params``) under one
-        ``jit`` on ``jax.random.key(cfg.seed, impl="rbg")``, the device's
-        counter-based generator. (Under the default threefry keys the
-        program that draws a leaf of 1.5e8 elements takes the TPU compiler 9
-        to 15 s, a shape; a job that starts from nothing paid a minute for
-        its weights.)"""
-        del rng
-        return jax.jit(model.init)(
-            jax.random.key(cfg.seed, impl="rbg")), {}
-
-    def file_data(self, cfg: TrainConfig):
-        raise ValueError("the block-diffusion task trains on an in-memory "
-                         "token data set (arrays=(tokens, None))")
-
     def prepare(self, arrays, cfg: TrainConfig):
         tokens = data_lib.token_arrays(arrays[0])
         return tokens, np.full(len(tokens), cfg.seed, np.int32)
 
     def _noised(self, model, seeds, stream, index, tokens):
         c = model.cfg
-        if tokens.shape[1] != c.seq_len:
-            raise ValueError(f"sequences of {tokens.shape[1]} tokens for a "
-                             f"model of seq_len {c.seq_len}")
+        self._sequences(model, tokens)
         # a batch is of one job: every row carries the same seed
         return data_lib.block_diffusion_noise(
             seeds[0], stream, index, tokens.shape[0], c.seq_len,
@@ -248,9 +282,7 @@ class BlockDiffLMTask:
         logits, sizes = model.apply(params, x, masked)
         with jax.named_scope("rdp.loss"):
             loss = diffusion_loss(logits, x, masked, t)
-        sizes = sizes.astype(jnp.float32)       # [layers, experts held]
-        return loss, ({}, {"routed_rows": jnp.sum(sizes),
-                           "expert_load": jnp.sum(sizes, axis=0)})
+        return loss, ({}, self._routing(sizes))
 
     def evaluate(self, model, loss_fn, state, x, y):
         from robotic_discovery_platform_tpu.models.blockdiff_lm import (
@@ -272,27 +304,54 @@ class BlockDiffLMTask:
                 "experts_held": f"{c.experts_held}/{c.num_experts}",
                 "vocab_size": c.vocab_size}
 
-    def observe(self, out, n_steps, batch_size, seconds, sample_shape):
-        load = np.asarray(out["expert_load"], np.float64)
-        obs.MOE_ROUTED_ROWS.inc(float(out["routed_rows"]) * n_steps)
-        if load.mean() > 0:
-            obs.MOE_LOAD_RATIO.set(float(load.max() / load.mean()))
-        if seconds > 0:
-            obs.TRAIN_TOKENS_RATE.set(
-                n_steps * batch_size * sample_shape[0] / seconds)
 
-    def variables(self, params, stats) -> dict:
-        del stats
-        return {"params": params}
+class CausalLMTask(_SparseDecoderTask):
+    """A sparse-expert decoder of window and full attention layers trained
+    by next-token prediction (``models/causal_lm``). The targets are the
+    sequences themselves, shifted by one inside the step, and the step
+    draws no noise: ``ys`` is one zero a sequence, which the job's feed
+    carries and nothing reads."""
 
-    def template(self, model):
-        return jax.eval_shape(
-            lambda: {"params": model.init(jax.random.key(0))})
+    name = "causal_lm"
+    config_type = CausalLMConfig
+    val_logged = ("token_accuracy",)
+
+    def build(self, model_cfg: CausalLMConfig):
+        from robotic_discovery_platform_tpu.models.causal_lm import (
+            build_causal_lm)
+
+        return build_causal_lm(model_cfg)
+
+    def prepare(self, arrays, cfg: TrainConfig):
+        del cfg
+        tokens = data_lib.token_arrays(arrays[0])
+        return tokens, np.zeros(len(tokens), np.int32)
+
+    def train_loss(self, model, loss_fn, params, state, x, y):
+        del loss_fn, state, y
+        self._sequences(model, x)
+        loss, _, sizes = model.loss(params, x)
+        return loss, ({}, self._routing(sizes))
+
+    def evaluate(self, model, loss_fn, state, x, y):
+        del loss_fn, y
+        self._sequences(model, x)
+        loss, accuracy, _ = model.loss(state.params, x, with_hits=True)
+        return {"loss": loss, "token_accuracy": accuracy}
+
+    def run_params(self, cfg: TrainConfig, model_cfg) -> dict:
+        c = model_cfg
+        return {"model": "CausalLM", "loss": "next_token",
+                "seq_len": c.seq_len, "sliding_window": c.sliding_window,
+                "num_layers": c.num_layers, "hidden_size": c.hidden_size,
+                "experts_held": f"{c.experts_held}/{c.num_experts}",
+                "vocab_size": c.vocab_size}
 
 
 UNET = UNetTask()
 BLOCKDIFF_LM = BlockDiffLMTask()
-TASKS = (UNET, BLOCKDIFF_LM)
+CAUSAL_LM = CausalLMTask()
+TASKS = (UNET, BLOCKDIFF_LM, CAUSAL_LM)
 
 
 def task_for(model_cfg):

@@ -664,7 +664,8 @@ def train_model(
         cfg / model_cfg: configuration (defaults = reference constants).
             The type of ``model_cfg`` says what is trained
             (``training/tasks.py``): a ``ModelConfig`` the segmenter, a
-            ``BlockDiffLMConfig`` a block-diffusion language model.
+            ``BlockDiffLMConfig`` a block-diffusion language model, a
+            ``CausalLMConfig`` a causal one of window and full layers.
         arrays: optional in-memory ((xs, ys)) dataset overriding
             ``cfg.dataset_dir`` (tests, synthetic smoke runs); for a token
             task ``(tokens [n, L] int32, None)``.

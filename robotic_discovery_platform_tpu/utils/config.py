@@ -109,6 +109,81 @@ class BlockDiffLMConfig:
 
 
 @dataclass(frozen=True)
+class RotaryConfig:
+    """One rotary table (rotate-half form over the whole head):
+    ``inv_freq_i = theta^(-2i/d)``. With ``factor`` above 1 the table is
+    YaRN's (models/causal_lm.rope_table): the slow frequencies divided by
+    ``factor`` over a ramp set by ``original_max_position``, ``beta_fast``
+    and ``beta_slow``, cos and sin multiplied by ``attention_factor``."""
+
+    theta: float = 1e6
+    factor: float = 1.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+#: the attention kinds a ``CausalLMConfig.layer_types`` entry may name
+ATTENTION_KINDS = ("sliding_attention", "full_attention")
+
+
+@dataclass(frozen=True)
+class CausalLMConfig:
+    """A causal sparse-expert decoder whose layers differ in kind, as one
+    chip's share of an expert-parallel job (models/causal_lm.py):
+    ``layer_types`` names each layer's attention, under the full causal mask
+    or inside a window of ``sliding_window`` keys, and each kind has a
+    rotary table of its own. Widths are a published model's;
+    ``num_layers``, ``experts_held`` and ``vocab_size`` are what this chip
+    holds of it. The defaults are the unit tests' size: two periods of a
+    two-layer pattern, the window shorter than the sequence."""
+
+    vocab_size: int = 64          # rows of the embedding and head held here
+    hidden_size: int = 64
+    num_layers: int = 4
+    num_heads: int = 4
+    num_kv_heads: int = 2         # each shared by num_heads // num_kv_heads
+    head_dim: int = 16
+    num_experts: int = 8          # the router's outputs, all chips' experts
+    experts_per_token: int = 2
+    experts_held: int = 2         # ids first_expert .. first_expert + held - 1
+    first_expert: int = 0
+    expert_width: int = 32
+    rms_norm_eps: float = 1e-6
+    norm_topk_prob: bool = True
+    seq_len: int = 32             # L tokens a sequence
+    # one entry a layer; the model runs the shortest period of the pattern
+    # layer by layer and repeats it
+    layer_types: tuple = ("sliding_attention", "full_attention") * 2
+    sliding_window: int = 8       # a query sees itself and the window - 1 before
+    sliding_rope: RotaryConfig = RotaryConfig(theta=100.0)
+    # at head_dim 16 the ramp runs over frequencies 1 .. 7 of the 8
+    full_rope: RotaryConfig = RotaryConfig(
+        theta=100.0, factor=4.0, original_max_position=64, beta_fast=4.0,
+        beta_slow=0.25, attention_factor=1.1386294361119891)
+    # as BlockDiffLMConfig's
+    moe_chunk_rows: int = 64
+    compute_dtype: str = "bfloat16"  # params stay float32
+    init_std: float = 0.02
+    embed_init_std: float = 0.02
+    kernel_impl: str = "auto"
+
+    def __post_init__(self):
+        # from a JSON document the pattern is a list and the tables dicts
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        for name in ("sliding_rope", "full_rope"):
+            if isinstance(getattr(self, name), dict):
+                object.__setattr__(
+                    self, name, RotaryConfig(**getattr(self, name)))
+        unknown = set(self.layer_types) - set(ATTENTION_KINDS)
+        if unknown or len(self.layer_types) != self.num_layers:
+            raise ValueError(
+                f"layer_types names {self.num_layers} layers, each one of "
+                f"{ATTENTION_KINDS}; got {self.layer_types}")
+
+
+@dataclass(frozen=True)
 class TrainConfig:
     """Reference hyperparameters: scripts/train_segmenter.py:45-50,143-145."""
 
