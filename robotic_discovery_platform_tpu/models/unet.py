@@ -34,9 +34,19 @@ import numpy as np
 from flax import linen as nn
 
 from robotic_discovery_platform_tpu.analysis.contracts import shape_contract
+from robotic_discovery_platform_tpu.observability import instruments as obs
 from robotic_discovery_platform_tpu.utils.config import ModelConfig
 
 DType = Any
+
+
+#: The batch from which :func:`upsample_align_corners` writes its two
+#: products over reshaped operands. Settled on the chip (PERF.md section 5,
+#: PR 36): at batch 8, 16 and 32 that form makes a whole forward pass or
+#: train step 20 to 47% faster, at batch 1, 2 and 4 the einsum form is the
+#: faster by 1 to 5%. Eight is where XLA's TPU convolutions start to keep
+#: the batch in the sublanes of a channel-minor tile.
+_BATCHED_FORM_MIN_BATCH = 8
 
 
 @shape_contract(x="b ih iw c")
@@ -50,8 +60,22 @@ def upsample_align_corners(x, h: int, w: int):
     trained reference checkpoints import with bit-comparable outputs
     (tools/import_torch_weights.py, tests/test_torch_parity.py).
 
-    Implemented as two small dense interpolation matmuls over the static
-    spatial dims -- MXU-friendly, fuses cleanly under jit.
+    Two dense interpolation products over the static spatial dims, the H
+    pass then the W pass: operands in ``x.dtype``, float32 products, a
+    float32 intermediate. How the two are WRITTEN settles more than their
+    own time, because XLA's TPU layout assignment starts from them. As
+    ``"Hh,bhwc->bHwc"`` then ``"Ww,bhwc->bhWc"`` they follow whatever
+    layout a small batch's convolutions want (a 64-channel activation of
+    batch 4 has a spatial dimension minor). From a batch of 8 the
+    convolutions keep channels minor, and that form gave every elementwise
+    operation of the two full-resolution blocks one layout and their
+    convolutions another: a batch-32 256x256 training step copied each
+    64-channel activation of ``inc`` and ``up4`` between the two, 29 of its
+    116 ms. There both passes keep ``(w c)`` resp. ``c`` contiguous as the
+    one free dimension of a plain product, over ``[b, h, (w c)]`` and
+    ``[(b H), w, c]``. On the chip the two forms agree bit for bit, forward
+    and backward. ``rdp_unet_upsample_form_total`` counts the choice;
+    tests/test_unet_layout.py guards the compiled layout.
     """
     b, ih, iw, c = x.shape
 
@@ -68,10 +92,19 @@ def upsample_align_corners(x, h: int, w: int):
         np.add.at(m, (np.arange(out), i1), frac)
         return jnp.asarray(m, x.dtype)
 
-    y = jnp.einsum("Hh,bhwc->bHwc", interp_matrix(h, ih), x,
-                   preferred_element_type=jnp.float32)
-    y = jnp.einsum("Ww,bhwc->bhWc", interp_matrix(w, iw), y,
-                   preferred_element_type=jnp.float32)
+    mh, mw = interp_matrix(h, ih), interp_matrix(w, iw)
+    batched = b >= _BATCHED_FORM_MIN_BATCH
+    obs.UNET_UPSAMPLE_FORM.labels(
+        form="batched" if batched else "einsum").inc()
+    f32 = jnp.float32
+    if batched:
+        y = jnp.einsum("Hh,bhk->bHk", mh, x.reshape(b, ih, iw * c),
+                       preferred_element_type=f32)
+        y = jnp.einsum("Ww,nwc->nWc", mw, y.reshape(b * h, iw, c),
+                       preferred_element_type=f32).reshape(b, h, w, c)
+    else:
+        y = jnp.einsum("Hh,bhwc->bHwc", mh, x, preferred_element_type=f32)
+        y = jnp.einsum("Ww,bhwc->bhWc", mw, y, preferred_element_type=f32)
     return y.astype(x.dtype)
 
 

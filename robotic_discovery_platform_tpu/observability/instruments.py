@@ -745,6 +745,17 @@ TRAIN_CONV_DISPATCH = REGISTRY.counter(
     "plain convolution with JAX's own derivative).",
     ("impl",),
 )
+UNET_UPSAMPLE_FORM = REGISTRY.counter(
+    families.UNET_UPSAMPLE_FORM,
+    "Bilinear up-samplings of the U-Net's decoder by the form "
+    "models/unet.upsample_align_corners wrote its two interpolation "
+    "products in for the batch it was given, one sample per call, that is "
+    "four per trace of a forward pass (a memoised runner or a compiled "
+    "serving bucket adds none): batched = over reshaped operands, from a "
+    "batch of 8, which leaves the activations' layout to the convolutions; "
+    "einsum = over the four-dimensional operand, below it.",
+    ("form",),
+)
 MOE_ROUTED_ROWS = REGISTRY.counter(
     families.MOE_ROUTED_ROWS,
     "Rows the expert layers' held experts took in training steps (one per "

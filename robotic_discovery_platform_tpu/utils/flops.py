@@ -277,8 +277,10 @@ def unet_forward_flops(img_size: int = 256, base: int = 64,
     for skip, feat in zip(skips, feats):
         h2 = h * 2
         if bilinear:
-            # upsample_align_corners: einsum over H then W
-            # [h2,h]x[h,w,c] then [w2,w]x[h2,w,c] with w == h, w2 == h2
+            # upsample_align_corners: the H pass [h2,h]x[h,(w c)], then
+            # the W pass [w2,w]x[w,c] for each of the h2 rows, with
+            # w == h, w2 == h2; the same count in either form it writes
+            # the two products in
             total += 2 * h2 * h * h * x_ch + 2 * h2 * h2 * h * x_ch
         else:
             # 2x2 stride-2 transpose conv: each INPUT pixel spawns four
