@@ -1,8 +1,9 @@
 """A sparse-expert decoder trained by block diffusion, as one chip's share of
-an expert-parallel job: one of the platform's two language-model families
-(the other, ``models/causal_lm``, is trained by next-token prediction and
-mixes two kinds of attention layer; the two share the expert layer, RMSNorm
-and the seeded start, ``models/moe``).
+an expert-parallel job: one of the platform's three language-model families
+(``models/causal_lm`` is trained by next-token prediction and mixes two
+kinds of attention layer; ``models/hybrid_lm`` mixes state-space, attention
+and expert layers of one branch each; the three share the expert layer,
+RMSNorm and the seeded start, ``models/moe``).
 
 The layer equations (``BlockDiffLMConfig``; RMSNorm ``eps``, no biases)::
 
@@ -15,7 +16,8 @@ The layer equations (``BlockDiffLMConfig``; RMSNorm ``eps``, no biases)::
     the experts_per_token largest, renormalised to sum 1 (norm_topk_prob)
     x += sum_e p_e Wdown_e( silu(h Wgate_e) * (h Wup_e) )
 
-then a final RMSNorm and an untied head. Parameters are float32; matrix
+(the expert layer in its default form; ``models/moe`` has the others) then
+a final RMSNorm and an untied head. Parameters are float32; matrix
 products and activations run in ``compute_dtype``; router logits, softmax,
 RMSNorm statistics, attention's softmax and the loss in float32.
 
@@ -46,7 +48,8 @@ import jax
 import jax.numpy as jnp
 
 from robotic_discovery_platform_tpu.models.moe import (  # noqa: F401
-    expert_layer, rms_norm, route, routed_experts, seeded_params)
+    expert_layer, expert_shapes, rms_norm, route, routed_experts,
+    seeded_params)
 from robotic_discovery_platform_tpu.ops.pallas.blockdiff_attention import (
     ATTN_RESIDUALS, blockdiff_attention)
 from robotic_discovery_platform_tpu.utils.config import BlockDiffLMConfig
@@ -56,16 +59,14 @@ def param_shapes(cfg: BlockDiffLMConfig) -> dict:
     """name -> shape; the layers' leaves carry the depth in front."""
     n, h, d = cfg.num_layers, cfg.hidden_size, cfg.head_dim
     q, kv = cfg.num_heads * d, cfg.num_kv_heads * d
-    e, f = cfg.experts_held, cfg.expert_width
     return {
         "embed": (cfg.vocab_size, h),
         "layers/attn_norm": (n, h), "layers/wq": (n, h, q),
         "layers/wk": (n, h, kv), "layers/wv": (n, h, kv),
         "layers/q_norm": (n, d), "layers/k_norm": (n, d),
         "layers/wo": (n, q, h), "layers/moe_norm": (n, h),
-        "layers/router": (n, h, cfg.num_experts),
-        "layers/w_gate": (n, e, h, f), "layers/w_up": (n, e, h, f),
-        "layers/w_down": (n, e, f, h),
+        **{f"layers/{name}": (n, *shape)
+           for name, shape in expert_shapes(cfg).items()},
         "final_norm": (h,), "head": (h, cfg.vocab_size),
     }
 

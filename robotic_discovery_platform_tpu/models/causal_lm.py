@@ -1,8 +1,11 @@
-"""A causal sparse-expert decoder whose layers differ in kind, as one chip's
-share of an expert-parallel job: the second of the platform's two
-language-model families (the first, ``models/blockdiff_lm``, stacks
-identical layers and is trained by block diffusion; the two share the
-expert layer, RMSNorm and the seeded start, ``models/moe``).
+"""A causal sparse-expert decoder whose attention layers differ in kind, as
+one chip's share of an expert-parallel job: the second of the platform's
+three language-model families (the first, ``models/blockdiff_lm``, stacks
+identical layers and is trained by block diffusion; the third,
+``models/hybrid_lm``, mixes state-space, attention and expert layers of one
+residual branch each and takes this module's head and loss; the three share
+the expert layer in the form their configuration gives it, RMSNorm and the
+seeded start, ``models/moe``).
 
 One layer (``CausalLMConfig``; RMSNorm ``eps``, no biases, no q/k norm)::
 
@@ -16,7 +19,10 @@ One layer (``CausalLMConfig``; RMSNorm ``eps``, no biases, no q/k norm)::
     the experts_per_token largest, renormalised to sum 1 (norm_topk_prob)
     x += sum_e p_e Wdown_e( silu(h Wgate_e) * (h Wup_e) )
 
-then a final RMSNorm and an untied head; the loss is the mean cross-entropy
+(the expert layer in its default form: a softmax router and gated SiLU
+experts; ``models/moe`` has the others, and a configuration that asks for
+one gets its leaves from ``moe.expert_shapes``) then a final RMSNorm and an
+untied head; the loss is the mean cross-entropy
 of position ``i``'s logits against token ``i + 1`` over positions
 ``0 .. L - 2``. Parameters are float32; matrix products and activations run
 in ``compute_dtype``; the rotary tables and their application, router
@@ -54,7 +60,7 @@ import jax
 import jax.numpy as jnp
 
 from robotic_discovery_platform_tpu.models.moe import (
-    expert_layer, rms_norm, seeded_params)
+    expert_layer, expert_shapes, rms_norm, seeded_params)
 from robotic_discovery_platform_tpu.ops.pallas.masked_attention import (
     ATTN_RESIDUALS, Causal, Window, masked_attention)
 from robotic_discovery_platform_tpu.utils.config import (
@@ -62,8 +68,6 @@ from robotic_discovery_platform_tpu.utils.config import (
 
 #: positions of a sequence whose logits are alive at a time
 HEAD_CHUNK = 2048
-_LAYER_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo", "moe_norm", "router",
-                 "w_gate", "w_up", "w_down")
 
 
 def period(layer_types: tuple) -> int:
@@ -80,10 +84,10 @@ def param_shapes(cfg: CausalLMConfig) -> dict:
     p = period(cfg.layer_types)
     r, h, d = cfg.num_layers // p, cfg.hidden_size, cfg.head_dim
     q, kv = cfg.num_heads * d, cfg.num_kv_heads * d
-    e, f = cfg.experts_held, cfg.expert_width
-    layer = dict(zip(_LAYER_LEAVES, (
-        (r, h), (r, h, q), (r, h, kv), (r, h, kv), (r, q, h), (r, h),
-        (r, h, cfg.num_experts), (r, e, h, f), (r, e, h, f), (r, e, f, h))))
+    layer = {"attn_norm": (r, h), "wq": (r, h, q), "wk": (r, h, kv),
+             "wv": (r, h, kv), "wo": (r, q, h), "moe_norm": (r, h),
+             **{name: (r, *shape)
+                for name, shape in expert_shapes(cfg).items()}}
     return {"embed": (cfg.vocab_size, h),
             **{f"layers/{j}/{name}": shape for j in range(p)
                for name, shape in layer.items()},
@@ -213,7 +217,9 @@ def hidden_states(cfg: CausalLMConfig, params: dict, tokens,
     return x, sizes.reshape(cfg.num_layers, cfg.experts_held)
 
 
-def _head(cfg: CausalLMConfig, params: dict, x):
+def head_logits(cfg, params: dict, x):
+    """The final norm and the head on a stream ``x``: float32 logits over
+    the vocabulary slice (``cfg`` gives ``rms_norm_eps``)."""
     with jax.named_scope("rdp.lm.head"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         return jnp.dot(x, params["head"].astype(x.dtype),
@@ -225,7 +231,7 @@ def forward(cfg: CausalLMConfig, params: dict, tokens,
     """Logits ``[batch, L, vocab]`` (float32) of every position, whole, and
     the rows each held expert took: for sizes at which they fit."""
     x, sizes = hidden_states(cfg, params, tokens, impl)
-    return _head(cfg, params, x), sizes
+    return head_logits(cfg, params, x), sizes
 
 
 def next_token_loss(cfg: CausalLMConfig, params: dict, x, tokens,
@@ -246,7 +252,7 @@ def next_token_loss(cfg: CausalLMConfig, params: dict, x, tokens,
 
     @jax.checkpoint
     def one(head_params, x, targets, weight):
-        logits = _head(cfg, head_params, x)
+        logits = head_logits(cfg, head_params, x)
         with jax.named_scope("rdp.loss"):
             picked = jnp.take_along_axis(logits, targets[..., None], -1)
             nll = jax.nn.logsumexp(logits, axis=-1) - picked[..., 0]

@@ -783,6 +783,14 @@ ATTN_MASK_TILES = REGISTRY.counter(
     "or rule shows here without a device trace.",
     ("kind", "state"),
 )
+SSM_SCAN_CHUNKS = REGISTRY.counter(
+    families.SSM_SCAN_CHUNKS,
+    "Chunks a state-space scan (ops/ssm_scan) cuts one sequence into, one "
+    "sample each time a scan's program is traced (so a memoised runner adds "
+    "none): kind = the implementation (xla). A change of chunk or "
+    "implementation shows here without a device trace.",
+    ("kind",),
+)
 
 _BREAKER_STATE_VALUES = {"closed": 0, "open": 1, "half_open": 2}
 

@@ -42,7 +42,12 @@ def grouped_matmul(lhs, rhs, group_sizes, *, impl: str = "auto",
     tile_rows = min(TILE_ROWS, rows)
     if rows % tile_rows:
         raise ValueError(f"{rows} rows are no multiple of {tile_rows}")
+    tile_k, tile_n = min(TILE_K, k), min(TILE_N, n)
+    if tile_k * tile_n == TILE_K * TILE_N:
+        # both full: the backward pass's transposed product (the same
+        # tiles) then holds a float32 tile of the cotangent and its
+        # float32 sum beside the operands, 16.1 MB of the 16 a kernel has
+        tile_n //= 2
     return megablox.gmm(
-        lhs, rhs, group_sizes, out_dtype,
-        (tile_rows, min(TILE_K, k), min(TILE_N, n)),
+        lhs, rhs, group_sizes, out_dtype, (tile_rows, tile_k, tile_n),
         None, None, False, impl == "interpret")

@@ -185,7 +185,11 @@ def save_model(variables, model_cfg, path: Path) -> None:
         json.dumps(dataclasses.asdict(model_cfg), indent=2)
     )
     (path / _MODEL_FAMILY_FILE).write_text(tasks.task_for(model_cfg).name)
-    if checkpoint.tree_bytes(variables) > _LEAF_FILES_ABOVE:
+    if checkpoint.are_leaf_files(variables):
+        # the weights as a streamed checkpoint's files (the best candidate
+        # of a state too large to hold twice): linked, not copied
+        checkpoint.link_leaves(path / _MODEL_LEAVES_DIR, variables)
+    elif checkpoint.tree_bytes(variables) > _LEAF_FILES_ABOVE:
         checkpoint.write_leaves(path / _MODEL_LEAVES_DIR, variables)
     else:
         (path / _MODEL_WEIGHTS_FILE).write_bytes(

@@ -43,7 +43,7 @@ def _resolve_uri(uri: str) -> Path:
     return Path(uri)
 
 
-def _link_or_copy(src, dst):
+def link_or_copy(src, dst):
     try:
         os.link(src, dst)
     except OSError:
@@ -172,7 +172,7 @@ class FileStore:
                 # hard links where the registry shares the artifacts' file
                 # system (a version's files are written once): registering
                 # gigabytes of weights then costs no second copy of them
-                shutil.copytree(source_dir, dest, copy_function=_link_or_copy)
+                shutil.copytree(source_dir, dest, copy_function=link_or_copy)
             versions.append(
                 {
                     "version": version,

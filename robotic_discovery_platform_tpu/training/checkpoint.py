@@ -39,6 +39,10 @@ decision of the trainer by the state's size,
   holds a second copy. ``best`` marks the step whose checkpoint holds the
   best candidate; pruning keeps it, and ``restore_streamed(step=best)``
   with a template of the parameters alone reads just those leaves.
+  ``streamed_files(template, step)`` names those leaves' files instead: a
+  leaf file is written once and never changed, so the registry's artifact
+  of the best candidate is hard links to them (:func:`link_leaves`), not a
+  second copy read back and written again.
 
 Which of them a job takes, and what it keeps as the best candidate, is a
 **save policy**, chosen once a ``train_model`` call: :class:`DeviceSnapshotSaves`
@@ -113,6 +117,42 @@ def write_leaves(directory: Path, tree) -> None:
     with ThreadPoolExecutor(4) as pool:
         list(pool.map(
             lambda key: np.save(directory / names[key], files[key]), files))
+    (directory / _MANIFEST).write_text(json.dumps(names))
+
+
+def leaf_files(directory: Path, template):
+    """``template``'s tree (any sub-tree of what :func:`write_leaves` wrote,
+    under the same keys) with the path of each leaf's file as the leaf."""
+    names = json.loads((directory / _MANIFEST).read_text())
+
+    def find(path, _):
+        key = jax.tree_util.keystr(path)
+        if key not in names:
+            raise KeyError(f"{directory} holds no leaf {key}")
+        return directory / names[key]
+
+    return jax.tree_util.tree_map_with_path(find, template)
+
+
+def are_leaf_files(tree) -> bool:
+    """Whether ``tree`` holds files' paths (:func:`leaf_files`) and not
+    arrays."""
+    leaves = jax.tree.leaves(tree)
+    return bool(leaves) and all(isinstance(leaf, Path) for leaf in leaves)
+
+
+def link_leaves(directory: Path, files) -> None:
+    """What :func:`write_leaves` would write for the arrays that the tree
+    ``files`` names by their files (:func:`leaf_files`), as hard links to
+    those files: no byte of a leaf is read or written. A leaf on another
+    file system is copied."""
+    from robotic_discovery_platform_tpu.tracking.store import link_or_copy
+
+    directory.mkdir(parents=True)
+    flat = _leaf_files(files)
+    names = {key: f"{i:05d}.npy" for i, key in enumerate(flat)}
+    for key, source in flat.items():
+        link_or_copy(source, directory / names[key])
     (directory / _MANIFEST).write_text(json.dumps(names))
 
 
@@ -205,11 +245,11 @@ class CheckpointManager:
         return json.loads(path.read_text())["step"] if path.is_file() \
             else None
 
-    def save_streamed(self, step: int, state: Any, best: bool = False):
+    def save_streamed(self, step: int, state: Any,
+                      best: bool = False) -> None:
         """Fetch ``state`` to the host now, piece by piece, and write it in
         the background; ``best`` marks this step as the best candidate's.
-        Returns the host copy (the worker only reads it). Single-process
-        only, as ``save_async``."""
+        Single-process only, as ``save_async``."""
         self.wait()
         with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.fetch"):
             host = fetch_streamed(state)
@@ -238,7 +278,16 @@ class CheckpointManager:
             target=work, name="checkpoint-save", daemon=True
         )
         self._pending.start()
-        return host
+
+    def _streamed_dir(self, step: int | None) -> Path:
+        """The directory of the streamed checkpoint of ``step`` (the
+        latest), once no save is in flight."""
+        self.wait()
+        steps = self._streamed_steps()
+        step = (steps[-1] if steps else None) if step is None else step
+        if step is None or step not in steps:
+            raise FileNotFoundError(f"no streamed checkpoint {step}")
+        return self._dir / _STREAMED / str(step)
 
     def restore_streamed(self, template: Any, step: int | None = None,
                          place=None) -> Any:
@@ -247,12 +296,13 @@ class CheckpointManager:
         checkpoint, each mapped from its file and handed to ``place`` (the
         device placement; ``None`` leaves numpy arrays) before the next is
         read."""
-        self.wait()
-        steps = self._streamed_steps()
-        step = (steps[-1] if steps else None) if step is None else step
-        if step is None or step not in steps:
-            raise FileNotFoundError(f"no streamed checkpoint {step}")
-        return read_leaves(self._dir / _STREAMED / str(step), template, place)
+        return read_leaves(self._streamed_dir(step), template, place)
+
+    def streamed_files(self, template: Any, step: int | None = None) -> Any:
+        """``template``'s tree with each leaf the path of its file in the
+        streamed checkpoint of ``step`` (:func:`leaf_files`); a save in
+        flight lands first."""
+        return leaf_files(self._streamed_dir(step), template)
 
     def latest_step(self) -> int | None:
         self.wait()
@@ -436,14 +486,15 @@ class StreamedSaves(_Saves):
     host leaf by leaf before the next donated step and a background worker
     writes it while that step runs; a restore places leaf after leaf, so
     neither side ever holds a second copy; and only a saved epoch can be
-    best: ``best_step`` names the checkpoint that holds the candidate.
-    ``shapes`` are the state's ``ShapeDtypeStruct``s. One process only."""
+    best: ``best_step`` names the checkpoint that holds the candidate, and
+    the candidate is handed on as that checkpoint's files, so registering
+    it moves no byte of it. ``shapes`` are the state's
+    ``ShapeDtypeStruct``s. One process only."""
 
     def __init__(self, ckpt, scalarize, shapes):
         super().__init__(ckpt, scalarize)
         self._shapes = shapes
         self._best_step = ckpt.best_step()
-        self._best_host = None  # the best step's parameters, if fetched here
 
     def restore(self, like):
         return self._ckpt.restore_streamed(
@@ -461,22 +512,18 @@ class StreamedSaves(_Saves):
         with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.wait"):
             self._ckpt.wait()
         with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.snapshot"):
-            host = self._ckpt.save_streamed(
-                epoch + 1, {"state": state}, best=improved)["state"]
-            if improved:
-                self._best_host = (host.params, host.batch_stats)
+            self._ckpt.save_streamed(
+                epoch + 1, {"state": state}, best=improved)
         return state
 
     def best(self):
-        """The parameters this call fetched for its best save, or those of
-        the checkpoint that holds the best of an earlier call."""
+        """``(params, statistics)`` of the best candidate as the files of
+        the checkpoint that holds it (:func:`leaf_files`; the write of this
+        call's own save lands first), or ``None``."""
         if self._best_step is None:
             return None
-        if self._best_host is None:
-            self._ckpt.wait()
-            best = self._ckpt.restore_streamed(
-                {"state": self._shapes.replace(
-                    opt_state=None, epoch=None, best_val_loss=None)},
-                step=self._best_step)["state"]
-            self._best_host = (best.params, best.batch_stats)
-        return self._best_host
+        best = self._ckpt.streamed_files(
+            {"state": self._shapes.replace(
+                opt_state=None, epoch=None, best_val_loss=None)},
+            step=self._best_step)["state"]
+        return best.params, best.batch_stats

@@ -3,8 +3,8 @@ and a model family.
 
 ``train_model`` owns the job (phases and spans, the kept jitted runners,
 the whole-epoch ``lax.scan``, optax Adam, checkpoints, tracking, registry);
-a task owns what differs between families (the U-Net segmenters and two
-language models, one trained by block diffusion, one by next-token
+a task owns what differs between families (the U-Net segmenters and three
+language models, one trained by block diffusion, two by next-token
 prediction): how the model is built from its configuration, the initial
 state, how a data set is staged, the loss of a batch, the evaluation
 metrics of a batch, which parameters and metrics are logged, and what the
@@ -52,7 +52,8 @@ from robotic_discovery_platform_tpu.models import losses as losses_lib
 from robotic_discovery_platform_tpu.observability import instruments as obs
 from robotic_discovery_platform_tpu.training import data as data_lib
 from robotic_discovery_platform_tpu.utils.config import (
-    BlockDiffLMConfig, CausalLMConfig, ModelConfig, TrainConfig)
+    HYBRID_KINDS, BlockDiffLMConfig, CausalLMConfig, HybridLMConfig,
+    ModelConfig, TrainConfig)
 
 
 class UNetTask:
@@ -176,7 +177,7 @@ class UNetTask:
 
 
 class _SparseDecoderTask:
-    """What the two language-model tasks share: a sparse-expert decoder as
+    """What the language-model tasks share: a sparse-expert decoder as
     one chip's share of an expert-parallel job (``models/moe``), trained on
     a resident token data set, ``xs`` ``[n, L]`` int32 sequences; a seeded
     start a reference can re-derive; the routing's counters."""
@@ -348,10 +349,38 @@ class CausalLMTask(_SparseDecoderTask):
                 "vocab_size": c.vocab_size}
 
 
+class HybridLMTask(CausalLMTask):
+    """A decoder of state-space, attention and expert layers of one branch
+    each (``models/hybrid_lm``), trained by next-token prediction as
+    :class:`CausalLMTask` trains its own: the model object answers the same
+    ``loss``, and the rows counted are the expert layers'."""
+
+    name = "hybrid_lm"
+    config_type = HybridLMConfig
+
+    def build(self, model_cfg: HybridLMConfig):
+        from robotic_discovery_platform_tpu.models.hybrid_lm import (
+            build_hybrid_lm)
+
+        return build_hybrid_lm(model_cfg)
+
+    def run_params(self, cfg: TrainConfig, model_cfg) -> dict:
+        c = model_cfg
+        letters = {kind: letter for letter, kind in HYBRID_KINDS.items()}
+        return {"model": "HybridLM", "loss": "next_token",
+                "seq_len": c.seq_len,
+                "layer_pattern": "".join(
+                    letters[kind] for kind in c.layer_pattern),
+                "num_layers": c.num_layers, "hidden_size": c.hidden_size,
+                "experts_held": f"{c.experts_held}/{c.num_experts}",
+                "vocab_size": c.vocab_size}
+
+
 UNET = UNetTask()
 BLOCKDIFF_LM = BlockDiffLMTask()
 CAUSAL_LM = CausalLMTask()
-TASKS = (UNET, BLOCKDIFF_LM, CAUSAL_LM)
+HYBRID_LM = HybridLMTask()
+TASKS = (UNET, BLOCKDIFF_LM, CAUSAL_LM, HYBRID_LM)
 
 
 def task_for(model_cfg):

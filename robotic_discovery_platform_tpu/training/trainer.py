@@ -665,7 +665,9 @@ def train_model(
             The type of ``model_cfg`` says what is trained
             (``training/tasks.py``): a ``ModelConfig`` the segmenter, a
             ``BlockDiffLMConfig`` a block-diffusion language model, a
-            ``CausalLMConfig`` a causal one of window and full layers.
+            ``CausalLMConfig`` a causal one of window and full layers, a
+            ``HybridLMConfig`` a causal one of state-space, attention and
+            expert layers.
         arrays: optional in-memory ((xs, ys)) dataset overriding
             ``cfg.dataset_dir`` (tests, synthetic smoke runs); for a token
             task ``(tokens [n, L] int32, None)``.

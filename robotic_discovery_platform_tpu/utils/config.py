@@ -87,6 +87,17 @@ class BlockDiffLMConfig:
     rms_norm_eps: float = 1e-6
     rope_theta: float = 1e6
     norm_topk_prob: bool = True
+    # the expert layer's form (models/moe.py), the same four fields on
+    # every sparse-expert configuration: how the router scores ("softmax",
+    # or "sigmoid": scores picked with a bias that takes no gradient,
+    # renormalised, times routed_scaling_factor), what an expert computes
+    # ("swiglu": Wdown(silu(x Wgate) * (x Wup)); "relu2": Wdown
+    # relu(x Wup)^2) and the width of a shared expert every token passes
+    # through (0 = none)
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    expert_act: str = "swiglu"
+    shared_expert_width: int = 0
     seq_len: int = 32             # L tokens; the model sees 2 L positions
     block_length: int = 4
     mask_token_id: int = 63       # inside the slice; no data token takes it
@@ -106,6 +117,9 @@ class BlockDiffLMConfig:
     # the experts' grouped product) on a TPU, dense jax.numpy elsewhere;
     # "pallas" / "interpret" / "xla" pin one for tests.
     kernel_impl: str = "auto"
+
+    def __post_init__(self):
+        _check_expert_form(self)
 
 
 @dataclass(frozen=True)
@@ -152,6 +166,17 @@ class CausalLMConfig:
     expert_width: int = 32
     rms_norm_eps: float = 1e-6
     norm_topk_prob: bool = True
+    # the expert layer's form (models/moe.py), the same four fields on
+    # every sparse-expert configuration: how the router scores ("softmax",
+    # or "sigmoid": scores picked with a bias that takes no gradient,
+    # renormalised, times routed_scaling_factor), what an expert computes
+    # ("swiglu": Wdown(silu(x Wgate) * (x Wup)); "relu2": Wdown
+    # relu(x Wup)^2) and the width of a shared expert every token passes
+    # through (0 = none)
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    expert_act: str = "swiglu"
+    shared_expert_width: int = 0
     seq_len: int = 32             # L tokens a sequence
     # one entry a layer; the model runs the shortest period of the pattern
     # layer by layer and repeats it
@@ -181,6 +206,107 @@ class CausalLMConfig:
             raise ValueError(
                 f"layer_types names {self.num_layers} layers, each one of "
                 f"{ATTENTION_KINDS}; got {self.layer_types}")
+        _check_expert_form(self)
+
+
+#: the layer kinds a ``HybridLMConfig.layer_pattern`` entry may name, and
+#: the letters a published ``hybrid_override_pattern`` writes them with
+HYBRID_KINDS = {"M": "mamba", "*": "attention", "E": "experts"}
+ROUTER_SCORINGS = ("softmax", "sigmoid")
+EXPERT_ACTS = ("swiglu", "relu2")
+
+
+@dataclass(frozen=True)
+class HybridLMConfig:
+    """A causal decoder whose layers are one residual branch each, a
+    Mamba-2 mixer, grouped-query attention or a sparse-expert layer with a
+    shared expert, as one chip's share of an expert-parallel job
+    (models/hybrid_lm.py). ``layer_pattern`` names each layer's kind (a
+    tuple of ``HYBRID_KINDS`` values, or a string of their letters as a
+    published ``hybrid_override_pattern`` has them). Widths are a published
+    model's; ``num_layers``, ``experts_held`` and ``vocab_size`` are what
+    this chip holds of it. The defaults are the unit tests' size: two
+    periods of a four-layer pattern, four chunks a sequence, two groups."""
+
+    vocab_size: int = 64          # rows of the embedding and head held here
+    hidden_size: int = 64
+    num_layers: int = 8
+    layer_pattern: tuple = "ME*E" * 2
+    rms_norm_eps: float = 1e-5
+    seq_len: int = 32             # L tokens a sequence
+    # the mixer: mamba_heads x mamba_head_dim inner channels; B and C are
+    # shared by the mamba_heads // ssm_groups heads of a group
+    mamba_heads: int = 4
+    mamba_head_dim: int = 16
+    ssm_groups: int = 2
+    ssm_state: int = 16
+    conv_kernel: int = 4
+    ssm_chunk: int = 8            # positions a chunk of the scan
+    # where the seeded start draws the mixer's step dt = softplus(dt_bias)
+    # (log-uniform on [min, max], floored) and A = -a, a uniform on a_range
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    a_range: tuple = (1.0, 16.0)
+    # attention: no rotary embedding
+    num_heads: int = 4
+    num_kv_heads: int = 2         # each shared by num_heads // num_kv_heads
+    head_dim: int = 16
+    # the expert layer (models/moe.py)
+    num_experts: int = 8          # the router's outputs, all chips' experts
+    experts_per_token: int = 2
+    experts_held: int = 2         # ids first_expert .. first_expert + held - 1
+    first_expert: int = 0
+    expert_width: int = 32
+    norm_topk_prob: bool = True
+    router_scoring: str = "sigmoid"
+    routed_scaling_factor: float = 2.5
+    expert_act: str = "relu2"
+    shared_expert_width: int = 64
+    # as BlockDiffLMConfig's
+    moe_chunk_rows: int = 64
+    compute_dtype: str = "bfloat16"  # params stay float32
+    init_std: float = 0.02
+    embed_init_std: float = 0.02
+    kernel_impl: str = "auto"
+
+    def __post_init__(self):
+        pattern = self.layer_pattern
+        if isinstance(pattern, str):
+            unknown = set(pattern) - set(HYBRID_KINDS)
+            if unknown:
+                raise ValueError(f"layer_pattern letters {sorted(unknown)} "
+                                 f"are none of {sorted(HYBRID_KINDS)}")
+            pattern = [HYBRID_KINDS[letter] for letter in pattern]
+        object.__setattr__(self, "layer_pattern", tuple(pattern))
+        object.__setattr__(self, "a_range", tuple(self.a_range))
+        unknown = set(self.layer_pattern) - set(HYBRID_KINDS.values())
+        if unknown or len(self.layer_pattern) != self.num_layers:
+            raise ValueError(
+                f"layer_pattern names {self.num_layers} layers, each one of "
+                f"{tuple(HYBRID_KINDS.values())}; got {self.layer_pattern}")
+        if self.mamba_heads % self.ssm_groups:
+            raise ValueError(f"{self.mamba_heads} mixer heads in "
+                             f"{self.ssm_groups} groups")
+        _check_expert_form(self)
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """The channels the convolution runs over: xs, B and C."""
+        return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state
+
+
+def _check_expert_form(cfg) -> None:
+    if (cfg.router_scoring not in ROUTER_SCORINGS
+            or cfg.expert_act not in EXPERT_ACTS):
+        raise ValueError(
+            f"router_scoring is one of {ROUTER_SCORINGS} and expert_act one "
+            f"of {EXPERT_ACTS}; got {cfg.router_scoring!r}, "
+            f"{cfg.expert_act!r}")
 
 
 @dataclass(frozen=True)

@@ -430,8 +430,7 @@ def test_a_streamed_save_and_restore_round_trips_leaf_by_leaf(
     monkeypatch.setattr(checkpoint_lib, "STREAM_PIECE_BYTES", 16)
     ckpt = checkpoint_lib.CheckpointManager(tmp_path, keep=1)
     for step, best in ((1, True), (2, False), (3, False)):
-        host = ckpt.save_streamed(step, {"state": _state(step)}, best=best)
-        assert isinstance(host["state"].params["a"], np.ndarray)
+        ckpt.save_streamed(step, {"state": _state(step)}, best=best)
     assert ckpt.latest_step() == 3 and ckpt.best_step() == 1
     # keep=1 beside the best: step 2 is pruned
     assert sorted(p.name for p in (tmp_path / "streamed").iterdir()
@@ -458,6 +457,48 @@ def test_a_streamed_save_and_restore_round_trips_leaf_by_leaf(
             epoch=jax.ShapeDtypeStruct((2,), jnp.int32))})
     ckpt.close()
     assert checkpoint_lib.tree_bytes(abstract) == 4 * (3 * (12 + 5) + 3)
+
+
+@pytest.mark.parametrize("linked", [True, False],
+                         ids=["one file system", "another file system"])
+def test_a_saved_candidate_becomes_an_artifact_without_a_second_copy(
+        tmp_path, monkeypatch, linked):
+    """``streamed_files`` names a saved sub-tree's leaves by their files
+    and ``link_leaves`` makes of them what ``write_leaves`` would have
+    written: hard links where the file system allows, copies where not,
+    which outlive the checkpoint either way."""
+    import os
+
+    if not linked:
+        def refuse(src, dst):
+            raise OSError("cross-device link")
+        monkeypatch.setattr(os, "link", refuse)
+    ckpt = checkpoint_lib.CheckpointManager(tmp_path / "ckpt", keep=1)
+    ckpt.save_streamed(1, {"state": _state(1)}, best=True)
+    abstract = jax.eval_shape(lambda: _state(0.0))
+    only = abstract.replace(opt_state=None, epoch=None, best_val_loss=None)
+    # asked for while the save is in flight: it lands first
+    files = ckpt.streamed_files({"state": only}, step=1)["state"]
+    assert checkpoint_lib.are_leaf_files(files.params)
+    assert not checkpoint_lib.are_leaf_files(_state(1).params)
+    assert not checkpoint_lib.are_leaf_files({})
+    variables = {"params": files.params}
+    checkpoint_lib.link_leaves(tmp_path / "artifact", variables)
+    made = tmp_path / "artifact" / "00000.npy"
+    assert made.samefile(files.params["a"]) is linked
+    with pytest.raises(KeyError, match="holds no leaf"):
+        ckpt.streamed_files({"state": only.replace(
+            params={"z": only.params["a"]})}, step=1)
+    with pytest.raises(FileNotFoundError):
+        ckpt.streamed_files({"state": only}, step=2)
+    ckpt.close()
+    # the checkpoint goes, the artifact stays whole
+    import shutil
+    shutil.rmtree(tmp_path / "ckpt")
+    got = checkpoint_lib.read_leaves(
+        tmp_path / "artifact", {"params": only.params})
+    jax.tree.map(np.testing.assert_array_equal, got,
+                 {"params": _state(1).params})
 
 
 # -- the task through train_model ---------------------------------------------

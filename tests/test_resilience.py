@@ -318,8 +318,10 @@ _DEPTH = np.zeros((8, 8), np.uint16)
 _K = np.eye(3, dtype=np.float32)
 
 
-def _blocking_analyze(release: threading.Event):
+def _blocking_analyze(release: threading.Event, entered=None):
     def analyze(frames, depths, intr, scales):
+        if entered is not None:
+            entered.set()
         release.wait(30.0)
         return {"coverage": np.full((len(frames),), 1.0)}
 
@@ -327,8 +329,8 @@ def _blocking_analyze(release: threading.Event):
 
 
 def test_dispatcher_sheds_load_at_backlog_cap():
-    release = threading.Event()
-    d = BatchDispatcher(_blocking_analyze(release), window_ms=1.0,
+    release, entered = threading.Event(), threading.Event()
+    d = BatchDispatcher(_blocking_analyze(release, entered), window_ms=1.0,
                         max_batch=1, max_backlog=1, submit_timeout_s=30.0)
     try:
         threads = []
@@ -343,9 +345,9 @@ def test_dispatcher_sheds_load_at_backlog_cap():
         # first frame: picked up by the collector, blocks in analyze
         threads.append(threading.Thread(target=bg_submit))
         threads[0].start()
-        deadline = time.monotonic() + 10
-        while d._q.qsize() > 0 and time.monotonic() < deadline:
-            time.sleep(0.005)  # collector must pop it first
+        # the collector must have popped it first: an empty queue alone
+        # says nothing while the thread has yet to put its frame there
+        assert entered.wait(10)
         # second frame: queued (backlog 1 == cap reached)
         threads.append(threading.Thread(target=bg_submit))
         threads[1].start()

@@ -250,8 +250,13 @@ def resumed(request, arrays, tmp_path_factory):
     phases = {p: obs.TRAIN_PHASE.labels(phase=p).count for p in JOB_PHASES}
     with watched(name, model_cfg) as out["idle_seen"]:
         out["idle"] = call(2, register=True)
-        _, out["registered"] = tracking.load_model(
-            f"models:/{TrainConfig().registered_model_name}/latest")
+        latest = f"models:/{TrainConfig().registered_model_name}/latest"
+        _, out["registered"] = tracking.load_model(latest)
+        # names of each leaf file of the registered version, as of now
+        out["registered_links"] = [
+            p.stat().st_nlink for p in sorted(
+                (tracking.resolve_model_uri(latest) / "variables").glob(
+                    "*.npy"))]
     out["idle_phases"] = {p: obs.TRAIN_PHASE.labels(phase=p).count - n
                           for p, n in phases.items()}
     with watched(name, model_cfg, spy=True) as out["more_seen"]:
@@ -293,6 +298,19 @@ def test_a_resumed_job_registers_the_saved_best_candidate(resumed):
     assert jax.tree.structure(resumed["registered"]) \
         == jax.tree.structure(want)
     jax.tree.map(np.testing.assert_array_equal, resumed["registered"], want)
+
+
+def test_a_streamed_candidate_is_registered_as_links_to_its_checkpoint(
+        resumed):
+    """A state too large to hold twice registers its best candidate as
+    hard links to the leaf files of the checkpoint that holds it: the run's
+    artifact, the registry's version and the checkpoint are one copy on the
+    disk. A U-Net's artifact is a file of its own."""
+    links = resumed["registered_links"]
+    if resumed["name"] != "lm-streamed":
+        assert not links
+        return
+    assert links and all(n == 3 for n in links)
 
 
 def test_a_checkpoint_of_other_shapes_raises_before_any_step(resumed):
