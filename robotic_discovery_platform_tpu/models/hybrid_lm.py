@@ -166,7 +166,6 @@ def grouped_rms_norm(x, weight, groups: int, eps: float):
 
 def mamba_layer(cfg: HybridLMConfig, layer: dict, x, impl: str):
     """``x + mixer(RMSNorm(x))`` for ``x`` [batch, L, hidden]."""
-    del impl
     b, s, _ = x.shape
     dtype = x.dtype
     inner, heads, groups, n = (cfg.mamba_inner, cfg.mamba_heads,
@@ -184,7 +183,7 @@ def mamba_layer(cfg: HybridLMConfig, layer: dict, x, impl: str):
         a = -jnp.exp(layer["A_log"])
     y = ssm_scan(xs.reshape(b, s, heads, cfg.mamba_head_dim), dt, a,
                  b_.reshape(b, s, groups, n), c_.reshape(b, s, groups, n),
-                 layer["D"], chunk=cfg.ssm_chunk)
+                 layer["D"], chunk=cfg.ssm_chunk, impl=impl)
     with jax.named_scope("rdp.ssm.gate"):
         y = y.reshape(b, s, inner).astype(jnp.float32) * jax.nn.silu(
             z.astype(jnp.float32))

@@ -787,8 +787,9 @@ SSM_SCAN_CHUNKS = REGISTRY.counter(
     families.SSM_SCAN_CHUNKS,
     "Chunks a state-space scan (ops/ssm_scan) cuts one sequence into, one "
     "sample each time a scan's program is traced (so a memoised runner adds "
-    "none): kind = the implementation (xla). A change of chunk or "
-    "implementation shows here without a device trace.",
+    "none): kind = the form the dispatch traced (pallas: the kernels of "
+    "ops/pallas/ssm_scan, compiled or interpreted; xla: the einsums). A "
+    "change of chunk or form shows here without a device trace.",
     ("kind",),
 )
 

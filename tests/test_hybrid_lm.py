@@ -207,7 +207,7 @@ def test_the_scans_sums_and_state_are_float32_under_bfloat16_inputs():
 
 
 def test_the_chunk_counter_is_sampled_where_the_scan_is_traced():
-    counter = obs.SSM_SCAN_CHUNKS.labels(kind=scan_lib.KIND)
+    counter = obs.SSM_SCAN_CHUNKS.labels(kind="xla")    # chunk 8: no kernel
     fn = jax.jit(lambda *v: scan_lib.ssm_scan(*v, chunk=8))
     before = counter.value
     fn(*scan_inputs(27))
