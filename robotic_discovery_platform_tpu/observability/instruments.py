@@ -19,6 +19,7 @@ from robotic_discovery_platform_tpu.observability import (
     events,
     families,
     journal as journal_lib,
+    recorder as recorder_lib,
 )
 from robotic_discovery_platform_tpu.observability.registry import (
     REGISTRY,
@@ -687,10 +688,13 @@ TRAIN_PHASE = REGISTRY.histogram(
 )
 #: The retraining job's one stage timer, shared by training/trainer.py,
 #: checkpoint.py and data.py: ``TRAIN_PHASES.stage("rdp.train.steps")`` is
-#: a profiler host span and a sample of TRAIN_PHASE at once.
+#: a profiler host span and a sample of TRAIN_PHASE at once, and inside
+#: ``TRAIN_PHASES.timeline(...)`` (one a ``train_model`` call) a record of
+#: the call's timeline in the flight recorder (``GET /debug/spans``).
 TRAIN_PHASES = StageTimer(
     observer=lambda phase, seconds:
-    TRAIN_PHASE.labels(phase=phase).observe(seconds)
+    TRAIN_PHASE.labels(phase=phase).observe(seconds),
+    recorder=recorder_lib.RECORDER,
 )
 
 # -- compilation (analysis/recompile.py, utils/platforms.py) -----------------

@@ -205,14 +205,18 @@ class CheckpointManager:
         barriers of a multi-host save must run on the main thread in
         lockstep across processes."""
         self.wait()  # one save in flight; surfaces the previous error
+        # the worker's stages hang under the stage that hands the save over
+        handed = obs.TRAIN_PHASES.handover()
 
         def work():
             try:
-                with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.fetch"):
-                    host = jax.device_get(state)
-                with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.write"):
-                    self._mgr.save(step, args=ocp.args.StandardSave(host))
-                    self._mgr.wait_until_finished()
+                with obs.TRAIN_PHASES.adopted(handed):
+                    with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.fetch"):
+                        host = jax.device_get(state)
+                    with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.write"):
+                        obs.TRAIN_PHASES.annotate(bytes=tree_bytes(host))
+                        self._mgr.save(step, args=ocp.args.StandardSave(host))
+                        self._mgr.wait_until_finished()
             except BaseException as exc:  # surfaced by the next wait()
                 self._pending_error = exc
 
@@ -255,10 +259,13 @@ class CheckpointManager:
             host = fetch_streamed(state)
         home = self._dir / _STREAMED
         final, tmp = home / str(step), home / f"{step}.tmp"
+        handed = obs.TRAIN_PHASES.handover()
 
         def work():
             try:
-                with obs.TRAIN_PHASES.stage("rdp.train.checkpoint.write"):
+                with obs.TRAIN_PHASES.adopted(handed), \
+                        obs.TRAIN_PHASES.stage("rdp.train.checkpoint.write"):
+                    obs.TRAIN_PHASES.annotate(bytes=tree_bytes(host))
                     shutil.rmtree(tmp, ignore_errors=True)
                     write_leaves(tmp, host)
                     shutil.rmtree(final, ignore_errors=True)
@@ -424,11 +431,13 @@ class DeviceSnapshotSaves(_Saves):
     def restore(self, like):
         """The latest checkpoint into the shapes of ``like`` (a state or
         its ``ShapeDtypeStruct``s); a checkpoint of other shapes raises."""
+        wanted = {"state": like, "best_params": like.params,
+                  "best_stats": like.batch_stats}
+        obs.TRAIN_PHASES.annotate(bytes=tree_bytes(wanted))
         restored = self._ckpt.restore(jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(
                 a.shape, a.dtype, sharding=self._sharding_of(a)),
-            {"state": like, "best_params": like.params,
-             "best_stats": like.batch_stats}))
+            wanted))
         state = self._land(restored["state"])
         if np.isfinite(float(state.best_val_loss)):
             self._best_params = restored["best_params"]
@@ -497,6 +506,7 @@ class StreamedSaves(_Saves):
         self._best_step = ckpt.best_step()
 
     def restore(self, like):
+        obs.TRAIN_PHASES.annotate(bytes=tree_bytes(like))
         return self._ckpt.restore_streamed(
             {"state": like}, place=jax.device_put)["state"]
 

@@ -251,7 +251,7 @@ class StreamingBatches:
         s = self.dataset.img_size
         # on the prefetch thread: one batch's decode + resize, fanned out
         # over the pool
-        with obs.TRAIN_PHASES.stage("rdp.loader.decode"):
+        with obs.TRAIN_PHASES.stage("rdp.loader.decode", timeline=False):
             xs = np.empty((len(idx), s, s, 3), np.float32)
             ys = np.empty((len(idx), s, s, 1), np.float32)
             loaded = pool.map(

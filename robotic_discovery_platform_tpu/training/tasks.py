@@ -223,7 +223,10 @@ class _SparseDecoderTask:
 
     def observe(self, out, n_steps, batch_size, seconds, sample_shape):
         load = np.asarray(out["expert_load"], np.float64)
-        obs.MOE_ROUTED_ROWS.inc(float(out["routed_rows"]) * n_steps)
+        rows = float(out["routed_rows"]) * n_steps
+        obs.MOE_ROUTED_ROWS.inc(rows)
+        # on the epoch's record of the job's timeline, beside its steps
+        obs.TRAIN_PHASES.annotate(routed_rows=round(rows))
         if load.mean() > 0:
             obs.MOE_LOAD_RATIO.set(float(load.max() / load.mean()))
         if seconds > 0:

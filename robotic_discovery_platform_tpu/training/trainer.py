@@ -39,7 +39,7 @@ from robotic_discovery_platform_tpu.training import checkpoint as checkpoint_lib
 from robotic_discovery_platform_tpu.training import data as data_lib
 from robotic_discovery_platform_tpu.training import tasks as tasks_lib
 from robotic_discovery_platform_tpu.training.checkpoint import CheckpointManager
-from robotic_discovery_platform_tpu.utils import transferguard
+from robotic_discovery_platform_tpu.utils import platforms, transferguard
 from robotic_discovery_platform_tpu.utils.config import ModelConfig, TrainConfig
 from robotic_discovery_platform_tpu.utils.logging import get_logger
 
@@ -344,15 +344,16 @@ def prefetch_to_device(batches, put):
 
     Two phases per batch: ``rdp.train.loader_wait`` is the wait on the
     input pipeline for the next host batch, ``rdp.train.h2d`` what ``put``
-    costs the host."""
+    costs the host; as many as the data set has batches, so neither is
+    recorded in the job's timeline."""
     staged = None
     batches = iter(batches)
     while True:
-        with phases.stage("rdp.train.loader_wait"):
+        with phases.stage("rdp.train.loader_wait", timeline=False):
             batch = next(batches, None)
         if batch is None:
             break
-        with phases.stage("rdp.train.h2d"):
+        with phases.stage("rdp.train.h2d", timeline=False):
             nxt = (put(batch[0]), put(batch[1]))
         if staged is not None:
             yield staged
@@ -630,9 +631,6 @@ class TrainResult:
     final_metrics: dict
     epochs_run: int
     wall_clock_s: float
-    # per-epoch wall seconds (train + val, excluding checkpoint IO) so
-    # benchmarks can separate steady-state rate from host contention
-    epoch_seconds: list = None
 
     def to_jsonable(self) -> dict:
         """Plain-JSON form (metrics may be numpy/jax scalars) -- the ONE
@@ -647,6 +645,69 @@ class TrainResult:
             "epochs_run": int(self.epochs_run),
             "wall_clock_s": round(float(self.wall_clock_s), 2),
         }
+
+
+def _process_counts() -> dict:
+    """What the process has counted so far of making executables and of
+    ``train_model``'s own decisions, under the names a job's root span gives
+    a call's differences of them (:func:`_recorded_job`). Process-wide: what
+    another thread compiles meanwhile is in a call's difference."""
+    counts = {f"jit_s.{stage}": obs.JIT_SECONDS.labels(stage=stage).value
+              for stage in platforms.JIT_STAGES}
+    for result in platforms.CACHE_RESULTS:
+        counts[f"compile_cache.{result}"] = obs.COMPILE_CACHE.labels(
+            result=result).value
+    counts["traces"] = sum(s.value for s in obs.JIT_TRACES.samples())
+    # by result, over the families
+    for name, counter in (("state", obs.TRAIN_STATE),
+                          ("runners", obs.TRAIN_RUNNERS)):
+        for sample in counter.samples():
+            key = f"{name}.{dict(sample.labels)['result']}"
+            counts[key] = counts.get(key, 0.0) + sample.value
+    return counts
+
+
+@contextlib.contextmanager
+def _recorded_job(cfg: TrainConfig):
+    """The ``rdp.train.job`` phase of one ``train_model`` call as the root of
+    a timeline in the flight recorder (``StageTimer.timeline``), which is
+    yielded for the call to label. The root's attributes are counts at the
+    call's boundaries: where the process stood when the call began
+    (``process_age_s``, and ``process_jit_s``, ``process_cache_hits``,
+    ``process_cache_misses``: every compile of the process until then,
+    whichever thread made it) and, when it returns or raises, what the call
+    added to :func:`_process_counts`: ``jit_s.<stage>`` seconds,
+    ``compile_cache.hit`` / ``.miss``, ``traces`` over all guards, and
+    ``state`` (``built`` | ``restored``) and ``runners`` (``built`` |
+    ``reused``; absent under a mesh, which looks nothing up) as
+    ``rdp_train_state_total`` and ``rdp_train_runner_cache_total`` counted
+    them."""
+    platforms.listen_to_compiles()
+    with phases.timeline("rdp.train.job", {
+            "checkpoint_dir": cfg.checkpoint_dir,
+            "epochs": cfg.epochs}) as timeline:
+        before = _process_counts()
+        age = platforms.process_age_s()
+        if age is not None:
+            phases.annotate(process_age_s=f"{age:.2f}")
+        jit_s = sum(before[f"jit_s.{s}"] for s in platforms.JIT_STAGES)
+        phases.annotate(
+            process_jit_s=f"{jit_s:.6f}",
+            process_cache_hits=int(before["compile_cache.hit"]),
+            process_cache_misses=int(before["compile_cache.miss"]))
+        try:
+            yield timeline
+        finally:
+            added = {name: value - before.get(name, 0.0)
+                     for name, value in _process_counts().items()}
+            decisions = ("state.", "runners.")
+            # "state.restored" counted once: state=restored
+            phases.annotate(
+                **dict(name.split(".") for name, value in added.items()
+                       if name.startswith(decisions) and value),
+                **{name: f"{value:.6f}" if name.startswith("jit_s.")
+                   else int(value) for name, value in added.items()
+                   if not name.startswith(decisions)})
 
 
 def train_model(
@@ -689,16 +750,21 @@ def train_model(
     The call is one ``rdp.train.job`` phase whose children tile it
     (:data:`phases`): init, restore, stage_data, then per epoch steps,
     validation, log, best_copy and the checkpoint hand-over, then register
-    and flush. Its three decisions are taken once, in init, and are objects
-    from there on: where arrays live (:class:`_Placement`), how an epoch
-    runs (:class:`_ResidentScan` or :class:`_Stepped`), and how the state is
-    saved and the best candidate kept (``checkpoint.DeviceSnapshotSaves`` or
-    ``checkpoint.StreamedSaves``).
+    and flush; and, profiler or not, one timeline of those phases in the
+    flight recorder (:func:`_recorded_job`; ``GET /debug/spans``), labelled
+    ``family``, ``checkpoint_dir``, ``run_id``, ``resumed``, ``epochs``,
+    with the bytes a restore or a checkpoint write moved and an epoch's
+    ``steps`` (and ``routed_rows``) on their spans. Its three decisions are
+    taken once, in init, and are objects from there on: where arrays live
+    (:class:`_Placement`), how an epoch runs (:class:`_ResidentScan` or
+    :class:`_Stepped`), and how the state is saved and the best candidate
+    kept (``checkpoint.DeviceSnapshotSaves`` or ``checkpoint.StreamedSaves``).
     """
-    with phases.stage("rdp.train.job"):
+    with _recorded_job(cfg) as timeline:
         t_start = time.perf_counter()
         with phases.stage("rdp.train.init"):
             task = tasks_lib.task_for(model_cfg)
+            timeline.labels["family"] = task.name
             if arrays is not None:
                 xs, ys = task.prepare(arrays, cfg)
                 n_samples, ds = len(xs), None
@@ -741,6 +807,7 @@ def train_model(
             # a concrete one.
             ckpt = CheckpointManager(cfg.checkpoint_dir, keep=cfg.keep_checkpoints)
             resuming = resume and ckpt.latest_step() is not None
+            timeline.labels["resumed"] = str(resuming)
             abstract_state = (runner.state_shapes(fresh_state)
                               if place.single else None)
             state = (None if resuming and place.single
@@ -783,6 +850,7 @@ def train_model(
         # original error; the clean path surfaces save failures by raising
         try:
             with run_ctx as run:
+                timeline.labels["run_id"] = str(run.info.run_id)
                 with phases.stage("rdp.train.log"):
                     track.log_params(
                         {
@@ -799,7 +867,6 @@ def train_model(
                         }
                     )
 
-                epoch_seconds: list = []
                 start_epoch = min(int(state.epoch), cfg.epochs)
                 if int(state.epoch) >= cfg.epochs:
                     log.warning(
@@ -823,6 +890,7 @@ def train_model(
                             # time is dispatch-only, so the epoch mean is
                             # the honest per-step number for both.
                             train_time = time.perf_counter() - t_epoch
+                        phases.annotate(steps=n_steps)
                         if n_steps and train_time > 0:
                             obs.TRAIN_STEP.observe(train_time / n_steps)
                             obs.TRAIN_RATE.set(
@@ -850,13 +918,12 @@ def train_model(
                                     track.log_metric(
                                         "train_step_loss", float(v),
                                         step=epoch * n_steps + i)
-                            epoch_seconds.append(time.perf_counter() - t_epoch)
                             log.info(
                                 "epoch %d/%d train_loss=%.4f val_loss=%.4f "
                                 "%s=%.4f (%.1fs)",
                                 epoch + 1, cfg.epochs, train_loss, val["loss"],
                                 task.val_logged[0], val[task.val_logged[0]],
-                                epoch_seconds[-1],
+                                time.perf_counter() - t_epoch,
                             )
 
                         # every checkpoint_every-th epoch and the last one
@@ -903,5 +970,4 @@ def train_model(
             final_metrics=final_metrics,
             epochs_run=cfg.epochs - start_epoch,
             wall_clock_s=time.perf_counter() - t_start,
-            epoch_seconds=epoch_seconds,
         )

@@ -99,13 +99,32 @@ _CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": "hit",
     "/jax/compilation_cache/cache_misses": "miss",
 }
+#: the label values of ``rdp_jit_seconds_total`` / ``rdp_compile_cache_total``
+JIT_STAGES = tuple(_JIT_STAGE_EVENTS.values())
+CACHE_RESULTS = tuple(_CACHE_EVENTS.values())
 _compile_listeners_on = False
 
 
-def _listen_to_compiles() -> None:
+def process_age_s() -> float | None:
+    """Seconds since this process was started, the interpreter's start-up
+    and every import included: ``/proc/self/stat``'s start time against
+    ``/proc/uptime``. ``None`` where there is no such file."""
+    try:
+        # the command's name may hold spaces and brackets; fields count
+        # from after its closing one, and ``starttime`` is the 22nd
+        fields = Path("/proc/self/stat").read_text().rsplit(")", 1)[1].split()
+        uptime = float(Path("/proc/uptime").read_text().split()[0])
+        return uptime - int(fields[19]) / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def listen_to_compiles() -> None:
     """Feed JAX's own compile telemetry (``jax.monitoring``) into
     ``rdp_jit_seconds_total{stage}`` and ``rdp_compile_cache_total{result}``.
-    Registered once a process; JAX keeps listeners for its lifetime."""
+    Registered once a process; JAX keeps listeners for its lifetime.
+    :func:`enable_compile_cache` switches it on, and so does every
+    ``train_model`` call, whose timeline reads both counters."""
     global _compile_listeners_on
     if _compile_listeners_on:
         return
@@ -147,7 +166,7 @@ def enable_compile_cache() -> str | None:
     backend -- and is idempotent."""
     import jax
 
-    _listen_to_compiles()
+    listen_to_compiles()
     if _cpu_pinned():
         return None
     if not os.environ.get(_CACHE_ENV_VAR):

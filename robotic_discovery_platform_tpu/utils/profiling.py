@@ -15,7 +15,7 @@ import threading
 import time
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Callable
+from typing import Any, Callable
 
 import jax
 
@@ -38,25 +38,104 @@ class StageTimer:
     (``TraceAnnotation``, on the clock of the device planes), so a
     profile taken around the timed code (:func:`jax_trace`,
     :func:`capture_profile`) shows the same stages by the same names.
-    With no profiler session running the annotation is a flag check."""
+    With no profiler session running the annotation is a flag check.
+
+    And, inside :meth:`timeline`, a record of a span tree kept in memory:
+    each stage of the thread that opened the timeline (and of a thread
+    that :meth:`adopted` one of its stages) appends one ``SpanRecord``
+    with the stage that was open around it as its parent. One pair of
+    ``time.monotonic_ns`` readings feeds the sample and the record, around
+    the profiler span, so a traced run holds the same interval on both
+    clocks. The finished timeline goes to ``recorder`` (a
+    ``FlightRecorder``), pinned."""
 
     totals: dict = field(default_factory=lambda: defaultdict(float))
     counts: dict = field(default_factory=lambda: defaultdict(int))
     last: dict = field(default_factory=dict)
     observer: Callable[[str, float], None] | None = None
+    recorder: Any = None
     _lock: threading.Lock = field(default_factory=threading.Lock,
                                   repr=False, compare=False)
+    # per thread: the open ``timeline`` and its innermost open ``span``
+    _open: threading.local = field(default_factory=threading.local,
+                                   repr=False, compare=False)
 
     @contextlib.contextmanager
-    def stage(self, name: str, **stats):
+    def stage(self, name: str, *, timeline: bool = True, **stats):
         """Time the body under ``name``; ``stats`` (``epoch=3``) ride on
-        the profiler span only."""
-        t0 = time.perf_counter()
+        the profiler span and the timeline's record. ``timeline=False``
+        keeps a stage that runs once a step or a batch out of the open
+        timeline, which stays a few hundred records whatever the data
+        set."""
+        tl = getattr(self._open, "timeline", None) if timeline else None
+        if tl is not None:
+            parent = self._open.span
+            record = self._open.span = tl.span(
+                name, 0, parent=parent,
+                thread=threading.current_thread().name, **stats)
+        # read next to the profiler span's own start: whatever runs between
+        # the two (another thread may take the interpreter for 5 ms there)
+        # is a disagreement of the two clocks about this stage
+        start = time.monotonic_ns()
+        if tl is not None:
+            record.start_ns = start
         try:
             with jax.profiler.TraceAnnotation(name, **stats):
                 yield
         finally:
-            self.observe(name, time.perf_counter() - t0)
+            end = time.monotonic_ns()
+            if tl is not None:
+                record.end(end)
+                self._open.span = parent
+            self.observe(name, (end - start) / 1e9)
+
+    @contextlib.contextmanager
+    def timeline(self, name: str, labels: dict | None = None):
+        """The body as the root stage ``name`` of a new ``Timeline``, which
+        is yielded (its ``labels`` may still grow) and, when the body
+        returns or raises (``Timeline.fail``), recorded and pinned."""
+        from robotic_discovery_platform_tpu.observability.recorder import (
+            Timeline,
+        )
+
+        outer = self.handover()
+        tl = self._open.timeline = Timeline(name, labels)
+        self._open.span = None
+        try:
+            with self.stage(name):
+                yield tl
+        except BaseException as exc:
+            tl.fail(exc)
+            raise
+        finally:
+            self._open.timeline, self._open.span = outer
+            if self.recorder is not None:
+                self.recorder.pin(self.recorder.record(tl))
+
+    def handover(self) -> tuple:
+        """The calling thread's open timeline and innermost stage, for a
+        thread it starts to hang its own stages under (:meth:`adopted`)."""
+        return (getattr(self._open, "timeline", None),
+                getattr(self._open, "span", None))
+
+    @contextlib.contextmanager
+    def adopted(self, handover: tuple):
+        """On a worker thread: its stages are children of the stage that
+        handed the work over."""
+        self._open.timeline, self._open.span = handover
+        try:
+            yield
+        finally:
+            self._open.timeline = self._open.span = None
+
+    def annotate(self, **attributes) -> None:
+        """Counts at the boundary (``bytes=``, ``steps=``): attributes of
+        the calling thread's innermost open record; nothing outside a
+        timeline."""
+        span = getattr(self._open, "span", None)
+        if span is not None:
+            span.attributes.update(
+                (k, str(v)) for k, v in attributes.items())
 
     def observe(self, name: str, dt: float) -> None:
         """Record one externally-measured sample for ``name`` (the ingest

@@ -1,13 +1,18 @@
 """What the retraining job says about itself: the ``rdp.train.*`` phase
 spans of a ``train_model`` call in a ``jax.profiler`` trace (names, nesting,
 threads, tiling), the ``rdp_train_phase_seconds`` histogram the same stages
-feed, the compile counters, the jitted runners kept across calls (what
+feed, the timeline of the same stages that every call leaves in the flight
+recorder (parent links, the checkpoint thread's hand-over, the counts on the
+root and at the spans' boundaries, a call that raises), the compile
+counters, the jitted runners kept across calls (what
 shares an entry of the memo, what builds anew, its bound, a replaced
 builder, a one-device mesh, the trace guards' budget), and the named
 scopes of the compiled step."""
 
 import dataclasses
+import json
 import re
+import urllib.request
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +25,10 @@ from robotic_discovery_platform_tpu import tracking
 from robotic_discovery_platform_tpu.analysis import recompile
 from robotic_discovery_platform_tpu.models import losses as losses_lib
 from robotic_discovery_platform_tpu.models.unet import build_unet
-from robotic_discovery_platform_tpu.observability import instruments as obs
+from robotic_discovery_platform_tpu.observability import (
+    exposition, instruments as obs, recorder as recorder_lib)
+from robotic_discovery_platform_tpu.observability.registry import (
+    MetricsRegistry)
 from robotic_discovery_platform_tpu.training import synthetic, trainer
 from robotic_discovery_platform_tpu.utils import platforms
 from robotic_discovery_platform_tpu.utils.config import ModelConfig, TrainConfig
@@ -76,6 +84,20 @@ def counted(family, call):
     return out, {k: after[k] - before[k] for k in before}
 
 
+def job_timelines(checkpoint_dir):
+    """The ``rdp.train.job`` timelines that the flight recorder has pinned
+    for the calls of one job, oldest first, as ``GET /debug/spans`` shows
+    them."""
+    return [t for t in recorder_lib.RECORDER.snapshot()["pinned"]
+            if t["name"] == "rdp.train.job"
+            and t["labels"]["checkpoint_dir"] == str(checkpoint_dir)]
+
+
+def records(timeline, names):
+    names = (names,) if isinstance(names, str) else names
+    return [s for s in timeline["spans"] if s["name"] in names]
+
+
 def forget_runners():
     """An empty memo: what an earlier test of this process kept (the tiny
     model and the default hyper-parameters are everybody's) must not decide
@@ -128,15 +150,18 @@ def job(request, tmp_path_factory):
                        lambda: trainer.train_model(cfg, TINY_MODEL, **feed))
     seconds.append(jit_seconds())
     before = {name: phase_sum(name) for name in TILING + ("rdp.train.job",)}
+    counts = [trainer._process_counts()]
     (result, spans), second = counted(family, lambda: traced(
         tmp, lambda: trainer.train_model(
             dataclasses.replace(cfg, epochs=2 + MORE), TINY_MODEL, resume=True,
             **feed)))
+    counts.append(trainer._process_counts())
     seconds.append(jit_seconds())
     observed = {name: phase_sum(name) - before[name] for name in before}
     return dict(mode=request.param, spans=spans, result=result,
                 observed=observed, counted=(first, second),
-                jit_seconds=seconds)
+                jit_seconds=seconds, process_counts=counts,
+                timelines=job_timelines(cfg.checkpoint_dir))
 
 
 def one(spans, name):
@@ -238,6 +263,148 @@ def test_a_repeated_call_reuses_its_runners_and_the_counters_say_so(job):
     # the traced call is the resumed one: no call in it traced
     assert not job["spans"].named("rdp.jit.trace")
     assert not job["spans"].holding("PjitFunction*", "rdp.jit.trace")
+
+
+def test_every_call_leaves_one_pinned_timeline_of_its_phases(job):
+    """The resumed call with a registry write: the spans of its timeline are
+    ``JOB_PHASES`` and the epochs' phases, each with the parent that the
+    profiler's trace of the same call shows by nesting."""
+    first, timeline = job["timelines"]
+    assert first["labels"]["resumed"] == "False" and first["error"] is None
+    assert timeline["error"] is None and timeline["labels"] == {
+        "family": "unet", "checkpoint_dir": first["labels"]["checkpoint_dir"],
+        "run_id": job["result"].run_id, "resumed": "True",
+        "epochs": str(2 + MORE)}
+    root, by_id = timeline["spans"][0], {
+        s["span_id"]: s for s in timeline["spans"]}
+    assert root["name"] == "rdp.train.job" and root["parent_id"] is None
+    assert all(s["parent_id"] in by_id and s["end_ns"] is not None
+               for s in timeline["spans"][1:])
+    for name in JOB_PHASES:
+        (phase,) = records(timeline, name)
+        assert phase["parent_id"] == root["span_id"]
+    epochs = records(timeline, "rdp.train.epoch")
+    assert [e["attributes"]["epoch"] for e in epochs] \
+        == [str(n) for n in range(2, 2 + MORE)]
+    assert {e["attributes"]["steps"] for e in epochs} == {"3"}
+    for epoch in epochs:
+        assert epoch["parent_id"] == root["span_id"]
+        for name in EPOCH_PHASES:
+            inside = [s for s in records(timeline, name)
+                      if s["parent_id"] == epoch["span_id"]]
+            assert len(inside) == 1, (name, epoch["attributes"])
+    # and the trace of the same call nests the same way: the k-th span of a
+    # name on the job's thread is held by the span its record calls parent
+    spans, main = job["spans"], job["spans"].main
+    nth = {}
+    for record in timeline["spans"]:
+        if record["attributes"]["thread"] != root["attributes"]["thread"]:
+            continue
+        found = spans.named(record["name"], main)
+        k = nth[record["name"]] = nth.get(record["name"], -1) + 1
+        record["traced"] = found[k]
+        assert len(found) == len(records(timeline, record["name"]))
+        if record is not root:
+            parent = by_id[record["parent_id"]]
+            assert parent["traced"].holds(found[k]), record["name"]
+            # one interval on both clocks: the record's readings are taken
+            # around the profiler's span
+            assert record["duration_ms"] / 1e3 == pytest.approx(
+                found[k].seconds, abs=5e-3)
+
+
+def test_the_checkpoint_threads_records_hang_under_the_hand_over(job):
+    timeline = job["timelines"][1]
+    by_id = {s["span_id"]: s for s in timeline["spans"]}
+    workers = records(timeline, WORKER_PHASES)
+    assert len(workers) == 2 * MORE
+    for record in workers:
+        handed = by_id[record["parent_id"]]
+        assert handed["name"] == "rdp.train.checkpoint.snapshot"
+        assert handed["start_ns"] <= record["start_ns"]
+        assert record["attributes"]["thread"] == "checkpoint-save" \
+            != handed["attributes"]["thread"]
+    # each save's two records under the one snapshot that handed it over
+    assert len({r["parent_id"] for r in workers}) == MORE
+    # the bytes at the boundary: every write and the restore move the same
+    # tree (state, best parameters and statistics)
+    (restore,) = records(timeline, "rdp.train.restore")
+    moved = {r["attributes"]["bytes"]
+             for r in records(timeline, "rdp.train.checkpoint.write")}
+    assert moved == {restore["attributes"]["bytes"]} and int(moved.pop()) > 0
+
+
+def test_a_timeline_holds_no_record_a_step_or_a_batch(job):
+    """Stream mode runs ``rdp.train.loader_wait`` / ``.h2d`` once a step and
+    ``rdp.loader.decode`` once a batch: histogram samples and profiler spans
+    (above), never records, so both modes leave the same timeline."""
+    for timeline in job["timelines"]:
+        assert not records(timeline, STEP_PHASES + ("rdp.loader.decode",))
+    names = [s["name"] for s in job["timelines"][1]["spans"]]
+    assert set(names) == set(TILING + EPOCH_PHASES + WORKER_PHASES + (
+        "rdp.train.job",)) | ({"rdp.train.best_copy"} & set(names))
+    assert len(names) <= 12 + 9 * MORE
+
+
+def test_the_roots_counts_are_the_programs_counters(job):
+    first, second = (t["spans"][0]["attributes"] for t in job["timelines"])
+    before, after = job["process_counts"]
+    # where the process stood when the resumed call began
+    assert float(second["process_jit_s"]) == pytest.approx(
+        job["jit_seconds"][1], abs=1e-5)
+    assert int(second["process_cache_hits"]) == before["compile_cache.hit"]
+    assert int(second["process_cache_misses"]) \
+        == before["compile_cache.miss"]
+    assert float(second["process_age_s"]) >= float(first["process_age_s"]) \
+        + job["timelines"][0]["duration_ms"] / 1e3 - 0.02
+    # and what the call added
+    for name, value in after.items():
+        if name.startswith("jit_s."):
+            assert float(second[name]) == pytest.approx(
+                value - before[name], abs=1e-5)
+        elif name.startswith("compile_cache."):
+            assert int(second[name]) == value - before[name]
+    for attributes, added, state in ((first, BUILT, "built"),
+                                     (second, REUSED, "restored")):
+        assert int(attributes["traces"]) == added["traces"]
+        assert attributes["runners"] == (
+            "built" if added["built"] else "reused")
+        assert attributes["state"] == state
+    # as rdp_train_state_total counted the resumed call
+    assert after["state.restored"] - before.get("state.restored", 0) == 1
+    assert after["state.built"] == before["state.built"]
+
+
+def test_the_timelines_seconds_are_the_histograms(job):
+    timeline = job["timelines"][1]
+    for name, seconds in job["observed"].items():
+        assert sum(r["duration_ms"] for r in records(timeline, name)) / 1e3 \
+            == pytest.approx(seconds, abs=1e-6), name
+
+
+def test_debug_spans_serves_the_jobs_timeline(job):
+    server = exposition.MetricsServer(
+        0, MetricsRegistry(), host="127.0.0.1").start()
+    try:
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{server.port}/debug/spans",
+                timeout=5) as r:
+            served = json.loads(r.read())
+    finally:
+        server.stop()
+    seqs = {t["seq"] for t in served["pinned"]}
+    assert {t["seq"] for t in job["timelines"]} <= seqs
+
+
+def test_without_a_call_the_recorder_holds_what_it_held(tmp_path):
+    before = recorder_lib.RECORDER.snapshot()
+    with obs.TRAIN_PHASES.stage("rdp.train.steps"):
+        obs.TRAIN_PHASES.annotate(steps=3)
+    trainer.memoized_runners("epoch", TrainConfig(), TINY_MODEL, (4, 32))
+    after = recorder_lib.RECORDER.snapshot()
+    assert [t["seq"] for t in after["pinned"]] \
+        == [t["seq"] for t in before["pinned"]]
+    assert after["recorded_total"] == before["recorded_total"]
 
 
 def tiny_job(tmp_path, **changes):
@@ -495,6 +662,19 @@ def test_an_exception_in_an_epoch_closes_every_span(tmp_path, monkeypatch):
     assert whole.holds(flush) and flush.start >= epochs[1].end
     assert not whole.holds(one(spans, "after"))
     assert spans.self_seconds("rdp.train.job") < 0.02 * whole.seconds + 0.05
+    # and the call's timeline is pinned as failed, every record closed, the
+    # root's counts taken on the way out
+    (timeline,) = job_timelines(cfg.checkpoint_dir)
+    assert timeline["error"] == "RuntimeError: the tracking store went away"
+    assert all(s["end_ns"] is not None for s in timeline["spans"])
+    assert [e["attributes"]["epoch"]
+            for e in records(timeline, "rdp.train.epoch")] == ["0", "1"]
+    root = timeline["spans"][0]["attributes"]
+    assert root["state"] == "built" and "traces" in root
+    assert timeline["labels"]["resumed"] == "False" \
+        and timeline["labels"]["run_id"]
+    assert records(timeline, "rdp.train.flush")[0]["parent_id"] \
+        == timeline["spans"][0]["span_id"]
 
 
 def test_a_stage_is_a_profiler_span_and_a_histogram_sample(tmp_path):
@@ -515,6 +695,62 @@ def test_a_stage_is_a_profiler_span_and_a_histogram_sample(tmp_path):
     with timer.stage("rdp.test.outer"):
         pass
     assert timer.summary()["rdp.test.outer"]["count"] == 2
+
+
+def test_worker_threads_records_lose_nothing_under_contention():
+    """More workers than cores, each adopting the stage that started it, a
+    short switch interval: every stage of every thread is one closed record
+    under its hand-over, and the opening thread's own nesting is intact."""
+    import sys
+    import threading
+
+    rec = recorder_lib.FlightRecorder(capacity=4)
+    timer = StageTimer(recorder=rec)
+    workers, stages = 16, 50
+
+    def work(handed, k):
+        with timer.adopted(handed):
+            for i in range(stages):
+                with timer.stage("rdp.test.worker", worker=k, i=i):
+                    timer.annotate(bytes=i)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with timer.timeline("rdp.test.job", {"kind": "stress"}) as timeline:
+            with timer.stage("rdp.test.handover"):
+                threads = [threading.Thread(
+                    target=work, args=(timer.handover(), k))
+                    for k in range(workers)]
+                for t in threads:
+                    t.start()
+                for i in range(stages):
+                    with timer.stage("rdp.test.own"):
+                        pass
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    (pinned,) = rec.pinned()
+    assert pinned is timeline and timeline.error is None
+    root, handover = timeline.spans[:2]
+    assert (root.name, handover.name, handover.parent_id) == (
+        "rdp.test.job", "rdp.test.handover", root.span_id)
+    mine = [s for s in timeline.spans if s.name == "rdp.test.worker"]
+    assert len(mine) == workers * stages
+    assert all(s.parent_id == handover.span_id and s.end_ns is not None
+               and s.attributes["bytes"] == s.attributes["i"] for s in mine)
+    assert len({(s.attributes["worker"], s.attributes["i"])
+                for s in mine}) == workers * stages
+    own = [s for s in timeline.spans if s.name == "rdp.test.own"]
+    assert len(own) == stages \
+        and all(s.parent_id == handover.span_id for s in own)
+    assert timer.summary()["rdp.test.worker"]["count"] == workers * stages
+    # outside the timeline the timer records nothing more
+    with timer.stage("rdp.test.own"):
+        timer.annotate(bytes=1)
+    assert len(timeline.spans) == 2 + workers * stages + stages
 
 
 CACHE_CONFIG = ("jax_compilation_cache_dir",
