@@ -739,6 +739,17 @@ TRAIN_STATE = REGISTRY.counter(
     "which took the state's shapes and built nothing.",
     ("family", "result"),
 )
+TRAIN_STAGED_BYTES = REGISTRY.counter(
+    families.TRAIN_STAGED_BYTES,
+    "Bytes of a resident data set that crossed from the host to the device "
+    "when a train_model call staged it (trainer._ResidentScan.stage), one "
+    "sample a call, by model family and the form they crossed in: "
+    "device_cast = integer rows narrower than float32 moved as integers and "
+    "made float32 on the device by one jitted program; host_float = rows "
+    "that were floats on the host; as_is = rows the step takes unconverted "
+    "(token ids).",
+    ("family", "form"),
+)
 TRAIN_CONV_DISPATCH = REGISTRY.counter(
     families.TRAIN_CONV_DISPATCH,
     "3x3 training convolutions by the form ops/pallas/conv.conv3x3 picked "

@@ -79,6 +79,9 @@ class PairedSegmentationData:
         return xs, ys
 
 
+#: what a pixel code is divided by to become a unit float
+UNIT_SCALE = 255.0
+
 #: threads of :func:`unit_floats` (the pass is bound by memory and page
 #: faults: beyond a handful they no longer add)
 _UNIT_FLOAT_THREADS = 8
@@ -95,12 +98,80 @@ def unit_floats(a: np.ndarray) -> np.ndarray:
     rows = max(1, -(-len(a) // _UNIT_FLOAT_THREADS))
 
     def scale(start):
-        np.divide(a[start:start + rows], 255.0, out=out[start:start + rows],
-                  dtype=np.float32)
+        np.divide(a[start:start + rows], UNIT_SCALE,
+                  out=out[start:start + rows], dtype=np.float32)
 
     with ThreadPoolExecutor(_UNIT_FLOAT_THREADS) as pool:
         list(pool.map(scale, range(0, len(a), rows)))
     return out
+
+
+class IntegerRows:
+    """Integer rows narrower than float32 that a job trains on as float32,
+    and what makes them that: ``unit`` for ``rows / 255`` (pixel codes, masks
+    coded 0/255), otherwise a plain cast (masks coded 0/1). The task that
+    prepared the data set states the rule (``tasks.UNetTask.prepare``); the
+    floats are made where the job holds them. A job whose data set is
+    resident on the device moves ``rows`` as the bytes they are, a quarter
+    of the floats', and converts there (``trainer._ResidentScan.stage``);
+    any other takes :meth:`floats` on the host.
+
+    It describes the float32 rows it stands for (``shape``, ``dtype``,
+    ``nbytes``, ``len``): what a job sizes and keys its programs by is what
+    will be resident, not what arrived."""
+
+    dtype = np.dtype(np.float32)
+
+    def __init__(self, rows: np.ndarray, unit: bool):
+        self.rows, self.unit = rows, unit
+        self.shape = rows.shape
+        self.nbytes = rows.size * self.dtype.itemsize
+
+    def __len__(self) -> int:
+        return len(self.rows)
+
+    def floats(self) -> np.ndarray:
+        """The float32 rows, on the host: :func:`unit_floats` or the cast."""
+        return (unit_floats(self.rows) if self.unit
+                else np.asarray(self.rows, np.float32))
+
+
+def memory_view(rows: np.ndarray):
+    """``(flat, (shape, axes))``: ``rows`` as the block of bytes the host
+    holds, without a copy. ``flat`` is a C-contiguous two-dimensional view
+    of ``rows.transpose(axes)``, the axes from the slowest-varying in
+    memory to the fastest, and ``shape`` that transpose's shape, so that
+    ``flat.reshape(shape).transpose(np.argsort(axes))`` is ``rows`` again:
+    a consumer on the device undoes the order there, where it costs
+    nothing, and the host re-lays nothing. For a C-contiguous array the
+    axes are in order. An array fetched from a TPU is not (it keeps the
+    device's order of dimensions: ``[n, h, w, c]`` images arrive with ``c``
+    outside ``h``), and flattening it in index order would be a strided
+    copy of every byte on one thread: 0.59 s for 1,024 uint8 pairs of 256 x
+    256 (PERF.md section 6, PR 40). Rows that are no dense block in any
+    order (a strided slice) are copied once, in index order."""
+    rows = np.asarray(rows)
+    axes = tuple(int(i) for i in np.argsort(
+        [-abs(stride) for stride in rows.strides], kind="stable"))
+    view = rows.transpose(axes)
+    if not view.flags.c_contiguous:
+        axes, view = tuple(range(rows.ndim)), np.ascontiguousarray(rows)
+    return view.reshape(len(view), -1), (view.shape, axes)
+
+
+def float_rows(rows: np.ndarray, unit: bool):
+    """Integer ``rows`` as the float32 a job trains on (``rows / 255`` if
+    ``unit``, else the cast): :class:`IntegerRows`, made later where the job
+    holds them, for an integer narrower than float32, since moving those is
+    moving fewer bytes; host floats at once for a wider one."""
+    late = IntegerRows(rows, unit)
+    return late if rows.dtype.itemsize < late.dtype.itemsize else late.floats()
+
+
+def host_rows(rows) -> np.ndarray:
+    """What a job that feeds its batches from the host indexes: ``rows``
+    themselves, or the floats :class:`IntegerRows` stand for."""
+    return rows.floats() if isinstance(rows, IntegerRows) else rows
 
 
 def train_val_split(n: int, val_fraction: float, seed: int = 0):

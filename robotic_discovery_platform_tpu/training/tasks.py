@@ -29,7 +29,11 @@ Every task has:
 ``init_variables(model, rng, cfg)`` -> ``(params, batch_stats)``
 ``file_data(cfg)``, ``prepare(arrays, cfg)`` -> ``(xs, ys)``
     the streamed data set of ``cfg.dataset_dir``; an in-memory data set
-    normalised for the step (host arrays, first axis the samples).
+    normalised for the step (host arrays, first axis the samples). Rows a
+    step takes as they are (token ids) are plain arrays and reach it
+    untouched; integer rows a step takes as floats may be handed on as
+    ``data.IntegerRows``, the rows with the task's rule for their floats,
+    which the job makes where it holds them.
 ``train_loss(model, loss_fn, params, state, x, y)``
     -> ``(loss, (collection updates, aux))``, differentiated in ``params``;
     ``aux`` is a dict of per-step numbers the epoch averages ({} for none).
@@ -110,23 +114,24 @@ class UNetTask:
         # ~0.004 targets. Besides the wrong scale, u8 arrays reaching the
         # jitted train step trip an XLA CPU space_to_batch crash on conv
         # backprop (e.g. synthetic.generate_arrays' raw uint8 output).
+        # The rule is stated here and every check made here; the floats of
+        # rows narrower than float32 are made where the job holds them
+        # (data.float_rows): on the device, for a resident data set.
         if not np.issubdtype(xs.dtype, np.floating):
-            xs = data_lib.unit_floats(xs)
+            xs = data_lib.float_rows(xs, unit=True)
         if not np.issubdtype(ys.dtype, np.floating):
-            if np.max(ys, initial=0) > 1:
-                # only the file loader's 0/255 coding gets the /255 path;
-                # any other integer coding (class indices {0,2}, 0..K
-                # multi-class labels) would silently become ~K/255 targets,
-                # so reject it loudly instead of training against noise
-                # (one O(N) pass; the sort for the message only on error)
-                if not ((ys == 0) | (ys == 255)).all():
-                    raise ValueError(
-                        "integer masks must be coded {0,1} or {0,255}; got "
-                        f"values {np.unique(ys)[:8].tolist()}"
-                    )
-                ys = data_lib.unit_floats(ys)
-            else:
-                ys = np.asarray(ys, np.float32)
+            coded_255 = np.max(ys, initial=0) > 1
+            # only the file loader's 0/255 coding gets the /255 path;
+            # any other integer coding (class indices {0,2}, 0..K
+            # multi-class labels) would silently become ~K/255 targets,
+            # so reject it loudly instead of training against noise
+            # (one O(N) pass; the sort for the message only on error)
+            if coded_255 and not ((ys == 0) | (ys == 255)).all():
+                raise ValueError(
+                    "integer masks must be coded {0,1} or {0,255}; got "
+                    f"values {np.unique(ys)[:8].tolist()}"
+                )
+            ys = data_lib.float_rows(ys, unit=bool(coded_255))
         return xs, ys
 
     def train_loss(self, model, loss_fn, params, state, x, y):

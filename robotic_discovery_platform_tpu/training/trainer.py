@@ -221,6 +221,65 @@ def make_epoch_runners(model, tx, loss_fn: Callable, donate: bool = True,
     )
 
 
+def _unit_quotient(x):
+    """``float32(x) / 255`` for whole ``x`` of at most 16 bits, rounded as
+    IEEE division rounds it, from operations every backend rounds alike
+    (products and sums; no division of ``x``). ``q0 = x * (1 / 255)`` is
+    within a few units in the last place; its residual ``x - 255 q0`` is
+    made exactly, by splitting ``q0`` into halves of 12 bits whose
+    products with 255 are exact (Veltkamp's split by 2^12 + 1), and one
+    correcting step ``q0 + residual / 255`` lands on the quotient. XLA
+    writes ``x / 255.0`` as a product with the reciprocal, which differs
+    from numpy's quotient in the last bit of 126 of the 256 codes on any
+    backend, and a TPU's own float32 quotient by a divisor it is handed at
+    run time is not correctly rounded either (255 codes differ, and 255 /
+    255 reads 0.99999994); this reads ``data.unit_floats``'s value for
+    every 8- and 16-bit code on the CPU backend (``tests/test_trainer.py``)
+    and on a TPU v5e (PERF.md section 6, PR 40)."""
+    unit = data_lib.UNIT_SCALE
+    x = x.astype(jnp.float32)
+    q0 = x * (1.0 / unit)
+    split = q0 * 4097.0
+    high = split - (split - q0)
+    residual = (x - high * unit) - (q0 - high) * unit
+    return q0 + residual * (1.0 / unit)
+
+
+def make_row_floats(units: tuple, layouts: tuple):
+    """The program that makes a resident data set's float32 rows on the
+    device out of integer rows that crossed as integers
+    (``data.IntegerRows``)::
+
+        row_floats(rows, train_idx, val_idx) -> ((train, val), ...)
+
+    one pair of float32 arrays ``[len(idx), *sample shape]`` per entry of
+    ``rows``. ``rows[i]`` is the host array's block of bytes as a
+    two-dimensional view and ``layouts[i]`` the ``(shape, axes)`` it was
+    flattened from (``data.memory_view``): the form PJRT moves without
+    re-laying it on its host threads (PERF.md section 5, PR 40). On the
+    device each gets its shape and its order of axes back, is gathered by
+    the two indices as integers and made float32: ``data.unit_floats``'s
+    quotient where ``units[i]`` says so (:func:`_unit_quotient`), the cast
+    where not.
+
+    A NEW ``jax.jit`` object on every call, budgeted at one trace as
+    :func:`make_train_step`'s; ``_ResidentScan.stage`` keeps one per rule
+    and shape set (:func:`_kept_row_floats`)."""
+
+    def row_floats(rows, train_idx, val_idx):
+        def floats(flat, unit, shape, axes):
+            x = flat.reshape(shape).transpose(np.argsort(axes))
+            return tuple(
+                _unit_quotient(x[idx]) if unit else x[idx].astype(jnp.float32)
+                for idx in (train_idx, val_idx))
+
+        return tuple(floats(flat, unit, *layout)
+                     for flat, unit, layout in zip(rows, units, layouts))
+
+    return transferguard.apply(jax.jit(
+        recompile.trace_guard("trainer.row_floats", budget=1)(row_floats)))
+
+
 #: Pairs of jitted runners the process keeps, one per configuration and
 #: shape set, least recently used out first; a dropped pair releases its
 #: executables, so this also bounds the programs a long-lived process holds
@@ -242,7 +301,9 @@ _DEVICE_SNAPSHOT_MAX_BYTES = 1024**3
 #: An in-memory data set above this many bytes is not kept resident on the
 #: device: under ``epoch_mode="auto"`` its epochs run step by step from the
 #: host (a v5e has 16 GiB of HBM; this leaves room for parameters,
-#: activations and the donated state's copy).
+#: activations and the donated state's copy). The bytes are those of the
+#: rows the device would hold (``_Data.resident_bytes``): uint8 pixels
+#: count as their float32 rows, four times what arrived.
 _SCAN_MAX_BYTES = 4 * 1024**3
 
 _runner_memo_lock = threading.Lock()
@@ -281,6 +342,32 @@ def _kept_runners(family, builders, task, model_cfg, cfg, donate,
         return _Kept(builders[0](model, tx, loss_fn, donate=donate, **kw))
     return _Kept((builders[0](model, tx, loss_fn, donate=donate, **kw),
                   builders[1](model, loss_fn, **kw)))
+
+
+@functools.lru_cache(maxsize=RUNNER_MEMO_BOUND)
+def _kept_row_floats(builder, units, layouts, dtypes, splits, guard_mode):
+    """The :func:`make_row_floats` program of one rule and shape set, kept
+    as :func:`_kept_runners` keeps the runners: a repeated job's staging
+    traces and compiles nothing. Every argument that shapes the program is
+    in the key."""
+    del dtypes, splits, guard_mode
+    return builder(units, layouts)
+
+
+def _floats_on_device(late, train_idx, val_idx) -> tuple:
+    """The ``(train, val)`` float32 arrays of each of ``late``
+    (``data.IntegerRows``), made on the device: the rows cross as the block
+    of bytes the host holds (``data.memory_view``) and the kept program of
+    their rules and shapes (:func:`make_row_floats`) gathers the two splits
+    and converts there."""
+    flats, layouts = zip(*(data_lib.memory_view(a.rows) for a in late))
+    row_floats = _kept_row_floats(
+        make_row_floats, tuple(a.unit for a in late), layouts,
+        tuple(str(a.rows.dtype) for a in late),
+        (len(train_idx), len(val_idx)),
+        transferguard.resolve_transfer_guard())
+    return row_floats(tuple(jax.device_put(flat) for flat in flats),
+                      jax.device_put(train_idx), jax.device_put(val_idx))
 
 
 def _program_settings(task, cfg: TrainConfig) -> TrainConfig:
@@ -440,7 +527,10 @@ def _placement(mesh, task, model_cfg):
 
 class _Data(NamedTuple):
     """A job's data set, split: in-memory arrays (``xs``, ``ys``) or a file
-    data set (``ds``), one of the two ``None``."""
+    data set (``ds``), one of the two ``None``. An array is the host rows a
+    step takes, or ``data.IntegerRows`` that stand for float32 rows not
+    made yet, and says of itself what the step will see: shapes, dtype and
+    bytes here are those of the float32 rows, whatever arrived."""
 
     xs: Any
     ys: Any
@@ -457,7 +547,9 @@ class _Data(NamedTuple):
              self.ys.shape[1:], str(self.ys.dtype)))
 
     def resident_bytes(self) -> int:
-        """Bytes of the in-memory arrays (0 for a file data set). No
+        """Bytes of the in-memory arrays as a resident job would hold them
+        on the device (0 for a file data set): integer pixels count as the
+        float32 rows they become, four times the bytes that arrived. No
         ``np.asarray``: that would copy (or device-fetch) the whole data
         set just to read a byte count."""
         return sum(
@@ -470,10 +562,14 @@ class _ResidentScan:
     """The epoch runner of a single device with the data set resident in
     HBM: a whole epoch is one ``lax.scan`` dispatch and one host fetch
     (:func:`make_epoch_runners`), the same ``jax.jit`` pair for every call
-    of equal settings and split sizes (:func:`memoized_runners`)."""
+    of equal settings and split sizes (:func:`memoized_runners`). What is
+    resident is the float32 (or token) rows of the two splits; integer
+    pixels cross to the device as integers and are made float32 there
+    (:meth:`stage`)."""
 
     def __init__(self, cfg, model_cfg, data: _Data, batch_size: int):
         self._cfg, self._data, self._batch_size = cfg, data, batch_size
+        self._family = tasks_lib.task_for(model_cfg).name
         self._train_epoch, self._eval_epoch = self._kept = memoized_runners(
             "epoch", cfg, model_cfg,
             data.shapes(cfg) + (len(data.train_idx), len(data.val_idx)))
@@ -485,9 +581,36 @@ class _ResidentScan:
         return fresh_state()
 
     def stage(self) -> None:
+        """The two splits onto the device, by what each array is: rows the
+        task handed on as ``data.IntegerRows`` cross as the integers they
+        are and become float32 there (:func:`_floats_on_device`), so the
+        host writes no copy of any kind and PJRT re-lays none; any other
+        array is indexed on the host and placed as it is. The bytes that
+        crossed, by form, go to ``rdp_train_staged_bytes_total`` and onto
+        the ``rdp.train.stage_data`` span."""
         xs, ys, _, train_idx, val_idx = self._data
-        self._train = jnp.asarray(xs[train_idx]), jnp.asarray(ys[train_idx])
-        self._val = jnp.asarray(xs[val_idx]), jnp.asarray(ys[val_idx])
+        late = [a for a in (xs, ys) if isinstance(a, data_lib.IntegerRows)]
+        made = iter(_floats_on_device(late, train_idx, val_idx)
+                    if late else ())
+        crossed = ({"device_cast": sum(a.rows.nbytes for a in late)}
+                   if late else {})
+        splits = []
+        for a in (xs, ys):
+            if isinstance(a, data_lib.IntegerRows):
+                splits.append(next(made))
+                continue
+            pair = jnp.asarray(a[train_idx]), jnp.asarray(a[val_idx])
+            form = ("host_float" if jnp.issubdtype(pair[0].dtype, jnp.floating)
+                    else "as_is")
+            crossed[form] = crossed.get(form, 0) + sum(
+                int(p.nbytes) for p in pair)
+            splits.append(pair)
+        self._train, self._val = zip(*splits)
+        for form, nbytes in crossed.items():
+            obs.TRAIN_STAGED_BYTES.labels(
+                family=self._family, form=form).inc(nbytes)
+        phases.annotate(bytes=sum(crossed.values()),
+                        form="+".join(sorted(crossed)))
         self._order_rng = np.random.default_rng(self._cfg.seed)
         self._val_order = jnp.asarray(data_lib.epoch_order(
             len(val_idx), self._batch_size, False, self._order_rng))
@@ -558,6 +681,8 @@ class _Stepped:
                     divisor=self._divisor, workers=cfg.loader_workers)
                 for idx, shuffle in ((train_idx, True), (val_idx, False)))
         else:
+            # fed from the host, so integer pixels become floats there
+            xs, ys = data_lib.host_rows(xs), data_lib.host_rows(ys)
             self._train, self._val = (
                 data_lib.Batches(
                     xs[idx], ys[idx], self._batch_size, shuffle=shuffle,
@@ -731,7 +856,12 @@ def train_model(
             expert layers.
         arrays: optional in-memory ((xs, ys)) dataset overriding
             ``cfg.dataset_dir`` (tests, synthetic smoke runs); for a token
-            task ``(tokens [n, L] int32, None)``.
+            task ``(tokens [n, L] int32, None)``. Integer images and masks
+            (coded {0,1} or {0,255}) train as the float32 the file loader
+            makes of them; a single-device job keeps the set resident on
+            the device where its float32 rows fit ``_SCAN_MAX_BYTES``
+            (4 GiB, whatever dtype arrived), and pairs narrower than
+            float32 then cross as integers and become float32 there.
         resume: restore the latest checkpoint under
             ``cfg.checkpoint_dir`` and continue from its epoch; a start
             from nothing where there is none. A single-device job restores

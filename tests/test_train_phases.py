@@ -103,6 +103,14 @@ def forget_runners():
     model and the default hyper-parameters are everybody's) must not decide
     whether a call here builds."""
     trainer._kept_runners.cache_clear()
+    trainer._kept_row_floats.cache_clear()
+
+
+def conversion_traces():
+    """Traces of the program that makes a resident data set's integer rows
+    float32 on the device (``trainer.make_row_floats``): one a first call
+    on uint8 pairs, beside the runners' two."""
+    return obs.JIT_TRACES.labels(fn="trainer.row_floats").value
 
 
 def traced(tmp_path, call):
@@ -145,10 +153,11 @@ def job(request, tmp_path_factory):
         synthetic.generate_dataset(tmp / "data", 16, 48, 64, seed=3)
         cfg = dataclasses.replace(cfg, dataset_dir=str(tmp / "data"))
     family = "epoch" if request.param == "scan" else "step"
-    seconds = [jit_seconds()]
+    seconds, conversions = [jit_seconds()], [conversion_traces()]
     _, first = counted(family,
                        lambda: trainer.train_model(cfg, TINY_MODEL, **feed))
     seconds.append(jit_seconds())
+    conversions.append(conversion_traces())
     before = {name: phase_sum(name) for name in TILING + ("rdp.train.job",)}
     counts = [trainer._process_counts()]
     (result, spans), second = counted(family, lambda: traced(
@@ -157,9 +166,11 @@ def job(request, tmp_path_factory):
             **feed)))
     counts.append(trainer._process_counts())
     seconds.append(jit_seconds())
+    conversions.append(conversion_traces())
     observed = {name: phase_sum(name) - before[name] for name in before}
     return dict(mode=request.param, spans=spans, result=result,
                 observed=observed, counted=(first, second),
+                conversions=np.diff(conversions).tolist(),
                 jit_seconds=seconds, process_counts=counts,
                 timelines=job_timelines(cfg.checkpoint_dir))
 
@@ -364,9 +375,13 @@ def test_the_roots_counts_are_the_programs_counters(job):
                 value - before[name], abs=1e-5)
         elif name.startswith("compile_cache."):
             assert int(second[name]) == value - before[name]
-    for attributes, added, state in ((first, BUILT, "built"),
-                                     (second, REUSED, "restored")):
-        assert int(attributes["traces"]) == added["traces"]
+    # the resident job's uint8 pairs: its first call also traces the
+    # program that makes them float32 on the device, the second nothing
+    assert job["conversions"] == [job["mode"] == "scan", 0]
+    for attributes, added, converted, state in (
+            (first, BUILT, job["conversions"][0], "built"),
+            (second, REUSED, 0, "restored")):
+        assert int(attributes["traces"]) == added["traces"] + converted
         assert attributes["runners"] == (
             "built" if added["built"] else "reused")
         assert attributes["state"] == state

@@ -85,9 +85,12 @@ def test_integer_masks_0_255_normalized_other_codings_rejected(tmp_path):
 
 @pytest.mark.parametrize("dtype", [np.uint8, np.uint16, np.int32, np.int64])
 def test_integer_rows_are_scaled_in_one_pass_to_the_last_bit(dtype):
-    """``data.unit_floats`` (what ``prepare`` hands integer images and
-    0/255 masks to) is ``float32(rows) / 255`` exactly, whatever the block
-    edges: fewer rows than threads, none, and a count no thread divides."""
+    """``data.unit_floats`` (what integer images and 0/255 masks become,
+    wherever they are made floats) is ``float32(rows) / 255`` exactly,
+    whatever the block edges: fewer rows than threads, none, and a count no
+    thread divides. ``prepare`` hands rows narrower than float32 on as they
+    arrived, with the rule that stands for those floats, and makes the
+    wider ones' floats itself."""
     rng = np.random.default_rng(0)
     for n in (0, 1, 5, 19):
         rows = rng.integers(0, 256, (n, 6, 6, 3)).astype(dtype)
@@ -96,10 +99,337 @@ def test_integer_rows_are_scaled_in_one_pass_to_the_last_bit(dtype):
         assert got.dtype == np.float32 and got.shape == want.shape
         np.testing.assert_array_equal(got, want)
     imgs, masks = synthetic.generate_arrays(5, 32, 32, seed=3)
-    xs, ys = tasks_lib.UNET.prepare((imgs.astype(dtype), masks.astype(dtype)),
-                                    TrainConfig())
-    np.testing.assert_array_equal(xs, imgs.astype(np.float32) / 255.0)
-    np.testing.assert_array_equal(ys, masks.astype(np.float32) / 255.0)
+    imgs, masks = imgs.astype(dtype), masks.astype(dtype)
+    xs, ys = tasks_lib.UNET.prepare((imgs, masks), TrainConfig())
+    narrow = np.dtype(dtype).itemsize < 4
+    for got, rows in ((xs, imgs), (ys, masks)):
+        assert isinstance(got, data_lib.IntegerRows) == narrow
+        if narrow:
+            assert got.rows is rows and got.unit
+        # what it stands for, and says of itself
+        assert (got.dtype, got.shape, len(got)) == (
+            np.float32, rows.shape, len(rows))
+        assert got.nbytes == 4 * rows.size
+        np.testing.assert_array_equal(
+            data_lib.host_rows(got), rows.astype(np.float32) / 255.0)
+    # masks coded {0, 1} are a plain cast, by the same two routes
+    _, ys = tasks_lib.UNET.prepare((imgs, masks // 255), TrainConfig())
+    assert isinstance(ys, data_lib.IntegerRows) == narrow
+    assert not (narrow and ys.unit)
+    np.testing.assert_array_equal(
+        data_lib.host_rows(ys), (masks // 255).astype(np.float32))
+
+
+# -- how a resident data set's rows reach the device --------------------------
+
+def every_code_pairs(n=16, size=32):
+    """``n`` synthetic pairs whose images hold every one of the 256 codes in
+    every row of the data set (so in both splits), masks coded 0/255."""
+    imgs, masks = synthetic.generate_arrays(n, size, size, seed=3)
+    codes = np.resize(np.arange(256, dtype=np.uint8), (size // 2, size, 3))
+    imgs[:, :size // 2] = codes
+    assert all(len(np.unique(img)) == 256 for img in imgs)
+    return imgs, masks
+
+
+@contextlib.contextmanager
+def rows_handed():
+    """What the whole-epoch runners of the ``train_model`` calls inside are
+    handed as resident rows, as they are on the device: ``seen["train"]``
+    and ``seen["val"]``, each ``(xs, ys)`` of the newest call, and
+    ``seen["calls"]``, how often each runner was entered."""
+    sound = trainer.make_epoch_runners
+    seen = {"calls": 0}
+
+    def make_epoch_runners(*args, **kw):
+        runners = sound(*args, **kw)
+
+        def spying(name, run):
+            def spy(state, xs, ys, order):
+                seen[name] = (xs, ys)
+                seen["calls"] += 1
+                return run(state, xs, ys, order)
+            return spy
+
+        return tuple(spying(name, run)
+                     for name, run in zip(("train", "val"), runners))
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(trainer, "make_epoch_runners", make_epoch_runners)
+        yield seen
+
+
+def staged_bytes(family="unet"):
+    """``rdp_train_staged_bytes_total`` of one family, by form."""
+    return {form: obs.TRAIN_STAGED_BYTES.labels(
+        family=family, form=form).value
+        for form in ("device_cast", "host_float", "as_is")}
+
+
+def added_bytes(before, family="unet"):
+    return {form: value - before[form]
+            for form, value in staged_bytes(family).items()
+            if value != before[form]}
+
+
+def assert_bitwise(got, want):
+    got = np.asarray(got)
+    assert got.dtype == want.dtype == np.float32 and got.shape == want.shape
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+def in_device_order(rows):
+    """``rows`` with the strides an array fetched from a TPU has: equal
+    values, the channel axis outside the two spatial ones in memory."""
+    return np.ascontiguousarray(rows.transpose(0, 3, 1, 2)).transpose(
+        0, 2, 3, 1)
+
+
+@pytest.mark.parametrize("rows, shares", [
+    (np.arange(2 * 3 * 4 * 5, dtype=np.uint8).reshape(2, 3, 4, 5), True),
+    (in_device_order(
+        np.arange(2 * 3 * 4 * 5, dtype=np.uint8).reshape(2, 3, 4, 5)), True),
+    (np.asfortranarray(np.arange(24, dtype=np.uint16).reshape(2, 3, 4)),
+     True),
+    (np.arange(2 * 6 * 4, dtype=np.uint8).reshape(2, 6, 4)[:, ::2], False),
+    (np.broadcast_to(np.uint8(7), (3, 2, 2)), False),
+], ids=["c-order", "device-order", "fortran-order", "strided-slice",
+        "broadcast"])
+def test_memory_view_is_the_rows_bytes_and_how_to_read_them(rows, shares):
+    """``data.memory_view``: a C-contiguous two-dimensional view of the
+    block the array holds, whatever its order of axes, with the shape and
+    axes that read it back; only rows that are no dense block are copied."""
+    flat, (shape, axes) = data_lib.memory_view(rows)
+    assert flat.ndim == 2 and flat.flags.c_contiguous
+    assert flat.dtype == rows.dtype and flat.size == rows.size
+    assert np.shares_memory(flat, rows) == shares
+    np.testing.assert_array_equal(
+        flat.reshape(shape).transpose(np.argsort(axes)), rows)
+
+
+@pytest.mark.parametrize("dtype, mask_codes", [
+    (np.uint8, 255), (np.uint8, 1), (np.uint16, 255), (np.uint16, 1),
+    (np.int32, 255), (np.int64, 1), (np.float32, 1), ("device-order", 255)])
+def test_the_resident_rows_are_unit_floats_bit_for_bit(
+        tmp_path, dtype, mask_codes):
+    """Whatever the pairs' dtype, the four arrays the epoch runners are
+    handed are ``unit_floats(rows)[idx]`` to the last bit, over all 256
+    codes, and masks coded {0, 1} their plain cast. Integers narrower than
+    float32 crossed as integers, in whatever order of axes the host held
+    them, and were made float32 by the kept program on the device; wider
+    ones and floats took the host path."""
+    imgs, masks = every_code_pairs()
+    want_x = data_lib.unit_floats(imgs)
+    want_y = (masks > 0).astype(np.float32)
+    np.testing.assert_array_equal(want_y, data_lib.unit_floats(masks))
+    if dtype is np.float32:
+        fed = want_x, want_y
+    elif dtype == "device-order":
+        # uint8 pairs as a TPU hands them back: they cross without a copy
+        dtype, fed = np.uint8, (in_device_order(imgs), in_device_order(masks))
+        assert not fed[0].flags.c_contiguous
+    else:
+        fed = imgs.astype(dtype), (masks // 255 * mask_codes).astype(dtype)
+    cfg = tiny_cfg(tmp_path, epochs=1)
+    before = staged_bytes()
+    with rows_handed() as seen:
+        trainer.train_model(cfg, TINY_MODEL, arrays=fed, register=False)
+    split = data_lib.train_val_split(len(imgs), cfg.validation_split,
+                                     cfg.seed)
+    for name, idx in zip(("train", "val"), split):
+        xs, ys = seen[name]
+        assert_bitwise(xs, want_x[idx])
+        assert_bitwise(ys, want_y[idx])
+    narrow = np.dtype(dtype).itemsize < 4
+    assert added_bytes(before) == (
+        {"device_cast": float(fed[0].nbytes + fed[1].nbytes)} if narrow
+        else {"host_float": 4.0 * (imgs.size + masks.size)})
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.int8, np.uint16, np.int16])
+def test_the_device_program_reads_unit_floats_for_every_code(dtype):
+    """``make_row_floats`` alone, over every value a dtype narrower than
+    float32 holds: its quotient is ``unit_floats``'s to the last bit (XLA's
+    own ``x / 255.0`` is a product with the reciprocal and is not), 255
+    reads exactly 1, and the cast rule is the cast; rows come back in the
+    order of the index they were gathered by."""
+    info = np.iinfo(dtype)
+    rows = np.arange(info.min, info.max + 1).astype(dtype).reshape(-1, 64)
+    train_idx = np.random.default_rng(0).permutation(len(rows))
+    val_idx = train_idx[:1]
+    (unit, unit_val), (cast, _) = trainer.make_row_floats(
+        (True, False), (((len(rows), 8, 8), (0, 1, 2)),
+                        (rows.shape, (0, 1))))(
+            (rows, rows), train_idx, val_idx)
+    assert_bitwise(unit, data_lib.unit_floats(
+        rows[train_idx].reshape(-1, 8, 8)))
+    assert_bitwise(unit_val, data_lib.unit_floats(
+        rows[val_idx].reshape(-1, 8, 8)))
+    assert_bitwise(cast, rows[train_idx].astype(np.float32))
+    if info.max >= 255:
+        assert np.asarray(unit).ravel()[
+            np.flatnonzero(rows[train_idx].ravel() == 255)[0]] == 1.0
+
+
+def test_integer_pairs_train_as_their_floats_do_bit_for_bit(tmp_path):
+    """One epoch on uint8 pairs and the same job on those pairs scaled to
+    float32 by ``unit_floats`` beforehand: equal metrics, equal state."""
+    imgs, masks = synthetic.generate_arrays(16, 32, 32, seed=3)
+    results, states = [], []
+    for name, fed in (("integers", (imgs, masks)),
+                      ("floats", (data_lib.unit_floats(imgs),
+                                  data_lib.unit_floats(masks)))):
+        cfg = tiny_cfg(tmp_path / name, epochs=1)
+        results.append(trainer.train_model(cfg, TINY_MODEL, arrays=fed,
+                                           register=False))
+        states.append(saved_at(TINY_MODEL, cfg, 1, False))
+    assert results[0].final_metrics == results[1].final_metrics
+    assert results[0].best_val_loss == results[1].best_val_loss
+    assert_trees_equal(states[0], states[1])
+
+
+def test_a_mask_of_another_coding_raises_before_anything_is_staged(
+        tmp_path, monkeypatch):
+    imgs, masks = synthetic.generate_arrays(8, 32, 32, seed=3)
+    staged = []
+    monkeypatch.setattr(trainer._ResidentScan, "stage",
+                        lambda self: staged.append(self))
+    with pytest.raises(ValueError, match="integer masks"):
+        trainer.train_model(tiny_cfg(tmp_path, epochs=1), TINY_MODEL,
+                            arrays=(imgs, masks // 255 * 2), register=False)
+    assert not staged
+
+
+def test_token_rows_reach_the_runner_as_the_integers_they_are(tmp_path):
+    """A language-model task's ``prepare`` states no rule: its int32 rows
+    are placed untouched, and the counter says ``as_is``."""
+    tokens = np.random.default_rng(5).integers(
+        0, TINY_LM.mask_token_id, (20, TINY_LM.seq_len))
+    cfg = tiny_cfg(tmp_path, epochs=1, batch_size=2)
+    before = staged_bytes("blockdiff_lm")
+    with rows_handed() as seen:
+        trainer.train_model(cfg, TINY_LM, arrays=(tokens, None),
+                            register=False)
+    split = data_lib.train_val_split(len(tokens), cfg.validation_split,
+                                     cfg.seed)
+    for name, idx in zip(("train", "val"), split):
+        xs, ys = (np.asarray(a) for a in seen[name])
+        assert xs.dtype == ys.dtype == np.int32
+        np.testing.assert_array_equal(xs, tokens[idx])
+        np.testing.assert_array_equal(ys, np.full(len(idx), cfg.seed))
+    assert added_bytes(before, "blockdiff_lm") == {
+        "as_is": 4.0 * (tokens.size + len(tokens))}
+
+
+def test_a_streamed_job_is_fed_float_batches_from_the_host(tmp_path):
+    """``epoch_mode="stream"`` (``_Stepped``) keeps the host path: uint8
+    pairs reach the step as the float32 batches ``Batches`` cuts from
+    ``unit_floats``'s rows, and nothing is counted as staged."""
+    imgs, masks = every_code_pairs()
+    cfg = tiny_cfg(tmp_path, epochs=1, epoch_mode="stream")
+    sound, fed = trainer.make_train_step, []
+
+    def make_train_step(*args, **kw):
+        step = sound(*args, **kw)
+
+        def spy(state, x, y):
+            fed.append((x, y))
+            return step(state, x, y)
+        return spy
+
+    before = staged_bytes()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(trainer, "make_train_step", make_train_step)
+        trainer.train_model(cfg, TINY_MODEL, arrays=(imgs, masks),
+                            register=False)
+    train_idx, _ = data_lib.train_val_split(
+        len(imgs), cfg.validation_split, cfg.seed)
+    want = list(data_lib.Batches(
+        data_lib.unit_floats(imgs)[train_idx],
+        data_lib.unit_floats(masks)[train_idx], cfg.batch_size, seed=cfg.seed))
+    assert len(fed) == len(want) == 3
+    for (x, y), (want_x, want_y) in zip(fed, want):
+        assert_bitwise(x, want_x)
+        assert_bitwise(y, want_y)
+    assert added_bytes(before) == {}
+
+
+def test_a_data_set_is_sized_by_the_floats_the_device_would_hold(
+        tmp_path, monkeypatch):
+    """``resident_bytes()`` and ``shapes()`` of uint8 pairs are those of
+    their float32 rows, so a uint8 data set whose floats are over
+    ``_SCAN_MAX_BYTES`` is streamed, not slipped under the limit at a byte
+    an element; and its runners are keyed as the float job's."""
+    imgs, masks = synthetic.generate_arrays(16, 32, 32, seed=3)
+    cfg = tiny_cfg(tmp_path, epochs=1)
+    split = data_lib.train_val_split(16, cfg.validation_split, cfg.seed)
+    as_integers, as_floats = (
+        trainer._Data(*tasks_lib.UNET.prepare(pair, cfg), None, *split)
+        for pair in ((imgs, masks), (data_lib.unit_floats(imgs),
+                                     data_lib.unit_floats(masks))))
+    assert as_integers.resident_bytes() == as_floats.resident_bytes() \
+        == 4 * (imgs.size + masks.size)
+    assert as_integers.shapes(cfg) == as_floats.shapes(cfg)
+
+    def lookups():
+        return {family: sum(
+            obs.TRAIN_RUNNERS.labels(family=family, result=r).value
+            for r in ("built", "reused")) for family in ("epoch", "step")}
+
+    # between the bytes that arrive and the bytes that would be resident
+    monkeypatch.setattr(trainer, "_SCAN_MAX_BYTES", 2 * (imgs.size + masks.size))
+    before, staged = lookups(), staged_bytes()
+    trainer.train_model(cfg, TINY_MODEL, arrays=(imgs, masks), register=False)
+    assert lookups() == {"epoch": before["epoch"], "step": before["step"] + 1}
+    assert added_bytes(staged) == {}
+
+
+def test_staging_is_counted_and_annotated_once_a_call_and_traced_once(
+        tmp_path):
+    """``rdp_train_staged_bytes_total{family, form}`` takes one sample a
+    ``stage()``, the bytes that crossed, and the ``rdp.train.stage_data``
+    span of the call's timeline carries the same ``bytes`` and ``form``; a
+    second call of equal shapes gets the kept conversion program back and
+    traces it no second time."""
+    from robotic_discovery_platform_tpu.analysis import recompile
+    from robotic_discovery_platform_tpu.observability import recorder
+
+    imgs, masks = synthetic.generate_arrays(16, 32, 32, seed=3)
+    cfg = tiny_cfg(tmp_path)
+
+    def traces():
+        return obs.JIT_TRACES.labels(fn="trainer.row_floats").value
+
+    def stage_spans():
+        return [span["attributes"] for t in recorder.RECORDER.snapshot()[
+            "pinned"] if t["labels"].get("checkpoint_dir")
+            == cfg.checkpoint_dir for span in t["spans"]
+            if span["name"] == "rdp.train.stage_data"]
+
+    trainer._kept_row_floats.cache_clear()      # what earlier tests kept
+    moved = imgs.nbytes + masks.nbytes
+    with recompile.strict():
+        for call, traced in enumerate((1, 0)):
+            before, n = staged_bytes(), traces()
+            trainer.train_model(
+                dataclasses.replace(cfg, epochs=call + 1), TINY_MODEL,
+                arrays=(imgs, masks), resume=True, register=False)
+            assert added_bytes(before) == {"device_cast": float(moved)}
+            assert traces() - n == traced
+    spans = stage_spans()
+    assert len(spans) == 2
+    for attributes in spans:
+        assert (attributes["bytes"], attributes["form"]) == (
+            str(moved), "device_cast")
+    # floats cross as floats, and the span says so
+    before = staged_bytes()
+    trainer.train_model(
+        dataclasses.replace(cfg, epochs=3), TINY_MODEL, resume=True,
+        arrays=(data_lib.unit_floats(imgs), data_lib.unit_floats(masks)),
+        register=False)
+    assert added_bytes(before) == {"host_float": 4.0 * moved}
+    assert (stage_spans()[-1]["bytes"], stage_spans()[-1]["form"]) == (
+        str(4 * moved), "host_float")
 
 
 def test_loss_decreases(tmp_path, arrays):
