@@ -1,9 +1,10 @@
 """A sparse-expert decoder trained by block diffusion, as one chip's share of
 an expert-parallel job: one of the platform's three language-model families
 (``models/causal_lm`` is trained by next-token prediction and mixes two
-kinds of attention layer; ``models/hybrid_lm`` mixes state-space, attention
-and expert layers of one branch each; the three share the expert layer,
-RMSNorm and the seeded start, ``models/moe``).
+kinds of attention layer; ``models/hybrid_lm`` mixes layers of one branch
+each: state-space mixers, gated short convolutions, attention, dense MLPs
+and experts; the three share the expert layer, RMSNorm and the seeded start,
+``models/moe``).
 
 The layer equations (``BlockDiffLMConfig``; RMSNorm ``eps``, no biases)::
 

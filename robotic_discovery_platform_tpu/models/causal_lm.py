@@ -2,10 +2,11 @@
 one chip's share of an expert-parallel job: the second of the platform's
 three language-model families (the first, ``models/blockdiff_lm``, stacks
 identical layers and is trained by block diffusion; the third,
-``models/hybrid_lm``, mixes state-space, attention and expert layers of one
-residual branch each and takes this module's head and loss; the three share
-the expert layer in the form their configuration gives it, RMSNorm and the
-seeded start, ``models/moe``).
+``models/hybrid_lm``, mixes layers of one residual branch each (state-space
+mixers, gated short convolutions, attention, dense MLPs, experts) and takes
+this module's head, loss and rotary table; the three share the expert layer
+in the form their configuration gives it, RMSNorm and the seeded start,
+``models/moe``).
 
 One layer (``CausalLMConfig``; RMSNorm ``eps``, no biases, no q/k norm)::
 
@@ -22,7 +23,9 @@ One layer (``CausalLMConfig``; RMSNorm ``eps``, no biases, no q/k norm)::
 (the expert layer in its default form: a softmax router and gated SiLU
 experts; ``models/moe`` has the others, and a configuration that asks for
 one gets its leaves from ``moe.expert_shapes``) then a final RMSNorm and an
-untied head; the loss is the mean cross-entropy
+untied head (``head_logits`` and ``next_token_loss`` also serve a model
+whose parameters hold no ``head``: its embedding is the head, tied); the
+loss is the mean cross-entropy
 of position ``i``'s logits against token ``i + 1`` over positions
 ``0 .. L - 2``. Parameters are float32; matrix products and activations run
 in ``compute_dtype``; the rotary tables and their application, router
@@ -219,9 +222,15 @@ def hidden_states(cfg: CausalLMConfig, params: dict, tokens,
 
 def head_logits(cfg, params: dict, x):
     """The final norm and the head on a stream ``x``: float32 logits over
-    the vocabulary slice (``cfg`` gives ``rms_norm_eps``)."""
+    the vocabulary slice (``cfg`` gives ``rms_norm_eps``). ``params`` without
+    a ``head`` are a tied model's: the embedding's rows are the head's
+    columns."""
     with jax.named_scope("rdp.lm.head"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        if "head" not in params:
+            return jnp.einsum("...h,vh->...v", x,
+                              params["embed"].astype(x.dtype),
+                              preferred_element_type=jnp.float32)
         return jnp.dot(x, params["head"].astype(x.dtype),
                        preferred_element_type=jnp.float32)
 
@@ -260,7 +269,10 @@ def next_token_loss(cfg: CausalLMConfig, params: dict, x, tokens,
                     if with_hits else jnp.zeros(()))
             return jnp.sum(nll * weight), jnp.sum(hits)
 
-    head_params = {k: params[k] for k in ("final_norm", "head")}
+    # a tied model's head is its embedding, whose gradient is then the sum
+    # of the gather's and the chunks'
+    head_params = {k: params[k] for k in (
+        "final_norm", "head" if "head" in params else "embed")}
 
     def body(total, args):
         nll, hits = one(head_params, *args)

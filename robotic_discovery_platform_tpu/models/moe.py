@@ -21,7 +21,8 @@ the window-attention families compute):
     (``norm_topk_prob``) renormalised. ``"sigmoid"``: ``s = sigmoid(logits)``;
     the largest of ``s + router_bias`` are picked (the bias is a leaf of the
     layer that takes no gradient), weighed by ``s`` alone, renormalised over
-    ``sum + 1e-20`` and multiplied by ``routed_scaling_factor``.
+    ``sum + router_norm_eps`` (1e-20 by default) and multiplied by
+    ``routed_scaling_factor``.
 ``expert_act``
     ``"swiglu"``: ``Wdown(silu(x Wgate) * (x Wup))``, three matrices an
     expert. ``"relu2"``: ``Wdown relu(x Wup)^2``, two.
@@ -33,8 +34,8 @@ the window-attention families compute):
 
 ``cfg`` is any family's configuration: the functions read those and
 ``hidden_size``, ``num_experts``, ``experts_per_token``, ``experts_held``,
-``first_expert``, ``expert_width``, ``norm_topk_prob`` and
-``moe_chunk_rows`` of it.
+``first_expert``, ``expert_width``, ``norm_topk_prob``,
+``router_norm_eps`` and ``moe_chunk_rows`` of it.
 """
 
 from __future__ import annotations
@@ -133,7 +134,8 @@ def route(cfg, probs, picked_by=None):
     if cfg.norm_topk_prob:
         total = jnp.sum(top, axis=-1, keepdims=True)
         # a softmax's picks sum to more than nothing; sigmoids may not
-        top = top / (total if picked_by is None else total + 1e-20)
+        top = top / (total if picked_by is None
+                     else total + cfg.router_norm_eps)
     if cfg.routed_scaling_factor != 1.0:
         top = top * cfg.routed_scaling_factor
     local = ids - cfg.first_expert
@@ -178,13 +180,18 @@ def _chunk_experts(rows, mats: tuple, here, impl: str):
                               out_dtype=jnp.float32)
 
 
+def dense_expert(mats: tuple, h):
+    """An expert's form on every row of ``h`` [..., hidden] at weight 1, as
+    dense products: ``mats`` is (gate,) up, down."""
+    *ins, w_down = (w.astype(h.dtype) for w in mats)
+    act = _activation(tuple(jnp.dot(h, w) for w in ins), h.dtype)
+    return jnp.dot(act, w_down)
+
+
 def shared_expert(mats: tuple, h):
-    """The shared expert's branch for ``h`` [tokens, hidden]: the held
-    experts' form on every token at weight 1, as dense products."""
+    """The shared expert's branch for ``h`` [tokens, hidden]."""
     with jax.named_scope("rdp.moe.shared"):
-        *ins, w_down = (w.astype(h.dtype) for w in mats)
-        act = _activation(tuple(jnp.dot(h, w) for w in ins), h.dtype)
-        return jnp.dot(act, w_down)
+        return dense_expert(mats, h)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))

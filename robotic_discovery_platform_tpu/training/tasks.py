@@ -358,8 +358,10 @@ class CausalLMTask(_SparseDecoderTask):
 
 
 class HybridLMTask(CausalLMTask):
-    """A decoder of state-space, attention and expert layers of one branch
-    each (``models/hybrid_lm``), trained by next-token prediction as
+    """A decoder of layers of one branch each, state-space mixers, gated
+    short convolutions, attention, dense MLPs and experts, under an untied
+    or a tied head (``models/hybrid_lm``), trained by next-token prediction
+    as
     :class:`CausalLMTask` trains its own: the model object answers the same
     ``loss``, and the rows counted are the expert layers'."""
 

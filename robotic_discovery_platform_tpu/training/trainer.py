@@ -852,8 +852,8 @@ def train_model(
             (``training/tasks.py``): a ``ModelConfig`` the segmenter, a
             ``BlockDiffLMConfig`` a block-diffusion language model, a
             ``CausalLMConfig`` a causal one of window and full layers, a
-            ``HybridLMConfig`` a causal one of state-space, attention and
-            expert layers.
+            ``HybridLMConfig`` a causal one of one-branch layers (state-space
+            mixers, short convolutions, attention, dense MLPs, experts).
         arrays: optional in-memory ((xs, ys)) dataset overriding
             ``cfg.dataset_dir`` (tests, synthetic smoke runs); for a token
             task ``(tokens [n, L] int32, None)``. Integer images and masks

@@ -87,15 +87,17 @@ class BlockDiffLMConfig:
     rms_norm_eps: float = 1e-6
     rope_theta: float = 1e6
     norm_topk_prob: bool = True
-    # the expert layer's form (models/moe.py), the same four fields on
+    # the expert layer's form (models/moe.py), the same five fields on
     # every sparse-expert configuration: how the router scores ("softmax",
     # or "sigmoid": scores picked with a bias that takes no gradient,
     # renormalised, times routed_scaling_factor), what an expert computes
     # ("swiglu": Wdown(silu(x Wgate) * (x Wup)); "relu2": Wdown
     # relu(x Wup)^2) and the width of a shared expert every token passes
-    # through (0 = none)
+    # through (0 = none); router_norm_eps is what a sigmoid router's picked
+    # scores are divided by beside their sum
     router_scoring: str = "softmax"
     routed_scaling_factor: float = 1.0
+    router_norm_eps: float = 1e-20
     expert_act: str = "swiglu"
     shared_expert_width: int = 0
     seq_len: int = 32             # L tokens; the model sees 2 L positions
@@ -166,15 +168,17 @@ class CausalLMConfig:
     expert_width: int = 32
     rms_norm_eps: float = 1e-6
     norm_topk_prob: bool = True
-    # the expert layer's form (models/moe.py), the same four fields on
+    # the expert layer's form (models/moe.py), the same five fields on
     # every sparse-expert configuration: how the router scores ("softmax",
     # or "sigmoid": scores picked with a bias that takes no gradient,
     # renormalised, times routed_scaling_factor), what an expert computes
     # ("swiglu": Wdown(silu(x Wgate) * (x Wup)); "relu2": Wdown
     # relu(x Wup)^2) and the width of a shared expert every token passes
-    # through (0 = none)
+    # through (0 = none); router_norm_eps is what a sigmoid router's picked
+    # scores are divided by beside their sum
     router_scoring: str = "softmax"
     routed_scaling_factor: float = 1.0
+    router_norm_eps: float = 1e-20
     expert_act: str = "swiglu"
     shared_expert_width: int = 0
     seq_len: int = 32             # L tokens a sequence
@@ -211,7 +215,8 @@ class CausalLMConfig:
 
 #: the layer kinds a ``HybridLMConfig.layer_pattern`` entry may name, and
 #: the letters a published ``hybrid_override_pattern`` writes them with
-HYBRID_KINDS = {"M": "mamba", "*": "attention", "E": "experts"}
+HYBRID_KINDS = {"M": "mamba", "*": "attention", "E": "experts",
+                "c": "shortconv", "m": "mlp"}
 ROUTER_SCORINGS = ("softmax", "sigmoid")
 EXPERT_ACTS = ("swiglu", "relu2")
 
@@ -219,14 +224,16 @@ EXPERT_ACTS = ("swiglu", "relu2")
 @dataclass(frozen=True)
 class HybridLMConfig:
     """A causal decoder whose layers are one residual branch each, a
-    Mamba-2 mixer, grouped-query attention or a sparse-expert layer with a
-    shared expert, as one chip's share of an expert-parallel job
-    (models/hybrid_lm.py). ``layer_pattern`` names each layer's kind (a
-    tuple of ``HYBRID_KINDS`` values, or a string of their letters as a
-    published ``hybrid_override_pattern`` has them). Widths are a published
-    model's; ``num_layers``, ``experts_held`` and ``vocab_size`` are what
-    this chip holds of it. The defaults are the unit tests' size: two
-    periods of a four-layer pattern, four chunks a sequence, two groups."""
+    Mamba-2 mixer, a gated short convolution, grouped-query attention, a
+    dense gated MLP or a sparse-expert layer, as one chip's share of an
+    expert-parallel job (models/hybrid_lm.py). ``layer_pattern`` names each
+    layer's kind (a tuple of ``HYBRID_KINDS`` values, or a string of their
+    letters as a published ``hybrid_override_pattern`` has them); a model
+    whose published layer is an operator and a feed-forward branch is two
+    entries a layer. Widths are a published model's; ``num_layers``,
+    ``experts_held`` and ``vocab_size`` are what this chip holds of it. The
+    defaults are the unit tests' size: two periods of a four-layer pattern,
+    four chunks a sequence, two groups."""
 
     vocab_size: int = 64          # rows of the embedding and head held here
     hidden_size: int = 64
@@ -248,10 +255,19 @@ class HybridLMConfig:
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4
     a_range: tuple = (1.0, 16.0)
-    # attention: no rotary embedding
+    # the gated short convolution: shortconv_kernel taps over hidden_size
+    # channels, no bias
+    shortconv_kernel: int = 3
+    # attention; rope_theta 0 = no rotary embedding, qk_norm = an RMSNorm
+    # over each head of q and k before it
     num_heads: int = 4
     num_kv_heads: int = 2         # each shared by num_heads // num_kv_heads
     head_dim: int = 16
+    qk_norm: bool = False
+    rope_theta: float = 0.0
+    mlp_width: int = 128          # the dense MLP: hidden -> width -> hidden
+    # the embedding's rows are the head's columns: one leaf, no ``head``
+    tie_embeddings: bool = False
     # the expert layer (models/moe.py)
     num_experts: int = 8          # the router's outputs, all chips' experts
     experts_per_token: int = 2
@@ -261,6 +277,8 @@ class HybridLMConfig:
     norm_topk_prob: bool = True
     router_scoring: str = "sigmoid"
     routed_scaling_factor: float = 2.5
+    # what a sigmoid router's picked scores are divided by beside their sum
+    router_norm_eps: float = 1e-20
     expert_act: str = "relu2"
     shared_expert_width: int = 64
     # as BlockDiffLMConfig's
