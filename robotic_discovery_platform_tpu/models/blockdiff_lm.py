@@ -38,6 +38,14 @@ of tokens. Noise comes from ``training/data.block_diffusion_noise``.
 Layers are identical, stacked and run under ``lax.scan`` with one
 ``jax.checkpoint`` a layer: the layer's input and attention's output (with
 its row sums) are saved, the rest is recomputed.
+
+**Where q and k are made ready.** A layer hands each projection's product
+to ``ops/pallas/qk_prep.prepare_heads`` with its norm weight, the forward
+pass's one rotary table (:func:`rotary_table`, built before the layer scan)
+and the scale: the head split, norm, rotation and scale are one Pallas pass
+a direction on a TPU (heads of 128) and the dense chain elsewhere;
+:func:`rotary` stays as the definition of what a layer does with its
+positions.
 """
 
 from __future__ import annotations
@@ -48,12 +56,16 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from robotic_discovery_platform_tpu.models.causal_lm import rope_table
 from robotic_discovery_platform_tpu.models.moe import (  # noqa: F401
     expert_layer, expert_shapes, rms_norm, route, routed_experts,
     seeded_params)
 from robotic_discovery_platform_tpu.ops.pallas.blockdiff_attention import (
     ATTN_RESIDUALS, blockdiff_attention)
-from robotic_discovery_platform_tpu.utils.config import BlockDiffLMConfig
+from robotic_discovery_platform_tpu.ops.pallas.qk_prep import (
+    prepare_heads, split_heads)
+from robotic_discovery_platform_tpu.utils.config import (
+    BlockDiffLMConfig, RotaryConfig)
 
 
 def param_shapes(cfg: BlockDiffLMConfig) -> dict:
@@ -92,27 +104,35 @@ def rotary(x, positions, theta: float):
     return (x32 * cos + rotated * sin).astype(x.dtype)
 
 
-def decoder_layer(cfg: BlockDiffLMConfig, layer: dict, x, positions,
+def rotary_table(cfg: BlockDiffLMConfig, length: int):
+    """The (cos, sin) of a forward pass (``causal_lm.rope_table``, no
+    scaling): both copies of a sequence of ``length`` at positions
+    ``0..length-1``. Built once and handed to every layer; :func:`rotary`
+    says what a layer does with it."""
+    return rope_table(RotaryConfig(theta=cfg.rope_theta), cfg.head_dim,
+                      jnp.concatenate([jnp.arange(length)] * 2))
+
+
+def decoder_layer(cfg: BlockDiffLMConfig, layer: dict, x, table,
                   impl: str):
-    """One layer on ``x`` [batch, 2L, hidden] -> (x, rows per held expert)."""
+    """One layer on ``x`` [batch, 2L, hidden] -> (x, rows per held expert).
+    ``table`` is the forward pass's :func:`rotary_table`."""
     b, s, hid = x.shape
     heads, kvh, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     dtype = x.dtype
     with jax.named_scope("rdp.attn.proj"):
         h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
 
-        def heads_of(w, n):
-            y = jnp.dot(h, w.astype(dtype))
-            return y.reshape(b, s, n, d).transpose(0, 2, 1, 3)
+        def product(name):
+            return jnp.dot(h, layer[name].astype(dtype))
 
-        q = heads_of(layer["wq"], heads)
-        k = heads_of(layer["wk"], kvh)
-        v = heads_of(layer["wv"], kvh)
-        q = rotary(rms_norm(q, layer["q_norm"], cfg.rms_norm_eps),
-                   positions, cfg.rope_theta)
-        k = rotary(rms_norm(k, layer["k_norm"], cfg.rms_norm_eps),
-                   positions, cfg.rope_theta)
-        q = (q.astype(jnp.float32) * d ** -0.5).astype(dtype)
+        def prepared(name, n, scale=1.0):
+            return prepare_heads(
+                product("w" + name), n, d, norm_weight=layer[name + "_norm"],
+                eps=cfg.rms_norm_eps, table=table, scale=scale, impl=impl)
+
+        q, k = prepared("q", heads, d ** -0.5), prepared("k", kvh)
+        v = split_heads(product("wv"), kvh, d)
     a = blockdiff_attention(q, k, v, seq_len=s // 2, block=cfg.block_length,
                             impl=impl)
     with jax.named_scope("rdp.attn.proj"):
@@ -136,7 +156,7 @@ def forward(cfg: BlockDiffLMConfig, params: dict, tokens, masked,
         noisy = jnp.where(masked, cfg.mask_token_id, tokens)
         ids = jnp.concatenate([noisy, tokens], axis=1)
         x = params["embed"].astype(dtype)[ids]
-        positions = jnp.concatenate([jnp.arange(length)] * 2)
+    table = rotary_table(cfg, length)
 
     # of a layer, its input and attention's output and row sums are kept;
     # the rest is recomputed in the backward pass
@@ -145,7 +165,7 @@ def forward(cfg: BlockDiffLMConfig, params: dict, tokens, masked,
         policy=jax.checkpoint_policies.save_only_these_names(ATTN_RESIDUALS))
     def layer_fn(x, layer):
         with jax.named_scope("rdp.lm.layer"):
-            return decoder_layer(cfg, layer, x, positions, impl)
+            return decoder_layer(cfg, layer, x, table, impl)
 
     x, sizes = jax.lax.scan(layer_fn, x, params["layers"])
     with jax.named_scope("rdp.lm.head"):

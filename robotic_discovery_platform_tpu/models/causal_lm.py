@@ -41,7 +41,11 @@ loop around it). The parameters are laid out to match: leaf
 ``layers/<j>/<name>`` holds layer ``j`` of every period, the periods in
 front. **One rotary table a kind** (:func:`rope_table`), built once a
 forward pass from its ``RotaryConfig`` and handed to the layers of that
-kind; with ``factor`` above 1 it is YaRN's.
+kind; with ``factor`` above 1 it is YaRN's. A layer hands each projection's
+product, the table and the scale to ``ops/pallas/qk_prep.prepare_heads``,
+where q and k are made ready for attention: head split, rotation and scale
+in one Pallas pass a direction on a TPU (heads of 128), the dense chain
+(``apply_rotary``, kept there as the definition) elsewhere.
 
 **The chip's share** is ``models/moe``'s: ``experts_held`` experts of every
 layer and ``vocab_size`` rows of the embedding and the head.
@@ -66,6 +70,8 @@ from robotic_discovery_platform_tpu.models.moe import (
     expert_layer, expert_shapes, rms_norm, seeded_params)
 from robotic_discovery_platform_tpu.ops.pallas.masked_attention import (
     ATTN_RESIDUALS, Causal, Window, masked_attention)
+from robotic_discovery_platform_tpu.ops.pallas.qk_prep import (  # noqa: F401
+    apply_rotary, prepare_heads, split_heads)
 from robotic_discovery_platform_tpu.utils.config import (
     CausalLMConfig, RotaryConfig)
 
@@ -138,17 +144,6 @@ def rope_table(rope: RotaryConfig, head_dim: int, positions):
         return (cos * rope.attention_factor, sin * rope.attention_factor)
 
 
-def apply_rotary(x, table, scale: float = 1.0):
-    """``[..., s, d]`` rotated by ``table`` (rotate-half form) and
-    multiplied by ``scale``, in float32, in one pass."""
-    cos, sin = table
-    d = x.shape[-1]
-    x32 = x.astype(jnp.float32)
-    rotated = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
-    out = x32 * cos + rotated * sin
-    return (out * scale if scale != 1.0 else out).astype(x.dtype)
-
-
 def attention_rule(cfg: CausalLMConfig, kind: str):
     """The mask of a layer kind, as a rule of ``masked_attention``."""
     return (Window(cfg.sliding_window) if kind == "sliding_attention"
@@ -169,13 +164,13 @@ def decoder_layer(cfg: CausalLMConfig, kind: str, layer: dict, x, table,
     with jax.named_scope("rdp.attn.proj"):
         h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps)
 
-        def heads_of(w, n):
-            y = jnp.dot(h, w.astype(dtype))
-            return y.reshape(b, s, n, d).transpose(0, 2, 1, 3)
+        def product(name):
+            return jnp.dot(h, layer[name].astype(dtype))
 
-        q = apply_rotary(heads_of(layer["wq"], heads), table, d ** -0.5)
-        k = apply_rotary(heads_of(layer["wk"], kvh), table)
-        v = heads_of(layer["wv"], kvh)
+        q = prepare_heads(product("wq"), heads, d, table=table,
+                          scale=d ** -0.5, impl=impl)
+        k = prepare_heads(product("wk"), kvh, d, table=table, impl=impl)
+        v = split_heads(product("wv"), kvh, d)
     a = masked_attention(q, k, v, attention_rule(cfg, kind), impl=impl)
     with jax.named_scope("rdp.attn.proj"):
         a = a.transpose(0, 2, 1, 3).reshape(b, s, heads * d)

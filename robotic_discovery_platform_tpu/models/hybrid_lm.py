@@ -42,7 +42,11 @@ Every layer is ``x += branch(RMSNorm(x))`` (``HybridLMConfig``; RMSNorm
 and ``k`` (``q_norm``, ``k_norm``); with ``rope_theta`` above 0 the rotary
 embedding of ``causal_lm.rope_table`` (rotate-half, whole head, no
 scaling), else **no rotary embedding**; ``softmax(q k^T / sqrt(head_dim) +
-causal) v Wo``.
+causal) v Wo``. The head split, norm, rotation and scale of q and k are
+``ops/pallas/qk_prep.prepare_heads``'s: one Pallas pass a direction on a
+TPU where heads fill 128-lane tiles and there is a norm or a table to fuse,
+the dense chain for heads of 64 (LFM2) and for a bare scale (no norm, no
+positions).
 
 ``mlp``: ``Wdown(silu(u Wgate) * (u Wup))`` of width ``mlp_width``.
 
@@ -88,11 +92,13 @@ import jax
 import jax.numpy as jnp
 
 from robotic_discovery_platform_tpu.models.causal_lm import (
-    apply_rotary, head_logits, next_token_loss, period, rope_table)
+    head_logits, next_token_loss, period, rope_table)
 from robotic_discovery_platform_tpu.models.moe import (
     dense_expert, expert_layer, expert_shapes, rms_norm, seeded_params)
 from robotic_discovery_platform_tpu.ops.pallas.masked_attention import (
     ATTN_RESIDUALS, Causal, masked_attention)
+from robotic_discovery_platform_tpu.ops.pallas.qk_prep import (
+    prepare_heads, split_heads)
 from robotic_discovery_platform_tpu.ops.ssm_scan import ssm_scan
 from robotic_discovery_platform_tpu.utils.config import (
     HybridLMConfig, RotaryConfig)
@@ -260,19 +266,17 @@ def attention_layer(cfg: HybridLMConfig, layer: dict, x, impl: str,
     with jax.named_scope("rdp.attn.proj"):
         u = rms_norm(x, layer["norm"], cfg.rms_norm_eps)
 
-        def heads_of(w, n):
-            y = jnp.dot(u, w.astype(dtype))
-            return y.reshape(b, s, n, d).transpose(0, 2, 1, 3)
+        def product(name):
+            return jnp.dot(u, layer[name].astype(dtype))
 
-        q = heads_of(layer["wq"], heads)
-        k, v = heads_of(layer["wk"], kvh), heads_of(layer["wv"], kvh)
-        if cfg.qk_norm:
-            q = rms_norm(q, layer["q_norm"], cfg.rms_norm_eps)
-            k = rms_norm(k, layer["k_norm"], cfg.rms_norm_eps)
-        if table is None:
-            q = (q.astype(jnp.float32) * d ** -0.5).astype(dtype)
-        else:
-            q, k = apply_rotary(q, table, d ** -0.5), apply_rotary(k, table)
+        def prepared(name, n, scale=1.0):
+            return prepare_heads(
+                product("w" + name), n, d,
+                norm_weight=layer[name + "_norm"] if cfg.qk_norm else None,
+                eps=cfg.rms_norm_eps, table=table, scale=scale, impl=impl)
+
+        q, k = prepared("q", heads, d ** -0.5), prepared("k", kvh)
+        v = split_heads(product("wv"), kvh, d)
     a = masked_attention(q, k, v, Causal(), impl=impl)
     with jax.named_scope("rdp.attn.proj"):
         a = a.transpose(0, 2, 1, 3).reshape(b, s, heads * d)

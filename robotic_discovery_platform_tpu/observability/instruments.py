@@ -807,6 +807,16 @@ SSM_SCAN_CHUNKS = REGISTRY.counter(
     "change of chunk or form shows here without a device trace.",
     ("kind",),
 )
+ATTN_QK_PREP = REGISTRY.counter(
+    families.ATTN_QK_PREP,
+    "Query and key tensors made ready for attention "
+    "(ops/pallas/qk_prep.prepare_heads: head split, per-head norm, rotary "
+    "embedding, scale), one sample per tensor each time a program is traced "
+    "(so a memoised runner adds none): form = fused (one Pallas pass a "
+    "direction, compiled or interpreted) or xla (the dense chain: a bare "
+    "scale, heads that do not fill 128 lanes, no TPU).",
+    ("form",),
+)
 
 _BREAKER_STATE_VALUES = {"closed": 0, "open": 1, "half_open": 2}
 

@@ -136,6 +136,65 @@ def test_the_configurations_bfloat16_stays_near_the_reference(impl):
         jnp.abs(want).max())
 
 
+def _samples():
+    return {form: obs.ATTN_QK_PREP.labels(form=form).value
+            for form in ("fused", "xla")}
+
+
+@pytest.fixture(scope="module")
+def fused_pair():
+    """Loss and gradients of a model whose heads fill a 128-lane tile, under
+    the interpreted kernels and under the dense forms, in float32; and the
+    samples each trace added to ``rdp_attn_qk_prep_total``."""
+    got = {}
+    for impl in ("interpret", "xla"):
+        cfg = small(num_heads=2, num_kv_heads=1, head_dim=128,
+                    kernel_impl=impl)
+        _, _, nested, tokens, masked, t = seeded(cfg)
+
+        def loss(p):
+            logits, _ = lm.forward(cfg, p, jnp.asarray(tokens),
+                                   jnp.asarray(masked))
+            return lm.diffusion_loss(logits, jnp.asarray(tokens),
+                                     jnp.asarray(masked), jnp.asarray(t))
+
+        before = _samples()
+        value, grads = jax.value_and_grad(loss)(nested)
+        got[impl] = ({"loss": value,
+                      **{"/".join(k): v
+                         for k, v in flatten_dict(grads).items()}},
+                     {k: v - before[k] for k, v in _samples().items()})
+    return got
+
+
+@pytest.mark.parametrize("leaf", ["loss"] + LEAVES)
+def test_q_and_k_prepared_in_one_pass_are_the_dense_chains(fused_pair, leaf):
+    """``ops/pallas/qk_prep``: the model under ``impl="interpret"`` (q and k
+    of every layer through the fused pass, forward and backward) against
+    ``impl="xla"``, by the order of float32's sums."""
+    (got, fused), (want, dense) = fused_pair["interpret"], fused_pair["xla"]
+    assert fused["fused"] > 0 and fused["fused"] % 2 == 0
+    assert fused["xla"] == 0 and dense["fused"] == 0 and dense["xla"] > 0
+    mine, its = np.asarray(got[leaf]), np.asarray(want[leaf])
+    assert np.linalg.norm(mine - its) <= 1e-5 * np.linalg.norm(its)
+
+
+@pytest.mark.parametrize("length,d", [(32, 16), (50, 128)])
+def test_the_table_built_once_is_what_rotary_computed_a_layer(length, d):
+    """``rotary`` (angles rebuilt from the positions at every call) is what
+    a layer did until the table left the layer scan."""
+    from robotic_discovery_platform_tpu.ops.pallas import qk_prep
+
+    cfg = small(head_dim=d, rope_theta=1e4)
+    x = jax.random.normal(jax.random.key(2), (2, 4, 2 * length, d))
+    positions = jnp.tile(jnp.arange(length), 2)
+    table = lm.rotary_table(cfg, length)
+    assert table[0].shape == table[1].shape == (2 * length, d)
+    np.testing.assert_array_equal(
+        qk_prep.apply_rotary(x, table),
+        lm.rotary(x, positions, cfg.rope_theta))
+
+
 def test_three_adam_steps_through_the_trainers_step_against_the_reference():
     cfg, tcfg = small(), TrainConfig(seed=11, learning_rate=1e-3)
     model, flat, nested, tokens, _, _ = seeded(cfg)
@@ -339,17 +398,17 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
     whole = {k[len("layers/"):]: v[0] for k, v in flat.items()
              if k.startswith("layers/")}
     want, rows = ref.layer(model, whole, x, mask)
-    positions = jnp.tile(jnp.arange(uncut.seq_len), 2)
+    table = lm.rotary_table(uncut, uncut.seq_len)
     no_experts = {**whole, **{k: jnp.zeros_like(whole[k][:2])
                               for k in ("w_gate", "w_up", "w_down")}}
     share0 = dataclasses.replace(uncut, experts_held=2)
-    base, _ = lm.decoder_layer(share0, no_experts, x[None], positions, "xla")
+    base, _ = lm.decoder_layer(share0, no_experts, x[None], table, "xla")
     total, taken = base, []
     for chip in range(8):
         share = dataclasses.replace(share0, first_expert=2 * chip)
         held = {**whole, **{k: whole[k][2 * chip:2 * chip + 2]
                             for k in ("w_gate", "w_up", "w_down")}}
-        out, sizes = lm.decoder_layer(share, held, x[None], positions, "xla")
+        out, sizes = lm.decoder_layer(share, held, x[None], table, "xla")
         total = total + (out - base)
         taken += sizes.tolist()
     np.testing.assert_array_equal(taken, rows)

@@ -105,6 +105,43 @@ def f32_pair():
             "grads": (flatten_dict(grads, sep="/"), want_grads)}
 
 
+def _samples():
+    return {form: obs.ATTN_QK_PREP.labels(form=form).value
+            for form in ("fused", "xla")}
+
+
+@pytest.fixture(scope="module")
+def fused_pair():
+    """Loss and gradients of a model whose heads fill a 128-lane tile
+    (YaRN's ``attention_factor`` in the full layers' table), under the
+    interpreted kernels and under the dense forms, in float32; and the
+    samples each trace added to ``rdp_attn_qk_prep_total``."""
+    got = {}
+    for impl in ("interpret", "xla"):
+        cfg = small(num_heads=2, num_kv_heads=1, head_dim=128,
+                    full_rope=PUBLISHED_YARN, kernel_impl=impl)
+        _, _, nested, tokens = seeded(cfg)
+        net = lm.build_causal_lm(cfg)
+        before = _samples()
+        value, grads = jax.value_and_grad(
+            lambda p: net.loss(p, jnp.asarray(tokens))[0])(nested)
+        got[impl] = ({"loss": value, **flatten_dict(grads, sep="/")},
+                     {k: v - before[k] for k, v in _samples().items()})
+    return got
+
+
+@pytest.mark.parametrize("leaf", ["loss"] + LEAVES)
+def test_q_and_k_prepared_in_one_pass_are_the_dense_chains(fused_pair, leaf):
+    """``ops/pallas/qk_prep``: the model under ``impl="interpret"`` (q and k
+    of every layer rotated and scaled by the fused pass, no norm) against
+    ``impl="xla"``, by the order of float32's sums."""
+    (got, fused), (want, dense) = fused_pair["interpret"], fused_pair["xla"]
+    assert fused["fused"] > 0 and fused["fused"] % 2 == 0
+    assert fused["xla"] == 0 and dense["fused"] == 0 and dense["xla"] > 0
+    mine, its = np.asarray(got[leaf]), np.asarray(want[leaf])
+    assert np.linalg.norm(mine - its) <= 1e-5 * np.linalg.norm(its)
+
+
 def test_the_reference_imports_nothing_of_the_program():
     source = REFERENCE.read_text()
     assert "robotic_discovery_platform_tpu" not in source

@@ -80,6 +80,53 @@ def seeded(cfg: HybridLMConfig, batch: int = 2):
     return model, flat, nested, ref.tokens(model, SEED, batch)
 
 
+# -- q and k made ready for attention -----------------------------------------
+
+def _samples():
+    return {form: obs.ATTN_QK_PREP.labels(form=form).value
+            for form in ("fused", "xla")}
+
+
+#: what the attention layers' q and k need, and the form that gives it under
+#: ``impl="interpret"``: LFM2's norm and table on heads of 128, the same on
+#: its own heads of 64, and this family's first model (a scale alone)
+PREPARED = {
+    "norm+table-128": ({"qk_norm": True, "rope_theta": 100.0,
+                        "head_dim": 128, "num_heads": 2,
+                        "num_kv_heads": 1}, "fused"),
+    "norm+table-64": ({"qk_norm": True, "rope_theta": 100.0, "head_dim": 64,
+                       "num_heads": 2, "num_kv_heads": 1}, "xla"),
+    "scale-alone": ({"head_dim": 128, "num_heads": 2, "num_kv_heads": 1},
+                    "xla"),
+}
+
+
+@pytest.mark.parametrize("what", PREPARED)
+def test_q_and_k_prepared_in_one_pass_are_the_dense_chains(what):
+    """``ops/pallas/qk_prep`` through the attention layers: loss and every
+    gradient leaf under ``impl="interpret"`` against ``impl="xla"`` in
+    float32, and the form ``rdp_attn_qk_prep_total`` says each trace took."""
+    fields, form = PREPARED[what]
+    got = {}
+    for impl in ("interpret", "xla"):
+        cfg = small(num_layers=4, layer_pattern="ME*E", kernel_impl=impl,
+                    **fields)
+        tokens = ref.tokens(dataclasses.asdict(cfg), SEED, 2)
+        net = lm.build_hybrid_lm(cfg)
+        nested = net.init(jax.random.key(SEED))
+        before = _samples()
+        value, grads = jax.value_and_grad(
+            lambda p: net.loss(p, jnp.asarray(tokens))[0])(nested)
+        got[impl] = {"loss": value, **flatten_dict(grads, sep="/")}
+        added = {k: v - before[k] for k, v in _samples().items()}
+        other = "xla" if form == "fused" else "fused"
+        assert added[other if impl == "interpret" else "fused"] == 0
+        assert added[form if impl == "interpret" else "xla"] >= 2
+    for leaf, its in got["xla"].items():
+        mine, its = np.asarray(got["interpret"][leaf]), np.asarray(its)
+        assert np.linalg.norm(mine - its) <= 1e-5 * np.linalg.norm(its), leaf
+
+
 # -- the scan against the recurrence ------------------------------------------
 
 SCAN_INPUTS = ("x", "dt", "a", "b", "c", "d")

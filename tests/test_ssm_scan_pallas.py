@@ -312,3 +312,32 @@ def test_mosaic_compiles_both_kernels_at_shapes_the_predicate_takes(
     assert forward.count("reduce-precision") >= 3
     both = jax.jit(jax.grad(loss, range(6))).lower(*args).compile().as_text()
     assert "ssm_scan_forward" in both and "ssm_scan_backward" in both
+
+
+@pytest.mark.parametrize("heads,norm,rope", [
+    (32, True, True), (4, True, True), (48, False, True), (8, True, False)],
+    ids=["sdar-q", "sdar-k", "mellum-q", "a-norm-alone"])
+def test_mosaic_compiles_the_q_k_pass_at_the_cells_widths(
+        one_chip, no_compile_cache, heads, norm, rope):
+    """``ops/pallas/qk_prep`` (the chip's compiler is described in this file
+    alone, so its kernels' compile lives here): two sequences of 8,192
+    positions on heads of 128, forward alone and with the backward pass."""
+    from robotic_discovery_platform_tpu.ops.pallas import qk_prep
+
+    def abstract(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, s, d = 2, 8192, 128
+    y = abstract((b, s, heads * d), jnp.bfloat16)
+    weight = abstract((d,), jnp.float32) if norm else None
+    table = (abstract((s, d), jnp.float32),) * 2 if rope else None
+    prepare = lambda y, weight, table: qk_prep.prepare_heads(
+        y, heads, d, norm_weight=weight, eps=1e-6, table=table,
+        scale=d ** -0.5, impl="pallas")
+    loss = lambda *v: jnp.sum(prepare(*v).astype(jnp.float32))
+    forward = jax.jit(prepare).lower(y, weight, table).compile().as_text()
+    assert "qk_prep_forward" in forward
+    assert forward.count("tpu_custom_call") == 1
+    both = jax.jit(jax.grad(loss, (0, 1) if norm else 0)).lower(
+        y, weight, table).compile().as_text()
+    assert "qk_prep_backward" in both
